@@ -1,8 +1,6 @@
 import time
 import numpy as np
 import jax, jax.numpy as jnp
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 N = 176_000_000  # 704MB f32
 x = jnp.ones((N,), jnp.float32)

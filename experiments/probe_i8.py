@@ -1,8 +1,8 @@
 """Does the int8 Pallas dot compile + run, and how fast vs bf16?"""
 import time, numpy as np, jax, jax.numpy as jnp, sys
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-sys.path.insert(0, "/root/repo")
+import os
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from h2o3_tpu.utils import compile_cache; compile_cache.enable()
 from h2o3_tpu.ops import hist_pallas as HP
 
 N = 11_000_000

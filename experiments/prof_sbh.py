@@ -2,9 +2,8 @@
 import time
 import numpy as np
 import jax, jax.numpy as jnp
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-import sys; sys.path.insert(0, "/root/repo")
+import os, sys; sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from h2o3_tpu.utils import compile_cache; compile_cache.enable()
 from h2o3_tpu.ops import hist_pallas as HP
 from h2o3_tpu.models.tree import binned as BN
 

@@ -3,7 +3,8 @@ import sys, time
 import numpy as np
 import jax, jax.numpy as jnp
 jax.config.update("jax_platforms", "cpu")
-sys.path.insert(0, "/root/repo")
+import os
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from h2o3_tpu.models.tree import binned as BN
 

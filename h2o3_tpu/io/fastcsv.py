@@ -9,14 +9,18 @@ ingest pipeline (io/dparse.py): `parse_columns` for byte ranges of local
 files (the native code does its own read, so pool threads overlap read
 with tokenize) and `parse_bytes_columns` for caller-staged buffers
 (streaming-decompressed gzip/zip windows, HTTP/object-store range reads).
-Build: `make -C native` (or scripts/build_native.sh); the Python parser
-falls back to the csv module when the library is absent.
+Build: `load_native` runs `make -C native` on first use when the library
+is missing or older than its committed source (the binaries are not
+tracked); the Python parser falls back to the csv module when the
+library can be neither found nor built.
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
+import subprocess
 
 import numpy as np
 
@@ -41,11 +45,42 @@ def native_dir() -> str:
     return env_str("H2O3_NATIVE_DIR", "") or os.path.join(here, "native")
 
 
+def _stale(so: str, src: str) -> bool:
+    try:
+        return os.path.getmtime(so) < os.path.getmtime(src)
+    except OSError:
+        return True             # no library yet
+
+
+def load_native(name: str) -> ctypes.CDLL:
+    """dlopen native/lib<name>.so, first building it from <name>.cpp when
+    that source is present and the library is missing or older. Safe
+    under concurrent first use (xdist workers, pool processes): builders
+    serialize on a lock file and re-check under it, and the Makefile
+    renames the finished library into place, so a process that skips the
+    lock because the library looks fresh never maps a half-written file.
+    A build that cannot run raises OSError, like a missing library."""
+    d = native_dir()
+    so = os.path.join(d, f"lib{name}.so")
+    src = os.path.join(d, f"{name}.cpp")
+    if os.path.exists(src) and _stale(so, src):
+        with open(os.path.join(d, ".build.lock"), "w") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
+            if _stale(so, src):
+                try:
+                    subprocess.run(["make", "-C", d, f"lib{name}.so"],
+                                   check=True, capture_output=True,
+                                   text=True)
+                except subprocess.CalledProcessError as e:
+                    raise OSError(f"building lib{name}.so failed:\n"
+                                  f"{e.stderr[-2000:]}") from e
+    return ctypes.CDLL(so)
+
+
 def _lib():
     global _LIB
     if _LIB is None:
-        path = os.path.join(native_dir(), "libfastcsv.so")
-        lib = ctypes.CDLL(path)
+        lib = load_native("fastcsv")
         lib.fastcsv_parse.restype = ctypes.c_void_p
         lib.fastcsv_parse.argtypes = [ctypes.c_char_p, ctypes.c_char,
                                       ctypes.c_int]

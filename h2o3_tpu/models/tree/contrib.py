@@ -14,7 +14,6 @@ training (engine.node_covers)."""
 from __future__ import annotations
 
 import ctypes
-import os
 
 import numpy as np
 
@@ -24,14 +23,13 @@ _LIB = None
 def _lib():
     global _LIB
     if _LIB is None:
-        from h2o3_tpu.io.fastcsv import native_dir
-        path = os.path.join(native_dir(), "libtreeshap.so")
+        from h2o3_tpu.io.fastcsv import load_native
         try:
-            lib = ctypes.CDLL(path)
+            lib = load_native("treeshap")
         except OSError as e:
             raise RuntimeError(
-                f"native TreeSHAP library not built ({path}); run "
-                f"`make -C native` to build it") from e
+                "native TreeSHAP library (native/treeshap.cpp) could be "
+                f"neither loaded nor built: {e}") from e
         lib.treeshap_ensemble.restype = None
         lib.treeshap_ensemble.argtypes = [
             ctypes.c_int, ctypes.c_int, ctypes.c_int, ctypes.c_int,

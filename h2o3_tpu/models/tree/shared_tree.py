@@ -62,12 +62,13 @@ class SharedTreeEstimator(ModelBase):
         # at 255 (a root histogram at 1024 bins halved 2 levels ≈ 256).
         # None = derive from nbins alone (the engine's own default).
         "nbins_top_level": None,
-        # TPU extensions (None = auto: on wherever the kernel family's
-        # probe compile passes and the shape qualifies; False = force the
-        # dense/sequential reference paths): int8-quantized histogram
-        # stats on the 2x-rate int8 MXU path; the radix-factored
-        # shallow-window histogram kernel; the level-fused route+hist
-        # kernel (ops/hist_pallas.py).
+        # TPU extensions (ops/hist_pallas.py). int8_hist: int8-quantized
+        # histogram stats on the 2x-rate int8 MXU path — opt-in (None =
+        # off). radix_shallow: the radix-factored shallow-window
+        # histogram kernel — opt-in (None = off; the chip's compiler
+        # refuses it at 32 columns). fused_level: the level-fused
+        # route+hist kernel — None = on wherever the level's shape
+        # qualifies, False = force the sequential pair.
         "int8_hist": None,
         "radix_shallow": None,
         "fused_level": None,
@@ -203,13 +204,20 @@ class SharedTreeEstimator(ModelBase):
         n_pad = grower.layout(n, shards=shards if multi else 1)
         # uint8 code plane (1 byte/code in HBM), packed to the Pallas
         # kernels' i32 word layout on TPU — the row axis is untouched so
-        # the rows sharding spec below applies to either layout
-        codes = BN.prepare_codes(BN.quantize(X, spec, n_pad=n_pad))
+        # one rows sharding applies to either layout. On a multi-device
+        # cloud the plane is BORN row-sharded (each device quantizes its
+        # own rows): unconstrained, a partitioner may replicate it, the
+        # whole matrix gathered onto every device first (BN._quantize).
+        codes_sh = None
+        if multi:
+            from jax.sharding import PartitionSpec as P
+            codes_sh = cl.sharding(P(None, MESH.ROWS))
+        codes = BN.prepare_codes(BN.quantize(X, spec, n_pad=n_pad,
+                                             sharding=codes_sh))
         y1 = BN.pad_rows(y, n_pad)
         w1 = BN.pad_rows(w, n_pad)
         if multi:
-            from jax.sharding import PartitionSpec as P
-            codes = jax.device_put(codes, cl.sharding(P(None, MESH.ROWS)))
+            codes = jax.device_put(codes, codes_sh)
             y1 = jax.device_put(y1, cl.rows_sharding(1))
             w1 = jax.device_put(w1, cl.rows_sharding(1))
         # register the code plane with the DKV tier pager: training

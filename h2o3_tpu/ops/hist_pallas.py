@@ -54,14 +54,22 @@ Kernels per level:
     ScoreBuildHistogram2 shape itself): ONE kernel reads the code tile
     once, routes the rows, and accumulates the histogram over the UPDATED
     heap — halving code traffic again at the shallow levels where the
-    histogram is bandwidth-floor (not dot) bound. Auto-on only where the
-    fused program compiles (`fused_supported` probe) and the whole-level
-    histogram fits VMEM (`_fused_applicable`); the unfused route+hist
-    pair is always the fallback and the XLA path.
+    histogram is bandwidth-floor (not dot) bound. On wherever the
+    whole-level histogram fits VMEM (`_fused_applicable`, a shape rule);
+    the unfused route+hist pair serves the deeper levels and is the XLA
+    path.
 
   * sbh_hist_radix — radix-factored shallow-window histogram (PERF_NOTES
     item 1): code = hi*16+lo with the leaf slot fused into the hi key
     kills the 256-wide VPU one-hot floor at effective windows <= 2.
+    EXPLICIT OPT-IN only: Mosaic refuses it at 32 columns (VMEM) and its
+    fused variant costs minutes of compile (tests/test_chip_compile.py
+    holds the default rules to that), so no default path selects it.
+
+Kernel selection is by SHAPE RULE only (`is_packed`, `_fused_applicable`,
+`_radix_shape_ok`). There is one installation; what its Mosaic compiles at
+the widths users train at is pinned by tests/test_chip_compile.py, and a
+kernel the compiler refuses raises — it never degrades to a slower path.
 
 Stats panel rows (S_STATS=4): 0=w, 1=w*grad, 2=w*hess, 3=spare(0) —
 (w, wg, wh) feed split gain, min_rows and Newton leaf values
@@ -76,12 +84,27 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:  # Pallas import is deferred-safe: exotic envs may lack Mosaic
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from h2o3_tpu.obs import metrics as _om
+
+# Bumped while a Pallas entry point below is TRACED (its Python body runs
+# once per distinct shape/static signature, inside whatever outer program
+# is being built) — the evidence that a compiled tree program holds the
+# TPU kernels. A backend that took the `_xla` twins leaves it at zero;
+# model_summary's "engine" string says nothing either way.
+KERNEL_TRACES = _om.counter(
+    "h2o3_pallas_kernel_traces_total",
+    "Pallas TPU kernels traced into device programs, by kernel and "
+    "leaf-window L")
+
+
+def kernel_traces() -> dict:
+    """{(kernel, L): traces so far} — snapshot it around a build to see
+    which kernels the selection rules put into the program."""
+    return {(e["labels"]["kernel"], int(e["labels"]["L"])): int(e["value"])
+            for e in KERNEL_TRACES._json()}
 
 # Rows per kernel grid step. n_pad must be a multiple of this.
 BLOCK_ROWS = 4096
@@ -103,7 +126,7 @@ WORD_TILE = 8
 
 
 def use_pallas() -> bool:
-    return _HAVE_PALLAS and jax.default_backend() == "tpu"
+    return jax.default_backend() == "tpu"
 
 
 def is_packed(codes) -> bool:
@@ -129,12 +152,23 @@ def pack_codes(codes_u8):
     """(C_pad, n_pad) uint8 -> (W_pad, n_pad) int32 packed plane: little-
     endian bytes, 4 codes/word along the COLUMN axis (dummy columns pack
     as code 0 = zero-stat rows' bin). The row axis is untouched, so row
-    sharding specs carry over unchanged."""
+    sharding specs carry over unchanged.
+
+    Built word by word from ROW slices of the uint8 plane: the obvious
+    pad + reshape(W, 4, n) form re-tiles the whole plane as int32 and, at
+    HIGGS size (32 x 11M), took the chip's compiler 64 s, 34 MB of code
+    and 2.8 GB of temporaries; this form compiles in ~1.5 s with 0.26 GB
+    (compile for a described v5e, tests/test_chip_compile.py)."""
     c_pad, n_pad = codes_u8.shape
-    w_pad = packed_words(c_pad)
-    c = jnp.pad(codes_u8, ((0, w_pad * PACK - c_pad), (0, 0))) \
-        .astype(jnp.int32).reshape(w_pad, PACK, n_pad)
-    return c[:, 0] | (c[:, 1] << 8) | (c[:, 2] << 16) | (c[:, 3] << 24)
+    words = []
+    for w in range(packed_words(c_pad)):
+        acc = jnp.zeros(n_pad, jnp.int32)
+        for k in range(PACK):
+            c = w * PACK + k
+            if c < c_pad:
+                acc = acc | (codes_u8[c].astype(jnp.int32) << (8 * k))
+        words.append(acc)
+    return jnp.stack(words)
 
 
 @functools.partial(jax.jit, static_argnames=("c_pad",))
@@ -156,83 +190,6 @@ def prepare_codes(codes_u8):
 
 
 # ===========================================================================
-# Probes: auto-enabling a kernel family must never brick training (or the
-# bench) on a TPU generation whose Mosaic rejects its tiling — compile each
-# once with a tiny shape and cache the answer.
-_I8_OK: bool | None = None
-_RADIX_OK: bool | None = None
-_FUSED_OK: bool | None = None
-
-
-def _probe_plane():
-    u8 = jnp.zeros((COL_TILE, BLOCK_ROWS), jnp.uint8)
-    return pack_codes(u8)
-
-
-def i8_supported() -> bool:
-    """True when the int8-stats histogram kernel compiles + runs here."""
-    global _I8_OK
-    if _I8_OK is None:
-        if not use_pallas():
-            _I8_OK = False
-        else:
-            try:
-                cp = _probe_plane()
-                h = jnp.zeros(BLOCK_ROWS, jnp.int32)
-                s = jnp.ones((S_STATS, BLOCK_ROWS), jnp.int32)
-                out = sbh_hist_pallas_i8(cp, h, s, base=0, L=1, n_bins=128)
-                _I8_OK = int(jnp.sum(out[0, 0, 0])) == BLOCK_ROWS
-            except Exception:  # pragma: no cover - chip-specific
-                _I8_OK = False
-    return _I8_OK
-
-
-def radix_supported() -> bool:
-    """Probe-compile the radix shallow-window kernel once."""
-    global _RADIX_OK
-    if _RADIX_OK is None:
-        if not use_pallas():
-            _RADIX_OK = False
-        else:
-            try:
-                cp = _probe_plane()
-                h = jnp.zeros(BLOCK_ROWS, jnp.int32)
-                s = jnp.ones((S_STATS, BLOCK_ROWS), jnp.float32)
-                out = sbh_hist_radix(cp, h, s, base=0, L=1, n_bins=256)
-                _RADIX_OK = abs(float(out[0, 0, 0, 0])
-                                - BLOCK_ROWS) < 0.5
-            except Exception:  # pragma: no cover - chip-specific
-                _RADIX_OK = False
-    return _RADIX_OK
-
-
-def fused_supported() -> bool:
-    """Probe-compile the level-fused route+hist kernel once."""
-    global _FUSED_OK
-    if _FUSED_OK is None:
-        if not use_pallas():
-            _FUSED_OK = False
-        else:
-            try:
-                cp = _probe_plane()
-                heap = jnp.zeros(BLOCK_ROWS, jnp.int32)
-                tbl = jnp.zeros((8, 8), jnp.float32).at[1, 0].set(1.0)
-                route_f = jnp.zeros((8, 256), jnp.float32)
-                s = jnp.ones((S_STATS, BLOCK_ROWS), jnp.float32)
-                nh, hist = sbh_route_hist_fused_pallas(
-                    cp, heap, tbl, route_f, s, base_r=0, L_r=1, base_h=1,
-                    L_h=2, n_bins=256, any_cat=True, na_code=255)
-                # every row splits left (route table all-zero): heap 0 -> 1,
-                # leaf 0 (even) lands in window slot 0, bin 0
-                _FUSED_OK = (int(nh[0]) == 1
-                             and abs(float(hist[0, 0, 0, 0])
-                                     - BLOCK_ROWS) < 0.5)
-            except Exception:  # pragma: no cover - chip-specific
-                _FUSED_OK = False
-    return _FUSED_OK
-
-
-# ===========================================================================
 # Shared kernel bodies (route math / stats panel / per-column accumulation)
 # — one definition each so the standalone kernels and the fused kernel
 # cannot drift semantically.
@@ -250,10 +207,13 @@ def _route_math(words, heap, tbl, route, *, base, L, n_bins, any_cat,
     active_f = active.astype(jnp.float32)
     ohl_f = ((iota_l == leaf_c[:, None]).astype(jnp.float32)
              * active_f[:, None])                             # (R, Lp) f32
-    # props lookup stays f32: bf16 cannot represent col ids > 256 or split
-    # bins > 256 exactly, which would silently misroute wide frames
+    # props lookup stays f32 — and says so to Mosaic: its DEFAULT
+    # contraction rounds f32 operands to bf16, which cannot represent col
+    # ids > 256 or split bins > 256 exactly and would silently misroute
+    # wide frames
     props = lax.dot_general(ohl_f, tbl,
                             (((1,), (1,)), ((), ())),
+                            precision=lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)  # (R, 8)
     did_r = props[:, 1] > 0.5
     # split column's code: word compare-select over the packed sublanes
@@ -332,7 +292,7 @@ def _dense_parts(words, A, *, n_bins, int8):
         # one-hot built TRANSPOSED (nb, R): bins on sublanes, rows on
         # lanes. Measured 1.9x faster than the (R, nb) orientation — the
         # compare broadcast is a major-dim insert (free) instead of a
-        # minor-dim relayout, and the dot contracts the rhs on dim 1.
+        # minor-dim layout change, and the dot contracts the rhs on dim 1.
         iota_b = lax.broadcasted_iota(jnp.int32, (n_bins, R), 0)
     parts = []
     for w in range(words.shape[0]):
@@ -409,12 +369,16 @@ def _route_kernel_f(codesP_ref, heap_ref, tbl_ref, route_ref, valtab_ref,
     heap_out_ref[0, :] = newheap
     nodes_p = valtab_ref.shape[1]
     iota_n = lax.broadcasted_iota(jnp.int32, (R, nodes_p), 1)
-    # f32 one-hot x f32 table: leaf values must reach F at full precision
-    # (scoring reads the same values as f32)
+    # f32 one-hot x f32 table at fp32 contraction precision: leaf values
+    # must reach F at full precision (scoring reads the same values as
+    # f32). Mosaic's DEFAULT contraction rounds the table to bf16 — on the
+    # chip every tree's margin update was off by up to 2^-9 of its leaf
+    # value (ISSUE 22, first on-chip parity run: 7.7e-4 against 1e-5).
     ohn = (iota_n == newheap[:, None]).astype(jnp.float32)
     val_r = lax.dot_general(
         ohn, valtab_ref[...],
         (((1,), (1,)), ((), ())),
+        precision=lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)[:, 0]
     f_out_ref[0, :] = f_ref[0, :] + eta * val_r
 
@@ -433,6 +397,7 @@ def sbh_route_pallas(codesP, heap, tbl, route_f, valtab=None, F=None, *,
     w_pad, n_pad = codesP.shape
     nblk = n_pad // BLOCK_ROWS
     n_bins = route_f.shape[1]
+    KERNEL_TRACES.inc(kernel="route_f" if emit_f else "route", L=str(L))
     if not emit_f:
         kernel = functools.partial(_route_kernel, base=base, L=L,
                                    n_bins=n_bins, any_cat=any_cat,
@@ -534,6 +499,7 @@ def _hist_kernel(codesP_ref, heap_ref, stats_ref, out_ref, *, base, L,
 
 
 def _hist_pallas(codesP, heap, stats, *, base, L, n_bins, half, int8):
+    KERNEL_TRACES.inc(kernel="hist_i8" if int8 else "hist", L=str(L))
     w_pad, n_pad = codesP.shape
     cw = min(w_pad, WORD_TILE)
     ncw = w_pad // cw
@@ -623,13 +589,12 @@ def sbh_hist_xla(codesT, heap, stats, *, base, L, n_bins, half=False):
 
 
 def sbh_hist(codes, heap, stats, *, base, L, n_bins, half=False,
-             radix=None):
-    """Histogram dispatch. `radix`: None = auto (engage the radix
-    shallow-window kernel wherever its probe compiled and the window
-    qualifies), False = never, True = same as auto (the factorization
-    only exists for qualifying windows)."""
+             radix=False):
+    """Histogram dispatch. `radix=True` engages the radix shallow-window
+    kernel wherever the window qualifies (`_radix_shape_ok` — the
+    factorization only exists for those); a compiler refusal raises."""
     if is_packed(codes):
-        if radix is not False and _radix_applicable(L, n_bins, half):
+        if radix and _radix_applicable(L, n_bins, half):
             return sbh_hist_radix(codes, heap, stats, base=base, L=L,
                                   n_bins=n_bins, half=half, int8=False)
         return sbh_hist_pallas(codes, heap, stats, base=base, L=L,
@@ -644,7 +609,7 @@ def sbh_hist_i8(codes, heap, stats_i8, *, base, L, n_bins, half=False,
     out (exact accumulation). The XLA fallback is the same segment-sum
     with integer dtype passthrough — bit-identical for the CPU tests."""
     if is_packed(codes):
-        if radix is not False and _radix_applicable(L, n_bins, half):
+        if radix and _radix_applicable(L, n_bins, half):
             return sbh_hist_radix(codes, heap, stats_i8, base=base, L=L,
                                   n_bins=n_bins, half=half, int8=True)
         return sbh_hist_pallas_i8(codes, heap, stats_i8, base=base, L=L,
@@ -671,7 +636,7 @@ def _radix_shape_ok(l_eff: int, n_bins: int) -> bool:
 
 def _radix_applicable(L, n_bins, half) -> bool:
     l_eff = (L + 1) // 2 if half else L
-    return _radix_shape_ok(l_eff, n_bins) and radix_supported()
+    return _radix_shape_ok(l_eff, n_bins)
 
 
 def _radix_kernel(codesP_ref, heap_ref, stats_ref, out_ref, *, base, L,
@@ -704,6 +669,7 @@ def sbh_hist_radix(codesP, heap, stats, *, base, L, n_bins, half=False,
     """Radix-factored histogram for effective windows <= RADIX_MAX_WINDOW.
     Same contract as sbh_hist_pallas but returns exactly (l_eff, c_pack,
     S_STATS, n_bins); f32 out (bf16 accumulation) or i32 when int8."""
+    KERNEL_TRACES.inc(kernel="radix", L=str(L))
     w_pad, n_pad = codesP.shape
     cw = min(w_pad, WORD_TILE)
     ncw = w_pad // cw
@@ -757,8 +723,7 @@ _FUSE_VMEM_OUT = 6 * 2 ** 20
 def _fused_applicable(L_h: int, n_bins: int, c_pack: int) -> bool:
     l_eff = (L_h + 1) // 2
     return (l_eff <= FUSE_MAX_WINDOW
-            and c_pack * l_eff * S_STATS * n_bins * 4 <= _FUSE_VMEM_OUT
-            and fused_supported())
+            and c_pack * l_eff * S_STATS * n_bins * 4 <= _FUSE_VMEM_OUT)
 
 
 def _fused_kernel(codesP_ref, heap_ref, tbl_ref, route_ref, stats_ref,
@@ -802,6 +767,8 @@ def sbh_route_hist_fused_pallas(codesP, heap, tbl, route_f, stats, *,
     """ONE kernel: route splits of [base_r, base_r+L_r), then accumulate
     the half (left-children) histogram of [base_h, base_h+L_h) over the
     updated heap. Returns (newheap, hist (l_eff, c_pack, S, n_bins))."""
+    KERNEL_TRACES.inc(kernel="fused_radix" if radix else "fused",
+                      L=str(L_h))
     w_pad, n_pad = codesP.shape
     c_pack = w_pad * PACK
     l_eff = (L_h + 1) // 2
@@ -853,18 +820,16 @@ def sbh_route_hist_fused_pallas(codesP, heap, tbl, route_f, stats, *,
 
 def sbh_route_hist(codes, heap, tbl, route_f, stats, *, base_r, L_r,
                    base_h, L_h, n_bins, any_cat=True, na_code=255,
-                   int8=False, fused=None, radix=None):
+                   int8=False, fused=None, radix=False):
     """Fused-or-sequential level pass: route the previous level's splits,
     then accumulate the new level's half (left-children) histogram over
-    the updated heap. `fused`: None = auto (engage the fused Pallas
-    program wherever its probe compiled and the level qualifies), False =
-    always sequential; the sequential path is also the XLA/CPU path and
-    is semantically identical (tier-1 gated). Returns (newheap, hist)."""
+    the updated heap. `fused`: None = engage the fused Pallas program
+    wherever the level qualifies (`_fused_applicable`), False = always
+    sequential; the sequential path is also the XLA/CPU path and is
+    semantically identical (tier-1 gated). Returns (newheap, hist)."""
     if (is_packed(codes) and fused is not False
             and _fused_applicable(L_h, n_bins, codes.shape[0] * PACK)):
-        l_eff = (L_h + 1) // 2
-        use_radix = (radix is not False and _radix_shape_ok(l_eff, n_bins)
-                     and radix_supported())
+        use_radix = bool(radix) and _radix_applicable(L_h, n_bins, True)
         return sbh_route_hist_fused_pallas(
             codes, heap, tbl, route_f, stats, base_r=base_r, L_r=L_r,
             base_h=base_h, L_h=L_h, n_bins=n_bins, any_cat=any_cat,

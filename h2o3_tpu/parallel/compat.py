@@ -1,12 +1,4 @@
-"""JAX version compatibility shims + the host-mesh collective guard.
-
-The repo targets the stable `jax.shard_map` API (jax >= 0.6, `check_vma`
-kwarg); older runtimes ship the same transform as
-`jax.experimental.shard_map.shard_map` with the replication check under
-`check_rep`. Resolving per call (not at import) keeps the module usable
-when jax itself is stubbed out.
-
-Host-mesh collective guard — THE one serialization point for concurrent
+"""Host-mesh collective guard — THE one serialization point for concurrent
 multi-replica dispatch on host (CPU) meshes. XLA's CPU client shares ONE
 collective thread pool across concurrently launched programs: two
 in-flight multi-replica executions each park a subset of their
@@ -31,16 +23,6 @@ import threading
 import jax
 
 from h2o3_tpu.utils import env as _uenv
-
-
-def shard_map(fn, *, mesh, in_specs, out_specs, check_vma=False):
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
 
 
 # ---------------------------------------------------------------------------

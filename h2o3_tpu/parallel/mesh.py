@@ -107,6 +107,8 @@ def init(n_rows_shards: int | None = None, n_model_shards: int = 1,
         name = str(_cfg.get_property("cloud.name", None) or "h2o3-tpu")
     with _lock:
         devices = list(devices if devices is not None else jax.devices())
+        from h2o3_tpu.utils import compile_cache as _cc
+        _cc.enable()
         total = len(devices)
         if n_rows_shards is None:
             n_rows_shards = total // n_model_shards

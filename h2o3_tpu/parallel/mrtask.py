@@ -272,9 +272,9 @@ def map_chunks(fn, *arrays, in_specs=None, out_specs=None, check_vma=False,
     in_specs = tuple(in_specs)
 
     def smapped(*arrs):
-        return _compat.shard_map(fn, mesh=c.mesh, in_specs=in_specs,
-                                 out_specs=out_specs if out_specs is not None
-                                 else P(), check_vma=check_vma)(*arrs)
+        return jax.shard_map(fn, mesh=c.mesh, in_specs=in_specs,
+                             out_specs=out_specs if out_specs is not None
+                             else P(), check_vma=check_vma)(*arrs)
 
     try:
         key = ("map_chunks", _fn_key(fn), c.mesh, in_specs,

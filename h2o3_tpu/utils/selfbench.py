@@ -48,8 +48,7 @@ def network_bench(sizes=(1 << 10, 1 << 16, 1 << 22)) -> list:
 
         @jax.jit
         def allreduce(x):
-            from jax.experimental.shard_map import shard_map
-            return shard_map(
+            return jax.shard_map(
                 lambda s: jax.lax.psum(s, axis),
                 mesh=mesh, in_specs=P(axis, None), out_specs=P(axis, None)
             )(x)

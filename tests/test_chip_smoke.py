@@ -1,0 +1,160 @@
+"""chip_smoke.py, off the chip.
+
+The script itself must FAIL here (no accelerator) and gets no option that
+lets it pass; what can be rehearsed on the CPU is rehearsed by importing
+its phase functions at a tiny size — widths (28 columns, 255 bins, depth
+8) stay as on the chip. The kernels phase has no CPU form (the Pallas
+kernels carry no interpret path); tests/test_chip_compile.py compiles
+them for a described chip instead.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+import jax
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke as cs  # noqa: E402
+from h2o3_tpu.utils import compile_cache  # noqa: E402
+
+SEED = 7
+MAX_FAST_ROWS = 8192
+
+
+@pytest.fixture(scope="module")
+def trained(cloud8):
+    return cs.phase_train(20_000, 3, SEED, on_chip=False)
+
+
+@pytest.fixture(scope="module")
+def predicted(trained, tmp_path_factory):
+    _, model, fr, X = trained
+    with pytest.MonkeyPatch.context() as mp:
+        # the frame must exceed the fast path's row ceiling
+        mp.setenv("H2O3_SCORE_FASTPATH_MAX_ROWS", str(MAX_FAST_ROWS))
+        return cs.phase_predict(model, fr, X, SEED,
+                                str(tmp_path_factory.mktemp("mojo")),
+                                slice_rows=4096, check_rows=500)
+
+
+def test_ingest_phase(cloud8, tmp_path):
+    rec = cs.phase_ingest(5_000, SEED, str(tmp_path))
+    assert rec["tokenized_bytes"] == rec["csv_bytes"] > 0
+    assert not os.listdir(tmp_path)         # the CSV is removed again
+
+
+def test_train_phase(trained):
+    rec = trained[0]
+    assert rec["train_auc"] > cs.AUC_MIN
+    assert (rec["cols"], rec["depth"], rec["nbins"]) == (28, 8, 255)
+    assert rec["pallas_kernels_traced"] == []   # the XLA twins ran here
+
+
+def test_predict_phase(predicted):
+    for path in ("large_frame_path", "fast_path"):
+        assert predicted[path]["compared"] == 500
+        assert predicted[path]["max_abs_dev"] < 2e-5
+
+
+def test_serve_phase(trained, predicted):
+    _, model, _, X = trained
+    rec = cs.phase_serve(model, X, predicted["p_full"], sizes=(1, 64, 300),
+                         repeats=3)
+    assert rec["trace_error_fallbacks"] == 0
+    assert sorted(rec["requests"]) == ["1", "300", "64"]
+
+
+@pytest.mark.parametrize("check", ["kernel_parity_check",
+                                   "optin_parity_check"])
+def test_parity_harness_with_the_twins_standing_in(check, monkeypatch):
+    """The kernels phase cannot run here, but its harness can: with each
+    Pallas entry point replaced by unpack + its XLA twin, every case must
+    line up (argument order, slicing, shapes) and deviate by exactly 0 —
+    found on the CPU, not on chip time."""
+    from h2o3_tpu.ops import hist_pallas as HP, parity
+
+    def unpack(cp):
+        return HP.unpack_codes(cp, c_pad=cp.shape[0] * HP.PACK)
+
+    def hist(cp, heap, stats, int8=False, **kw):
+        return HP.sbh_hist_xla(unpack(cp), heap, stats, **kw)
+
+    def route(cp, heap, tbl, rf, valtab=None, F=None, **kw):
+        h, f = HP.sbh_route_xla(unpack(cp), heap, tbl, rf, valtab, F, **kw)
+        return h, (f if kw.get("emit_f") else None)
+
+    def fused(cp, heap, tbl, rf, stats, *, base_r, L_r, base_h, L_h,
+              n_bins, any_cat, na_code, int8=False, radix=False):
+        nh, _ = route(cp, heap, tbl, rf, base=base_r, L=L_r,
+                      any_cat=any_cat, na_code=na_code)
+        return nh, hist(cp, nh, stats, base=base_h, L=L_h, n_bins=n_bins,
+                        half=True)
+
+    for name in ("sbh_hist_pallas", "sbh_hist_pallas_i8", "sbh_hist_radix"):
+        monkeypatch.setattr(HP, name, hist)
+    monkeypatch.setattr(HP, "sbh_route_pallas", route)
+    monkeypatch.setattr(HP, "sbh_route_hist_fused_pallas", fused)
+    devs = getattr(parity, check)(SEED)
+    assert len(devs) >= 13 and max(devs.values()) == 0
+
+
+def _run(args, cwd=REPO, **env):
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", **env}
+    return subprocess.run([sys.executable, *args], cwd=cwd, env=env,
+                          capture_output=True, text=True, timeout=600)
+
+
+def test_four_chip_phase_on_four_virtual_devices():
+    """Rehearsal (b): the --chips 4 path on a 4-device CPU mesh, in a
+    process of its own (the suite's cloud has 8)."""
+    r = _run(["-c", "import json, chip_smoke as cs; print(json.dumps("
+              f"cs.phase_four_chips(20000, 2, {SEED}, on_chip=False)))"],
+             XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    assert r.returncode == 0, r.stderr[-3000:]
+    rec = json.loads(r.stdout.strip().splitlines()[-1])
+    assert rec["all_reduces_per_tree"] == cs.DEPTH
+    assert min(rec["split_agreement_per_tree"]) > 0.95
+    assert rec["train_4"]["train_auc"] > cs.AUC_MIN
+
+
+@pytest.mark.parametrize("args", [[], ["--chips", "4"]])
+def test_script_fails_without_an_accelerator(args):
+    r = _run(["chip_smoke.py", *args])
+    assert r.returncode != 0
+    assert '"ok"' not in r.stdout
+    assert "no accelerator" in r.stderr
+
+
+# ---- the compile-cache helper ---------------------------------------------
+def test_cache_dir_is_fixed_inside_the_checkout(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert compile_cache.cache_dir() == os.path.join(REPO, ".jax_cache")
+
+
+def test_cache_dir_honours_the_variable(monkeypatch, tmp_path):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert compile_cache.enable() == str(tmp_path)
+    # JAX read the variable itself; code set nothing
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_enable_places_the_cache_only_off_the_cpu(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.enable() is None       # the suite's CPU backend
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        assert compile_cache.enable() == compile_cache.cache_dir()
+        assert jax.config.jax_compilation_cache_dir == \
+            os.path.join(REPO, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
