@@ -21,6 +21,7 @@ TPU-native design:
 from __future__ import annotations
 
 import copy
+import functools
 import math
 import time
 from dataclasses import dataclass, field
@@ -34,8 +35,39 @@ from h2o3_tpu.core.frame import Frame, Vec, T_CAT, T_NUM
 from h2o3_tpu.core.jobs import Job
 from h2o3_tpu.core.kvstore import DKV
 from h2o3_tpu.models import metrics as M
+from h2o3_tpu.obs import metrics as _om
+from h2o3_tpu.obs.timeline import SPANS as _SPANS, span as _span
 from h2o3_tpu.parallel import mesh as _mesh
 from h2o3_tpu.parallel import compat as _compat
+
+# the operator's scoring rate by rate() on /metrics — the companion of
+# h2o3_gbm_row_trees_total for training. path = "bucket" (the compiled-
+# scorer cache served it) or "frame" (the model's own whole-frame path)
+_PREDICT_CALLS = _om.counter(
+    "h2o3_predict_calls_total",
+    "completed Model.predict() calls, by algorithm and scoring path")
+_PREDICT_ROWS = _om.counter(
+    "h2o3_predict_rows_total",
+    "rows scored by completed Model.predict() calls, by algorithm and "
+    "scoring path")
+
+
+def _predict_root(fn):
+    """Wrap a family's predict() in the `predict` root span and the two
+    counters; ModelBase applies it to its own predict and, through
+    __init_subclass__, to every override."""
+    @functools.wraps(fn)
+    def predict(self, test_data, *args, **kwargs):
+        rows = int(getattr(test_data, "nrows", 0) or 0)
+        with _span("predict", model=self.key, algo=self.algo,
+                   frame=getattr(test_data, "key", None), rows=rows,
+                   cols=int(getattr(test_data, "ncols", 0) or 0),
+                   path="frame") as sp:
+            out = fn(self, test_data, *args, **kwargs)
+        _PREDICT_CALLS.inc(algo=self.algo, path=sp.attrs["path"])
+        _PREDICT_ROWS.inc(rows, algo=self.algo, path=sp.attrs["path"])
+        return out
+    return predict
 
 
 # ===========================================================================
@@ -406,6 +438,11 @@ class ModelBase:
         self._dinfo: Optional[DataInfo] = None
         self.key: Optional[str] = None
 
+    def __init_subclass__(cls, **kwargs):
+        super().__init_subclass__(**kwargs)
+        if "predict" in cls.__dict__:
+            cls.predict = _predict_root(cls.__dict__["predict"])
+
     # ---- public training entrypoint (H2OEstimator.train) ----------------
     def train(self, x=None, y=None, training_frame=None, validation_frame=None,
               **overrides) -> "ModelBase":
@@ -434,7 +471,8 @@ class ModelBase:
             if int(self.params["nfolds"] or 0) > 1 or self.params.get("fold_column"):
                 self._run_cross_validation(frame, x, y, job)
             self._fit(frame, job)
-            self._score_train_valid(frame, validation_frame)
+            with job.phase("metrics"):
+                self._score_train_valid(frame, validation_frame)
             self._output.run_time_ms = int(1000 * (time.time() - t0))
             # release validation scoring state: the margins/design matrix
             # would otherwise pin device memory for the model's lifetime
@@ -451,14 +489,15 @@ class ModelBase:
         # can score the new one (modelmon owns the try/except — a failed
         # profile must never fail the train)
         from h2o3_tpu.obs import modelmon as _modelmon
-        _modelmon.install_baseline(self, frame)
-        DKV.put(self.key, self)
-        # optional serving pre-warm on publish (H2O3_SCORER_PREWARM=1):
-        # compile the most common row bucket in the background so the
-        # first real request warm-hits instead of paying the compile
         from h2o3_tpu import serving
-        if serving.prewarm_enabled():
-            serving.prewarm(self)
+        with _span("model.publish", model=self.key):
+            _modelmon.install_baseline(self, frame)
+            DKV.put(self.key, self)
+            # optional serving pre-warm on publish (H2O3_SCORER_PREWARM=1):
+            # compile the most common row bucket in the background so the
+            # first real request warm-hits instead of paying the compile
+            if serving.prewarm_enabled():
+                serving.prewarm(self)
         return self
 
     def _resolve_predictors(self, frame, x, y):
@@ -594,6 +633,7 @@ class ModelBase:
         d = self._dinfo.response_domain if self._dinfo else None
         return len(d) if d else 1
 
+    @_predict_root
     def predict(self, test_data: Frame) -> Frame:
         out = self._score_host(test_data)
         return self._prediction_frame(out, test_data.nrows)
@@ -602,13 +642,29 @@ class ModelBase:
         """Score a frame and fetch the result to host in ONE device→host
         transfer. Serving-sized frames ride the compiled-scorer cache (no
         recompile per row count); large frames take the legacy sharded
-        path, whose compile cost amortizes over the batch."""
+        path, whose compile cost amortizes over the batch. The stages of
+        that path are the children of the `predict` root span; the cache's
+        own are scorer.warm_hit / scorer.compile."""
         from h2o3_tpu import serving
         from h2o3_tpu.parallel import mrtask as _mrt
         out = serving.score_frame(self, test_data)
-        if out is None:
+        if out is not None:
+            root = _SPANS.current()
+            if root is not None and root.name == "predict":
+                root.attrs["path"] = "bucket"
+            return out
+        with _span("predict.matrix"):       # adapt + stack + concat dispatch
             X = self._dinfo.matrix(test_data)
-            out = _mrt.host_fetch(self._score_matrix(X))
+        with _span("predict.dispatch"):     # returns at enqueue
+            out = self._score_matrix(X)
+        with _span("predict.wait"):
+            # the host blocked on the device; the fetch blocked here anyway
+            # h2o3-ok: R002 the span IS the wait: it splits device time from the fetch that follows
+            jax.block_until_ready(out)
+        with _span("predict.fetch") as sp:
+            # h2o3-ok: R002,R015 the span IS the device→host fetch
+            out = _mrt.host_fetch(out)
+            sp.attrs["bytes"] = int(out.nbytes)
         return out
 
     def _prediction_columns(self, out: np.ndarray, n: int) -> list:
@@ -631,14 +687,17 @@ class ModelBase:
     def _prediction_frame(self, out: np.ndarray, n: int) -> Frame:
         """Build the predictions Frame from host scores."""
         names, vecs = [], []
-        for name, vals, dom in self._prediction_columns(out, n):
-            if dom is not None:
-                vecs.append(Vec._from_floats(vals, np.zeros(n, bool),
-                                             T_CAT, np.asarray(dom, object)))
-            else:
-                vecs.append(Vec.from_numpy(vals))
-            names.append(name)
-        return Frame(names, vecs)
+        with _span("predict.frame") as sp:
+            for name, vals, dom in self._prediction_columns(out, n):
+                if dom is not None:
+                    vecs.append(Vec._from_floats(
+                        vals, np.zeros(n, bool), T_CAT,
+                        np.asarray(dom, object)))
+                else:
+                    vecs.append(Vec.from_numpy(vals))
+                names.append(name)
+            sp.attrs["cols"] = len(names)
+            return Frame(names, vecs)
 
     def model_performance(self, test_data: Optional[Frame] = None):
         if test_data is None:

@@ -117,7 +117,8 @@ def _quantize(X, edges, *, b_val, c_pad, n_pad, sharding=None):
         code = jnp.searchsorted(e, x, side="left").astype(jnp.int32)
         return jnp.where(jnp.isnan(x), b_val, code)
 
-    codes = jax.vmap(one_col, in_axes=(1, 0), out_axes=0)(X, edges)
+    with jax.named_scope("bin.search"):
+        codes = jax.vmap(one_col, in_axes=(1, 0), out_axes=0)(X, edges)
     codes = jnp.clip(codes, 0, b_val).astype(jnp.uint8)  # (C, n)
     out = jnp.zeros((c_pad, n_pad), jnp.uint8)
     out = lax.dynamic_update_slice(out, codes, (0, 0))
@@ -412,16 +413,21 @@ class BinnedGrower:
         #                                dtype: i32 when int8 — sibling
         #                                subtraction stays exact)
         did_prev = None                # split mask of level d-1
+        # jax.named_scope below is metadata only: it names the stages of
+        # the K-tree program in a device trace (tree.level.hist /
+        # .route_hist / .split / .nodes, tree.margin) and changes neither
+        # the program nor its persistent-cache key
         for d in range(D):
             L = 1 << d
             base = L - 1
             if d == 0:
-                hacc = hist_fn(codes, heap, stats_in, base=base, L=L,
-                               n_bins=BP, radix=self.use_radix)[:L, :C]
-                if self.axis_name:
-                    # the ScoreBuildHistogram reduce: merge shard-local
-                    # histograms in one collective per level
-                    hacc = lax.psum(hacc, self.axis_name)
+                with jax.named_scope("tree.level.hist"):
+                    hacc = hist_fn(codes, heap, stats_in, base=base, L=L,
+                                   n_bins=BP, radix=self.use_radix)[:L, :C]
+                    if self.axis_name:
+                        # the ScoreBuildHistogram reduce: merge shard-local
+                        # histograms in one collective per level
+                        hacc = lax.psum(hacc, self.axis_name)
             else:
                 # ONE fused-or-sequential pass: route the previous level's
                 # splits, then (sibling subtraction) histogram LEFT
@@ -431,84 +437,90 @@ class BinnedGrower:
                 # parent - left: routing moves every row of a split leaf,
                 # so parent = left + right exactly; unsplit parents are
                 # masked to zero (their child slots are dead).
-                heap, left = HP.sbh_route_hist(
-                    codes, heap, prev["tbl"], prev["route_f"], stats_in,
-                    base_r=(L >> 1) - 1, L_r=L >> 1, base_h=base, L_h=L,
-                    n_bins=BP, any_cat=any_cat, na_code=spec.b_val,
-                    int8=self.int8, fused=self.fused,
-                    radix=self.use_radix)
-                left = left[: L >> 1, :C]
-                if self.axis_name:
-                    # psum BEFORE subtraction: hist_prev is already global
-                    left = lax.psum(left, self.axis_name)
-                par = jnp.where(did_prev[:, None, None, None],
-                                hist_prev, jnp.zeros_like(hist_prev))
-                right = par - left
-                hacc = jnp.stack([left, right], axis=1) \
-                    .reshape(L, *left.shape[1:])
+                with jax.named_scope("tree.level.route_hist"):
+                    heap, left = HP.sbh_route_hist(
+                        codes, heap, prev["tbl"], prev["route_f"], stats_in,
+                        base_r=(L >> 1) - 1, L_r=L >> 1, base_h=base, L_h=L,
+                        n_bins=BP, any_cat=any_cat, na_code=spec.b_val,
+                        int8=self.int8, fused=self.fused,
+                        radix=self.use_radix)
+                with jax.named_scope("tree.level.hist"):
+                    left = left[: L >> 1, :C]
+                    if self.axis_name:
+                        # psum BEFORE subtraction: hist_prev is already
+                        # global
+                        left = lax.psum(left, self.axis_name)
+                    par = jnp.where(did_prev[:, None, None, None],
+                                    hist_prev, jnp.zeros_like(hist_prev))
+                    right = par - left
+                    hacc = jnp.stack([left, right], axis=1) \
+                        .reshape(L, *left.shape[1:])
             hist_prev = hacc
             hist = hacc.astype(jnp.float32) * inv[None, None, :, None] \
                 if self.int8 else hacc
 
-            if mtries and mtries < c_real:
-                r = jax.random.uniform(jax.random.fold_in(key, d),
-                                       (L, C))
-                r = jnp.where(jnp.arange(C) < c_real, r, 2.0)
-                kth = jnp.sort(r, axis=1)[:, mtries - 1:mtries]
-                cmask = r <= kth
-            else:
-                cmask = jnp.broadcast_to(
-                    (jnp.arange(C) < c_real)[None], (L, C))
-            if tree_mask is not None:
-                # col_sample_rate_per_tree: a whole-tree column subset drawn
-                # by the caller (SharedTree _rand per-tree cols analog)
-                cmask = cmask & tree_mask[None, :]
+            with jax.named_scope("tree.level.split"):
+                if mtries and mtries < c_real:
+                    r = jax.random.uniform(jax.random.fold_in(key, d),
+                                           (L, C))
+                    r = jnp.where(jnp.arange(C) < c_real, r, 2.0)
+                    kth = jnp.sort(r, axis=1)[:, mtries - 1:mtries]
+                    cmask = r <= kth
+                else:
+                    cmask = jnp.broadcast_to(
+                        (jnp.arange(C) < c_real)[None], (L, C))
+                if tree_mask is not None:
+                    # col_sample_rate_per_tree: a whole-tree column subset
+                    # drawn by the caller (SharedTree _rand per-tree cols
+                    # analog)
+                    cmask = cmask & tree_mask[None, :]
 
-            s = find_splits_binned(
-                hist, self.is_cat_dev, self.mono, cmask, lo, hi,
-                b_val=spec.b_val, min_rows=self.min_rows, msi=self.msi,
-                lam=self.lam, use_hess=self.use_hess, any_cat=any_cat)
+                s = find_splits_binned(
+                    hist, self.is_cat_dev, self.mono, cmask, lo, hi,
+                    b_val=spec.b_val, min_rows=self.min_rows, msi=self.msi,
+                    lam=self.lam, use_hess=self.use_hess, any_cat=any_cat)
 
-            did = s["did"]
-            did_prev = did
-            ids = jnp.arange(L)
-            tgt = base + ids
-            colA = colA.at[tgt].set(jnp.where(did, s["col"], -1))
-            binA = binA.at[tgt].set(jnp.where(did, s["bin"], -1))
-            nalA = nalA.at[tgt].set(s["nal"])
-            routeA = routeA.at[tgt].set(s["route"])
-            valA = valA.at[tgt].set(s["val_t"])
-            coverA = coverA.at[tgt].set(s["w_t"])
-            kidL = jnp.where(did, 2 * tgt + 1, self.nodes)
-            kidR = jnp.where(did, 2 * tgt + 2, self.nodes)
-            valA = valA.at[kidL].set(s["val_l"], mode="drop")
-            valA = valA.at[kidR].set(s["val_r"], mode="drop")
-            coverA = coverA.at[kidL].set(s["w_l"], mode="drop")
-            coverA = coverA.at[kidR].set(s["w_t"] - s["w_l"], mode="drop")
-            gains = gains.at[jnp.where(did, s["col"], C)].add(s["gain"])
+            with jax.named_scope("tree.level.nodes"):
+                did = s["did"]
+                did_prev = did
+                ids = jnp.arange(L)
+                tgt = base + ids
+                colA = colA.at[tgt].set(jnp.where(did, s["col"], -1))
+                binA = binA.at[tgt].set(jnp.where(did, s["bin"], -1))
+                nalA = nalA.at[tgt].set(s["nal"])
+                routeA = routeA.at[tgt].set(s["route"])
+                valA = valA.at[tgt].set(s["val_t"])
+                coverA = coverA.at[tgt].set(s["w_t"])
+                kidL = jnp.where(did, 2 * tgt + 1, self.nodes)
+                kidR = jnp.where(did, 2 * tgt + 2, self.nodes)
+                valA = valA.at[kidL].set(s["val_l"], mode="drop")
+                valA = valA.at[kidR].set(s["val_r"], mode="drop")
+                coverA = coverA.at[kidL].set(s["w_l"], mode="drop")
+                coverA = coverA.at[kidR].set(s["w_t"] - s["w_l"], mode="drop")
+                gains = gains.at[jnp.where(did, s["col"], C)].add(s["gain"])
 
-            # ---- routing tables for the next level -----------------------
-            Lp = max(8, L)
-            tbl = jnp.zeros((8, Lp), jnp.float32)
-            tbl = tbl.at[0, :L].set(s["col"].astype(jnp.float32))
-            tbl = tbl.at[1, :L].set(did.astype(jnp.float32))
-            tbl = tbl.at[2, :L].set(s["bin"].astype(jnp.float32))
-            tbl = tbl.at[3, :L].set(s["nal"].astype(jnp.float32))
-            route_f = jnp.zeros((Lp, BP), jnp.float32)
-            route_f = route_f.at[:L].set(s["route"].astype(jnp.float32))
-            prev = dict(tbl=tbl, route_f=route_f)
+                # ---- routing tables for the next level -------------------
+                Lp = max(8, L)
+                tbl = jnp.zeros((8, Lp), jnp.float32)
+                tbl = tbl.at[0, :L].set(s["col"].astype(jnp.float32))
+                tbl = tbl.at[1, :L].set(did.astype(jnp.float32))
+                tbl = tbl.at[2, :L].set(s["bin"].astype(jnp.float32))
+                tbl = tbl.at[3, :L].set(s["nal"].astype(jnp.float32))
+                route_f = jnp.zeros((Lp, BP), jnp.float32)
+                route_f = route_f.at[:L].set(s["route"].astype(jnp.float32))
+                prev = dict(tbl=tbl, route_f=route_f)
 
-            # ---- monotone bounds for children ----------------------------
-            mc = self.mono[s["col"]]
-            mid = 0.5 * (s["val_l"] + s["val_r"])
-            lo_l = jnp.where(mc < 0, jnp.maximum(lo, mid), lo)
-            hi_l = jnp.where(mc > 0, jnp.minimum(hi, mid), hi)
-            lo_r = jnp.where(mc > 0, jnp.maximum(lo, mid), lo)
-            hi_r = jnp.where(mc < 0, jnp.minimum(hi, mid), hi)
-            lo = jnp.stack([jnp.where(did, lo_l, lo),
-                            jnp.where(did, lo_r, lo)], 1).reshape(2 * L)
-            hi = jnp.stack([jnp.where(did, hi_l, hi),
-                            jnp.where(did, hi_r, hi)], 1).reshape(2 * L)
+                # ---- monotone bounds for children ------------------------
+                mc = self.mono[s["col"]]
+                mid = 0.5 * (s["val_l"] + s["val_r"])
+                lo_l = jnp.where(mc < 0, jnp.maximum(lo, mid), lo)
+                hi_l = jnp.where(mc > 0, jnp.minimum(hi, mid), hi)
+                lo_r = jnp.where(mc > 0, jnp.maximum(lo, mid), lo)
+                hi_r = jnp.where(mc < 0, jnp.minimum(hi, mid), hi)
+                lo = jnp.stack([jnp.where(did, lo_l, lo),
+                                jnp.where(did, lo_r, lo)], 1).reshape(2 * L)
+                hi = jnp.stack([jnp.where(did, hi_l, hi),
+                                jnp.where(did, hi_r, hi)], 1).reshape(2 * L)
 
             if level_cb is not None:
                 # eager instrumentation only (bench per-level breakdown):
@@ -518,12 +530,15 @@ class BinnedGrower:
 
         # terminal pass: route the last level + fused F update
         L = 1 << D
-        valt = jnp.clip(valA, -clip_val, clip_val) if clip_val else valA
-        valtab = jnp.zeros((8, nodes_p), jnp.float32).at[0, : self.nodes]             .set(valt)
-        heap, F = HP.sbh_route(codes, heap, prev["tbl"], prev["route_f"],
-                               valtab, F, base=(L >> 1) - 1, L=L >> 1,
-                               eta=eta, emit_f=True, any_cat=any_cat,
-                               na_code=spec.b_val)
+        with jax.named_scope("tree.margin"):
+            valt = jnp.clip(valA, -clip_val, clip_val) if clip_val else valA
+            valtab = jnp.zeros((8, nodes_p), jnp.float32) \
+                .at[0, : self.nodes].set(valt)
+            heap, F = HP.sbh_route(codes, heap, prev["tbl"],
+                                   prev["route_f"], valtab, F,
+                                   base=(L >> 1) - 1, L=L >> 1, eta=eta,
+                                   emit_f=True, any_cat=any_cat,
+                                   na_code=spec.b_val)
         return dict(col=colA, bin=binA, nal=nalA, route=routeA, val=valt,
                     cover=coverA, gains=gains[:C], F=F, heap=heap)
 
@@ -688,23 +703,26 @@ def gbm_chunk_trainer(grower: BinnedGrower, n: int, *, dist: str, eta: float,
                     # decorrelate row sampling across shards; the mtries key
                     # kt stays common so every shard draws the SAME col masks
                     ks = jax.random.fold_in(ks, lax.axis_index(axis))
-                g, h = _grad_hess_binned(dist, F, y1)
-                if sample_rate < 1.0:
-                    u = jax.random.uniform(ks, w1.shape)
-                    wt = w1 * (u < sample_rate)
-                else:
-                    wt = w1
-                stats = jnp.stack(
-                    [wt, wt * g, wt * h, jnp.zeros_like(wt)], axis=0)
-                tmask = _tree_col_mask(grower, jax.random.fold_in(kt, 7),
-                                       col_rate_tree)
+                with jax.named_scope("tree.grad"):
+                    g, h = _grad_hess_binned(dist, F, y1)
+                with jax.named_scope("tree.sample"):
+                    if sample_rate < 1.0:
+                        u = jax.random.uniform(ks, w1.shape)
+                        wt = w1 * (u < sample_rate)
+                    else:
+                        wt = w1
+                    stats = jnp.stack(
+                        [wt, wt * g, wt * h, jnp.zeros_like(wt)], axis=0)
+                    tmask = _tree_col_mask(
+                        grower, jax.random.fold_in(kt, 7), col_rate_tree)
                 out = grower.grow(codes, stats, F, eta=eta, clip_val=cv,
                                   key=kt, mtries=mtries, tree_mask=tmask)
                 F = out["F"]
-                tree = (out["col"], out["bin"], out["nal"],
-                        pack_route(out["route"], grower.spec.n_bins,
-                                   grower.spec.b_val),
-                        out["val"], out["gains"], out["cover"])
+                with jax.named_scope("tree.pack"):
+                    tree = (out["col"], out["bin"], out["nal"],
+                            pack_route(out["route"], grower.spec.n_bins,
+                                       grower.spec.b_val),
+                            out["val"], out["gains"], out["cover"])
                 return (F, key), tree
 
             (F, _), trees = lax.scan(per_tree, (F, key),

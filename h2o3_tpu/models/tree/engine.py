@@ -208,43 +208,52 @@ def find_best_splits(hist, mn, mx, min_rows, min_split_improvement,
 def _level_step(X, stats, w_in, leaf, heap, active, colA, thrA, nalA, valA,
                 gains, col_mask, key, *, d, B, mtries,
                 min_rows, min_split_improvement, reg_lambda=0.0):
+    # the named scopes are metadata: they name the level's stages in a
+    # device trace and change neither the program nor its cache key
     L = 2 ** d
     C = X.shape[1]
-    in_sample = active & (w_in > 0)
-    lv = jnp.where(in_sample, leaf, L)
-    mn, mx = leaf_ranges(X, lv, L)
-    bins = bin_rows(X, lv, mn, mx, B)
-    hist = build_histograms(bins, lv, stats, L, B)
-    if mtries > 0 and mtries < C:
-        # per-leaf mtries column sampling (DRF per-node semantics)
-        r = jax.random.uniform(jax.random.fold_in(key, d), (L, C))
-        kth = jnp.sort(r, axis=1)[:, mtries - 1:mtries]
-        cmask = (r <= kth) & col_mask[None, :]
-    else:
-        cmask = jnp.broadcast_to(col_mask[None, :], (L, C))
-    did, gain, bcol, thr, nal, lw, lwy = find_best_splits(
-        hist, mn, mx, min_rows, min_split_improvement, cmask, B,
-        reg_lambda=reg_lambda)
-    base = 2 ** d - 1
-    lvl_val = jnp.where(lw > 0, lwy / jnp.maximum(lw, 1e-30), 0.0)
-    colA = jax.lax.dynamic_update_slice(
-        colA, jnp.where(did, bcol, -1).astype(jnp.int32), (base,))
-    thrA = jax.lax.dynamic_update_slice(thrA, thr, (base,))
-    nalA = jax.lax.dynamic_update_slice(nalA, nal, (base,))
-    valA = jax.lax.dynamic_update_slice(valA, lvl_val.astype(jnp.float32),
-                                        (base,))
-    gains = gains.at[bcol].add(jnp.where(did, jnp.maximum(gain, 0.0), 0.0))
+    with jax.named_scope("tree.level.bin"):
+        in_sample = active & (w_in > 0)
+        lv = jnp.where(in_sample, leaf, L)
+        mn, mx = leaf_ranges(X, lv, L)
+        bins = bin_rows(X, lv, mn, mx, B)
+    with jax.named_scope("tree.level.hist"):
+        hist = build_histograms(bins, lv, stats, L, B)
+    with jax.named_scope("tree.level.split"):
+        if mtries > 0 and mtries < C:
+            # per-leaf mtries column sampling (DRF per-node semantics)
+            r = jax.random.uniform(jax.random.fold_in(key, d), (L, C))
+            kth = jnp.sort(r, axis=1)[:, mtries - 1:mtries]
+            cmask = (r <= kth) & col_mask[None, :]
+        else:
+            cmask = jnp.broadcast_to(col_mask[None, :], (L, C))
+        did, gain, bcol, thr, nal, lw, lwy = find_best_splits(
+            hist, mn, mx, min_rows, min_split_improvement, cmask, B,
+            reg_lambda=reg_lambda)
+    with jax.named_scope("tree.level.nodes"):
+        base = 2 ** d - 1
+        lvl_val = jnp.where(lw > 0, lwy / jnp.maximum(lw, 1e-30), 0.0)
+        colA = jax.lax.dynamic_update_slice(
+            colA, jnp.where(did, bcol, -1).astype(jnp.int32), (base,))
+        thrA = jax.lax.dynamic_update_slice(thrA, thr, (base,))
+        nalA = jax.lax.dynamic_update_slice(nalA, nal, (base,))
+        valA = jax.lax.dynamic_update_slice(
+            valA, lvl_val.astype(jnp.float32), (base,))
+        gains = gains.at[bcol].add(
+            jnp.where(did, jnp.maximum(gain, 0.0), 0.0))
     # route ALL rows in split nodes (OOB rows included — they need the tree's
     # prediction), freeze rows in terminal nodes
-    c = bcol[leaf]
-    t = thr[leaf]
-    x = jnp.take_along_axis(X, c[:, None], axis=1)[:, 0]
-    isna = jnp.isnan(x)
-    go_right = jnp.where(isna, ~nal[leaf], x > t)
-    splits = did[leaf] & active
-    leaf = jnp.where(splits, 2 * leaf + go_right.astype(jnp.int32), 0)
-    heap = jnp.where(splits, 2 * heap + 1 + go_right.astype(jnp.int32), heap)
-    active = splits
+    with jax.named_scope("tree.level.route"):
+        c = bcol[leaf]
+        t = thr[leaf]
+        x = jnp.take_along_axis(X, c[:, None], axis=1)[:, 0]
+        isna = jnp.isnan(x)
+        go_right = jnp.where(isna, ~nal[leaf], x > t)
+        splits = did[leaf] & active
+        leaf = jnp.where(splits, 2 * leaf + go_right.astype(jnp.int32), 0)
+        heap = jnp.where(splits, 2 * heap + 1 + go_right.astype(jnp.int32),
+                         heap)
+        active = splits
     return leaf, heap, active, colA, thrA, nalA, valA, gains
 
 
@@ -252,12 +261,13 @@ def _level_step(X, stats, w_in, leaf, heap, active, colA, thrA, nalA, valA,
 @functools.partial(jax.jit, static_argnames=("D",))
 def _final_leaves(stats, leaf, active, w_in, valA, *, D):
     L = 2 ** D
-    lv = jnp.where(active & (w_in > 0), leaf, L)
-    sums = jax.ops.segment_sum(stats[:, :2], lv, num_segments=L + 1)[:L]
-    vals = jnp.where(sums[:, 0] > 0,
-                     sums[:, 1] / jnp.maximum(sums[:, 0], 1e-30),
-                     0.0).astype(jnp.float32)
-    return jax.lax.dynamic_update_slice(valA, vals, (2 ** D - 1,))
+    with jax.named_scope("tree.leaves"):
+        lv = jnp.where(active & (w_in > 0), leaf, L)
+        sums = jax.ops.segment_sum(stats[:, :2], lv, num_segments=L + 1)[:L]
+        vals = jnp.where(sums[:, 0] > 0,
+                         sums[:, 1] / jnp.maximum(sums[:, 0], 1e-30),
+                         0.0).astype(jnp.float32)
+        return jax.lax.dynamic_update_slice(valA, vals, (2 ** D - 1,))
 
 
 def gamma_pass(heap, w, res, hess, val, *, nodes, scale=1.0,
@@ -386,31 +396,36 @@ def _ensemble_walk(X, col, thr, nal, val, tw, catbits, iscat, *, depth,
     if has_cat:
         nb = catbits.shape[-1] * 32
 
+    # walk.tree > walk.level / walk.leaf: stable names for the walk's ops in
+    # a device trace (XLA's own — fusion.25 — change with any edit)
     def per_tree(acc, t):
         node = jnp.zeros(n, jnp.int32)
 
         def step(d, node):
-            c = col[t][node]
-            leafish = c < 0
-            cc = jnp.maximum(c, 0)
-            x = jnp.take_along_axis(X, cc[:, None], axis=1)[:, 0]
-            isna = jnp.isnan(x)
-            right = x > thr[t][node]
-            if has_cat:
-                code = jnp.clip(jnp.nan_to_num(x).astype(jnp.int32),
-                                0, nb - 1)
-                word = catbits[t][node, code // 32]
-                bit = (word >> (code % 32).astype(jnp.uint32)) & 1
-                right = jnp.where(iscat[cc], bit == 1, right)
-            right = jnp.where(isna, ~nal[t][node], right)
-            child = 2 * node + 1 + right.astype(jnp.int32)
-            return jnp.where(leafish, node, child)
+            with jax.named_scope("walk.level"):
+                c = col[t][node]
+                leafish = c < 0
+                cc = jnp.maximum(c, 0)
+                x = jnp.take_along_axis(X, cc[:, None], axis=1)[:, 0]
+                isna = jnp.isnan(x)
+                right = x > thr[t][node]
+                if has_cat:
+                    code = jnp.clip(jnp.nan_to_num(x).astype(jnp.int32),
+                                    0, nb - 1)
+                    word = catbits[t][node, code // 32]
+                    bit = (word >> (code % 32).astype(jnp.uint32)) & 1
+                    right = jnp.where(iscat[cc], bit == 1, right)
+                right = jnp.where(isna, ~nal[t][node], right)
+                child = 2 * node + 1 + right.astype(jnp.int32)
+                return jnp.where(leafish, node, child)
 
         node = jax.lax.fori_loop(0, depth, step, node)
-        return acc + tw[t] * val[t][node], None
+        with jax.named_scope("walk.leaf"):
+            return acc + tw[t] * val[t][node], None
 
-    out, _ = jax.lax.scan(per_tree, jnp.zeros(n, jnp.float32),
-                          jnp.arange(col.shape[0]))
+    with jax.named_scope("walk.tree"):
+        out, _ = jax.lax.scan(per_tree, jnp.zeros(n, jnp.float32),
+                              jnp.arange(col.shape[0]))
     return out
 
 

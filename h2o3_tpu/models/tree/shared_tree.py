@@ -181,7 +181,8 @@ class SharedTreeEstimator(ModelBase):
         stride = max(1, n >> 18)
         from h2o3_tpu.parallel import mrtask as _mr
         Xs = _mr.host_fetch(X[::stride][: 1 << 18])
-        spec = BN.make_bins(Xs, is_cat, b_val)
+        with _span("gbm.bin.spec", rows=int(Xs.shape[0]), bins=b_val):
+            spec = BN.make_bins(Xs, is_cat, b_val)
 
         cl = MESH.cloud()
         shards = cl.n_rows_shards
@@ -212,14 +213,17 @@ class SharedTreeEstimator(ModelBase):
         if multi:
             from jax.sharding import PartitionSpec as P
             codes_sh = cl.sharding(P(None, MESH.ROWS))
-        codes = BN.prepare_codes(BN.quantize(X, spec, n_pad=n_pad,
-                                             sharding=codes_sh))
-        y1 = BN.pad_rows(y, n_pad)
-        w1 = BN.pad_rows(w, n_pad)
-        if multi:
-            codes = jax.device_put(codes, codes_sh)
-            y1 = jax.device_put(y1, cl.rows_sharding(1))
-            w1 = jax.device_put(w1, cl.rows_sharding(1))
+        # the DISPATCH of the binning programs (a retrace or an executable
+        # load shows here); the device's share ends the caller's `setup`
+        with _span("gbm.bin.codes", rows=n, cols=C):
+            codes = BN.prepare_codes(BN.quantize(X, spec, n_pad=n_pad,
+                                                 sharding=codes_sh))
+            y1 = BN.pad_rows(y, n_pad)
+            w1 = BN.pad_rows(w, n_pad)
+            if multi:
+                codes = jax.device_put(codes, codes_sh)
+                y1 = jax.device_put(y1, cl.rows_sharding(1))
+                w1 = jax.device_put(w1, cl.rows_sharding(1))
         # register the code plane with the DKV tier pager: training
         # re-streams it every level, so it is pinned (never an LRU victim
         # mid-build) but now VISIBLE to the HBM accounting that budget
@@ -578,12 +582,42 @@ class H2OGradientBoostingEstimator(SharedTreeEstimator):
             f"checkpoint {ckpt} not found or wrong algo"
         return prev
 
+    def _binned_setup_phase(self, frame: Frame, job):
+        """`_binned_setup` as the job's `setup` phase, ended when binning
+        HAS RUN: the phase clock used to stop at the asynchronous dispatch
+        of `_quantize` and read 1.7 % where binning cost 25 %. One sync a
+        train(), where the Σw readback that follows synced anyway."""
+        with job.phase("setup"):   # quantile spec + codes + device_put
+            ctx = self._binned_setup(frame)
+            # h2o3-ok: R002 the phase ends when the device has binned, not when the host has enqueued it
+            jax.block_until_ready(ctx["codes"])
+        return ctx
+
+    @staticmethod
+    def _run_chunk(trainer, *args):
+        """One chunk of trees: build, dispatch, wait — each under its name
+        inside `gbm.chunk`. A new grower is a new jit identity, so every
+        train() traces and lowers the K-tree program again and loads its
+        executable from the cache (seconds at HIGGS size, the device idle
+        meanwhile): `gbm.chunk.build`; lowering and executable are
+        memoized on the trainer per argument signature, as its own call
+        would. `gbm.chunk.wait` ends the `grow` phase when the trees HAVE
+        grown: the history readback of the `score` phase that follows
+        blocked on the same margins, and booked the device's growing
+        time as scoring."""
+        with _span("gbm.chunk.build"):
+            program = trainer.lower(*args).compile()
+        F, trees = program(*args)
+        with _span("gbm.chunk.wait"):
+            # h2o3-ok: R002 the span IS the wait for the chunk's program
+            jax.block_until_ready(F)
+        return F, trees
+
     def _fit_binned(self, frame: Frame, job, dist):
         if dist == "multinomial":
             return self._fit_binned_multinomial(frame, job)
         p = self.params
-        with job.phase("setup"):   # quantile spec + codes + device_put
-            ctx = self._binned_setup(frame)
+        ctx = self._binned_setup_phase(frame, job)
         BN, grower, cl = ctx["BN"], ctx["grower"], ctx["cl"]
         X, y, w, y1, w1 = ctx["X"], ctx["y"], ctx["w"], ctx["y1"], ctx["w1"]
         n, C, n_pad = ctx["n"], ctx["C"], ctx["n_pad"]
@@ -645,7 +679,9 @@ class H2OGradientBoostingEstimator(SharedTreeEstimator):
                     mtries=mtries, k_trees=k, col_rate_tree=col_rate_tree,
                     mesh=ctx["mesh"])
                 key, kc = jax.random.split(key)
-                F, trees = trainer(ctx["codes"], y1, w1, F, kc)
+                # h2o3-ok: R015 its wait IS the rest of the phase: `grow` ends when the trees have grown
+                F, trees = self._run_chunk(trainer, ctx["codes"], y1, w1,
+                                           F, kc)
             E.ROW_TREES.inc(n * k, engine="binned")
             chunks.append(trees)
             done += k
@@ -658,22 +694,23 @@ class H2OGradientBoostingEstimator(SharedTreeEstimator):
             if self._should_stop() or job.budget_exhausted:
                 break
 
-        self._trees, gainsT = self._binned_tree_arrays(ctx, chunks,
-                                                       prev=prev)
-        self._varimp_from_gains(np.asarray(gainsT[:C], np.float64))
-        self._output.model_summary = {
-            "number_of_trees": int(self._trees.ntrees),
-            "max_depth": grower.D, "distribution": dist, "learn_rate": lr,
-            "init_f": f0, "engine": "binned_pallas",
-            "nbins_effective": ctx["spec"].b_val,
-        }
+        with job.phase("finish"):
+            self._trees, gainsT = self._binned_tree_arrays(ctx, chunks,
+                                                           prev=prev)
+            self._varimp_from_gains(np.asarray(gainsT[:C], np.float64))
+            self._output.model_summary = {
+                "number_of_trees": int(self._trees.ntrees),
+                "max_depth": grower.D, "distribution": dist,
+                "learn_rate": lr, "init_f": f0, "engine": "binned_pallas",
+                "nbins_effective": ctx["spec"].b_val,
+            }
 
     def _fit_binned_multinomial(self, frame: Frame, job):
         """K class trees per iteration through ONE jitted binned program
         (the SharedTree.java:548-561 K-tree layer)."""
         self._vstate = None   # no multinomial validation series (yet)
         p = self.params
-        ctx = self._binned_setup(frame)
+        ctx = self._binned_setup_phase(frame, job)
         BN, grower, cl = ctx["BN"], ctx["grower"], ctx["cl"]
         y, w, y1, w1 = ctx["y"], ctx["w"], ctx["y1"], ctx["w1"]
         n, C, n_pad = ctx["n"], ctx["C"], ctx["n_pad"]
@@ -732,7 +769,9 @@ class H2OGradientBoostingEstimator(SharedTreeEstimator):
                     mtries=mtries, k_iters=k, col_rate_tree=col_rate_tree,
                     mesh=ctx["mesh"])
                 key, kc = jax.random.split(key)
-                F, trees = trainer(ctx["codes"], y1, w1, F, kc)
+                # h2o3-ok: R015 its wait IS the rest of the phase: `grow` ends when the trees have grown
+                F, trees = self._run_chunk(trainer, ctx["codes"], y1, w1,
+                                           F, kc)
             E.ROW_TREES.inc(n * k * K, engine="binned")
             chunks.append(trees)
             done += k
@@ -743,21 +782,22 @@ class H2OGradientBoostingEstimator(SharedTreeEstimator):
                 break
 
         # chunks hold (iters, K, ...) arrays; split into per-class ensembles
-        self._trees_k = []
-        gains_tot = None
-        for c in range(K):
-            sel = (lambda a, c=c: a[:, c])
-            ta, g = self._binned_tree_arrays(
-                ctx, chunks, prev=prevs[c] if prevs is not None else None,
-                lead=sel)
-            self._trees_k.append(ta)
-            gains_tot = g if gains_tot is None else gains_tot + g
-        self._varimp_from_gains(np.asarray(gains_tot[:C], np.float64))
-        self._output.model_summary = {
-            "number_of_trees": sum(t.ntrees for t in self._trees_k),
-            "max_depth": grower.D, "distribution": "multinomial",
-            "learn_rate": lr, "engine": "binned_pallas",
-        }
+        with job.phase("finish"):
+            self._trees_k = []
+            gains_tot = None
+            for c in range(K):
+                sel = (lambda a, c=c: a[:, c])
+                ta, g = self._binned_tree_arrays(
+                    ctx, chunks,
+                    prev=prevs[c] if prevs is not None else None, lead=sel)
+                self._trees_k.append(ta)
+                gains_tot = g if gains_tot is None else gains_tot + g
+            self._varimp_from_gains(np.asarray(gains_tot[:C], np.float64))
+            self._output.model_summary = {
+                "number_of_trees": sum(t.ntrees for t in self._trees_k),
+                "max_depth": grower.D, "distribution": "multinomial",
+                "learn_rate": lr, "engine": "binned_pallas",
+            }
 
     def _fit_multinomial(self, X, y, w, job):
         self._vstate = None   # no multinomial validation series (yet)

@@ -15,6 +15,14 @@ TimelineSnapshot analog.
 xprof bridge: when H2O3_OBS_TRACE_DIR is set and a span's name starts with
 H2O3_OBS_TRACE_SPAN, the span also starts/stops a jax.profiler trace —
 deep kernel-level visibility for exactly the region you care about.
+
+One clock with the device: every span() is also a
+`jax.profiler.TraceAnnotation` of the same name, so in ANY profiler
+capture (the bridge's, POST /3/Profiler's, a benchmark's) the program's
+spans lie on the host plane of the same .xplane.pb as the device ops.
+With no capture running the annotation is one level check; the ring and
+its time.time() stamps are what /3/Timeline and /3/Trace/{id} read
+either way.
 """
 
 from __future__ import annotations
@@ -26,6 +34,8 @@ import threading
 import time
 from dataclasses import dataclass, field
 from collections import deque
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from h2o3_tpu.analysis.lockdep import make_lock
 from h2o3_tpu.utils import env as _env
@@ -233,7 +243,10 @@ def span(name: str, **attrs):
     if traced:
         sp.attrs["xprof"] = _xprof_trace_dir()
     try:
-        yield sp
+        # entered after the bridge so that the span which starts a
+        # capture is itself an event of it
+        with _TraceAnnotation(name):
+            yield sp
     finally:
         if traced:
             _stop_trace()

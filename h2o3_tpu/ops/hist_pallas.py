@@ -404,6 +404,7 @@ def sbh_route_pallas(codesP, heap, tbl, route_f, valtab=None, F=None, *,
                                    na_code=na_code)
         newheap = pl.pallas_call(
             kernel,
+            name="sbh_route",
             grid=(nblk,),
             in_specs=[
                 pl.BlockSpec((w_pad, BLOCK_ROWS), lambda j: (0, j)),
@@ -422,6 +423,7 @@ def sbh_route_pallas(codesP, heap, tbl, route_f, valtab=None, F=None, *,
                                na_code=na_code)
     newheap, newF = pl.pallas_call(
         kernel,
+        name="sbh_route_f",
         grid=(nblk,),
         in_specs=[
             pl.BlockSpec((w_pad, BLOCK_ROWS), lambda j: (0, j)),
@@ -515,6 +517,7 @@ def _hist_pallas(codesP, heap, stats, *, base, L, n_bins, half, int8):
                                gwe=gwe, half=half, int8=int8)
     out = pl.pallas_call(
         kernel,
+        name="sbh_hist",
         grid=(npass, ncw, nblk),
         in_specs=[
             pl.BlockSpec((cw, r_blk), lambda p, g, j: (g, j)),
@@ -683,6 +686,7 @@ def sbh_hist_radix(codesP, heap, stats, *, base, L, n_bins, half=False,
                                gwe=gwe, half=half, int8=int8)
     out = pl.pallas_call(
         kernel,
+        name="sbh_hist_radix",
         grid=(ncw, nblk),
         in_specs=[
             pl.BlockSpec((cw, BLOCK_ROWS), lambda g, j: (g, j)),
@@ -788,6 +792,7 @@ def sbh_route_hist_fused_pallas(codesP, heap, tbl, route_f, stats, *,
                                int8=int8, radix=radix)
     newheap, hist = pl.pallas_call(
         kernel,
+        name="sbh_route_hist_fused",
         grid=(nblk,),
         in_specs=[
             pl.BlockSpec((w_pad, BLOCK_ROWS), lambda j: (0, j)),

@@ -333,3 +333,166 @@ def test_glm_irlsm_spans():
     assert "glm.irlsm" in names
     DKV.remove(f.key)
     DKV.remove(m.key)
+
+
+# ---------------------------------------------------------------------------
+# spans inside predict() and train(), one clock with the profiler (ISSUE 27)
+@pytest.fixture(scope="module")
+def small_gbm():
+    """A small binned GBM and the spans and job its train() left."""
+    from h2o3_tpu.core.jobs import jobs_list
+    from h2o3_tpu.models import H2OGradientBoostingEstimator
+    rng = np.random.default_rng(11)
+    n = 400
+    x1, x2 = rng.normal(size=n), rng.normal(size=n)
+    f = Frame.from_dict({
+        "x1": x1, "x2": x2,
+        "y": np.array(["n", "p"], object)[(x1 + x2 > 0).astype(int)]})
+    # the second train() of the process is the one read: in the first,
+    # one-off compiles of eager ops between the phases weigh seconds
+    for _ in range(2):
+        SPANS.clear()
+        m = H2OGradientBoostingEstimator(ntrees=4, max_depth=3, seed=5,
+                                         model_id="obs_small_gbm")
+        m.train(y="y", training_frame=f)
+    spans = SPANS.snapshot()
+    job = [j for j in jobs_list() if j["dest"] == m.key][-1]
+    yield m, f, spans, job
+    DKV.remove(f.key)
+    DKV.remove(m.key)
+
+
+def _predict_rows(path):
+    return REGISTRY.get("h2o3_predict_rows_total").value(algo="gbm",
+                                                         path=path)
+
+
+@pytest.mark.parametrize("path,children", [
+    ("frame", ["predict.matrix", "predict.dispatch", "predict.wait",
+               "predict.fetch", "predict.frame"]),
+    ("bucket", ["predict.frame"])])
+def test_predict_leaves_one_root_span_with_its_stages(
+        small_gbm, monkeypatch, path, children):
+    m, f, _, _ = small_gbm
+    if path == "frame":      # the large-frame walk, at a test's size
+        monkeypatch.setenv("H2O3_SCORE_FASTPATH_MAX_ROWS", "10")
+    other = "bucket" if path == "frame" else "frame"
+    rows0, other0 = _predict_rows(path), _predict_rows(other)
+    calls0 = REGISTRY.get("h2o3_predict_calls_total").value(algo="gbm",
+                                                            path=path)
+    SPANS.clear()
+    pred = m.predict(f)
+    spans = SPANS.snapshot()
+    DKV.remove(pred.key)
+    roots = [s for s in spans if s["name"] == "predict"]
+    assert len(roots) == 1
+    root = roots[0]
+    assert root["parent"] == 0
+    assert root["attrs"] == {"model": m.key, "algo": "gbm", "frame": f.key,
+                             "rows": f.nrows, "cols": f.ncols, "path": path}
+    kids = [s for s in spans if s["parent"] == root["id"]
+            and s["name"].startswith("predict.")]
+    assert [s["name"] for s in kids] == children
+    assert all(s["name"].startswith(("predict.", "scorer."))
+               for s in spans if s["parent"] == root["id"])
+    assert sum(s["duration_ms"] for s in kids) <= root["duration_ms"]
+    assert all(root["start"] <= s["start"] and s["end"] <= root["end"]
+               for s in kids)
+    if path == "frame":
+        fetch = next(s for s in kids if s["name"] == "predict.fetch")
+        assert fetch["attrs"]["bytes"] >= 4 * 2 * f.nrows
+    assert kids[-1]["attrs"]["cols"] == 3      # predict, pn, pp
+    assert _predict_rows(path) == rows0 + f.nrows
+    assert _predict_rows(other) == other0
+    assert REGISTRY.get("h2o3_predict_calls_total").value(
+        algo="gbm", path=path) == calls0 + 1
+
+
+def test_a_predict_override_gets_the_root_span_only():
+    from h2o3_tpu.models import H2OKMeansEstimator
+    from h2o3_tpu.models.model import ModelBase
+    assert H2OKMeansEstimator.predict is not ModelBase.predict
+    rng = np.random.default_rng(2)
+    f = Frame.from_dict({"a": rng.normal(size=60), "b": rng.normal(size=60)})
+    m = H2OKMeansEstimator(k=2, seed=1)
+    m.train(training_frame=f)
+    SPANS.clear()
+    pred = m.predict(f)
+    spans = SPANS.snapshot()
+    roots = [s for s in spans if s["name"] == "predict"]
+    assert len(roots) == 1 and roots[0]["attrs"]["algo"] == "kmeans"
+    assert roots[0]["attrs"]["rows"] == 60
+    assert not [s for s in spans if s["name"] == "predict.frame"]
+    for k in (pred.key, f.key, m.key):
+        DKV.remove(k)
+
+
+def test_train_phases_cover_the_job_and_setup_waits_for_binning(small_gbm):
+    _, _, spans, job = small_gbm
+    ph = job["phases"]
+    assert {"setup", "grow", "score", "finish", "metrics"} <= set(ph)
+    run = next(s for s in spans if s["name"] == "job.run")
+    assert sum(ph.values()) >= 0.90 * run["duration_ms"]
+    assert sum(ph.values()) <= run["duration_ms"] + 1
+    by = {}
+    for s in spans:
+        by.setdefault(s["name"], []).append(s)
+    assert ph["setup"] >= by["gbm.bin.codes"][0]["duration_ms"]
+    # setup holds both binning spans; each chunk its build and its wait
+    setup = by["job.setup"][0]
+    for name in ("gbm.bin.spec", "gbm.bin.codes"):
+        assert by[name][0]["parent"] == setup["id"]
+    chunks = {s["id"] for s in by["gbm.chunk"]}
+    for name in ("gbm.chunk.build", "gbm.chunk.wait"):
+        assert len(by[name]) == len(chunks)
+        assert {s["parent"] for s in by[name]} == chunks
+    # the metrics walk and the publish have a name of their own
+    assert by["job.metrics"][0]["parent"] == run["id"]
+    assert by["model.publish"][0]["start"] >= run["end"]
+
+
+def test_span_without_a_capture_initialises_no_backend():
+    """span() enters a profiler TraceAnnotation: with no capture running
+    that must neither raise nor touch a device — the REST edge opens spans
+    before a cloud is formed, and a process that has not chosen its
+    platform yet must not have it chosen by a span."""
+    import subprocess
+    import sys
+    code = ("from h2o3_tpu.obs.timeline import SPANS, span\n"
+            "with span('t.cold', a=1):\n"
+            "    pass\n"
+            "assert SPANS.snapshot()[-1]['name'] == 't.cold'\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n")
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120)
+    assert p.returncode == 0, p.stderr[-2000:]
+
+
+def test_span_is_an_event_of_the_host_plane_under_a_capture(tmp_path):
+    """One clock: under ANY jax.profiler capture the program's spans lie
+    in the same .xplane.pb as the device ops."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        t0 = time.time()
+        with span("t.on_the_host_plane"):
+            time.sleep(0.01)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    pd = ProfileData.from_file(path)
+    found = [(pl.name, e.duration_ns) for pl in pd.planes for ln in pl.lines
+             for e in ln.events if e.name == "t.on_the_host_plane"]
+    assert len(found) == 1, [pl.name for pl in pd.planes]
+    plane, dur_ns = found[0]
+    assert "host" in plane.lower() and not plane.startswith("/device:")
+    ring = SPANS.snapshot()[-1]
+    assert ring["name"] == "t.on_the_host_plane" and ring["start"] >= t0
+    # the event and the ring's span time the same block
+    assert abs(dur_ns / 1e6 - ring["duration_ms"]) < 5.0
