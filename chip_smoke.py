@@ -8,6 +8,7 @@ depth 8, bernoulli; only the tree count is cut):
 
     device  -> jax.devices() must be a TPU, else exit non-zero
     kernels -> Pallas kernels == their XLA twins at HIGGS width, on chip
+    walk    -> the dense scoring walk == the gather walk, leaf for leaf
     ingest  -> seeded CSV through h2o3_tpu.import_file (native tokenizer)
     train   -> 11M x 28 Frame -> H2OGradientBoostingEstimator.train
     predict -> large-frame sharded path AND compiled-scorer fast path,
@@ -53,6 +54,8 @@ NTREES = 10                  # cut (bench.py trains 50): keeps a cold run to min
 INGEST_ROWS = 1_000_000
 SLICE_ROWS = 1_000_000       # <= scorer_cache._max_rows(): the fast path
 CHECK_ROWS = 10_000          # rows compared with the host scorer, per path
+WALK_ROWS = 1_000_000        # rows walked by both bodies of the scoring walk
+ONE_ROW_REQUESTS = 300       # 1-row REST requests behind the median
 FOUR_CHIP_ROWS = 4_000_000   # cut so the 4-chip + 1-chip pair fits one call
 FOUR_CHIP_NTREES = 5
 # Training AUC floor, fixed from the generator below: the true logit
@@ -157,6 +160,58 @@ def phase_kernels(seed: int) -> dict:
     devs = kernel_parity_check(seed=seed)
     return {"checks": len(devs), "max_dev": max(devs.values()),
             "kernels": sorted({k for k, _ in HP.kernel_traces()})}
+
+
+def phase_walk_exact(rows: int, ntrees: int, seed: int) -> dict:
+    """engine._walk_dense against engine._walk_gather on this device, at
+    HIGGS width and depth 8: thresholds drawn from the data's own values
+    (so `x == thr` happens), early leaves, NaN and ±inf among the rows.
+    Tree by tree the value walked to is the NODE's own number, so `==`
+    is leaf for leaf; then once more with random values and weights, the
+    ensemble's sum bit for bit. The CPU's matmul is exact whatever its
+    operands; only the chip can show that its bfloat16 products select a
+    feature's bytes exactly."""
+    import jax.numpy as jnp
+    from h2o3_tpu.models.tree import engine as E
+    assert E._walk_path(DEPTH, COLS, False) == "dense"
+    rng = np.random.default_rng(seed + 2)
+    X, _ = higgs_like(rows, seed)
+    for v, p in ((np.nan, 0.01), (np.inf, 0.001), (-np.inf, 0.001),
+                 (0.0, 0.001), (-0.0, 0.001)):
+        X[rng.random(X.shape) < p] = v
+    nodes, inner = 2 ** (DEPTH + 1) - 1, 2 ** DEPTH - 1
+    col = rng.integers(0, COLS, size=(ntrees, nodes)).astype(np.int32)
+    col[:, inner:] = -1
+    col[:, 1:inner][rng.random((ntrees, inner - 1)) < 0.1] = -1
+    thr = X[rng.integers(0, rows, size=col.shape), np.maximum(col, 0)]
+    nal = rng.random(col.shape) < 0.5
+    ids = np.broadcast_to(np.arange(nodes, dtype=np.float32), col.shape)
+    no_bits = (jnp.zeros((1, 1, 1), jnp.uint32), jnp.zeros(1, bool))
+    Xd = jnp.asarray(X)
+    tbl = [jnp.asarray(a) for a in (col, thr, nal)]
+
+    def both(val, tw):
+        args = (Xd, *tbl, jnp.asarray(val), jnp.asarray(tw))
+        return (np.asarray(E._walk_dense(*args, depth=DEPTH)),
+                np.asarray(E._walk_gather(*args, *no_bits, depth=DEPTH,
+                                          has_cat=False)))
+
+    reached = set()
+    for t in range(ntrees):
+        dense, gather = both(ids, np.eye(ntrees, dtype=np.float32)[t])
+        assert np.array_equal(dense, gather), \
+            (t, int((dense != gather).sum()))
+        reached.update(np.unique(gather).astype(int).tolist())
+    on_thr = int((X[:, col[0, 0]] == thr[0, 0]).sum())
+    assert on_thr >= 1 and len(reached) > inner // 2, (on_thr, len(reached))
+    t0 = time.perf_counter()
+    dense, gather = both(rng.standard_normal(col.shape).astype(np.float32),
+                         (rng.random(ntrees) + 0.5).astype(np.float32))
+    assert np.array_equal(dense, gather), int((dense != gather).sum())
+    return {"rows": rows, "cols": COLS, "ntrees": ntrees, "depth": DEPTH,
+            "nodes_reached": len(reached), "rows_on_root_thr": on_thr,
+            "nonfinite_cells": int((~np.isfinite(X)).sum()),
+            "both_bodies_s": round(time.perf_counter() - t0, 2)}
 
 
 def write_csv(path: str, X, y):
@@ -349,12 +404,15 @@ def phase_predict(model, fr, X, seed: int, out_dir: str,
 
 
 def phase_serve(model, X, p_full, sizes=(1, 64, 4096), repeats: int = 5,
+                one_row_repeats: int = ONE_ROW_REQUESTS,
                 timeout_s: float = 600.0) -> dict:
     """REST scoring in this process: the server on a free port, the
     client on a worker thread. The serving layer answers from the legacy
     scorer when its fast path raises (degrade, don't 500) — so a 200
     alone proves nothing: the trace-error fallback counter must stay at
-    zero and the model must not be strike-parked."""
+    zero and the model must not be strike-parked. A 1-row request is
+    repeated `one_row_repeats` times: its warm median is the serving
+    path's latency reading (PERF.md §6)."""
     from h2o3_tpu.api.server import start_server
     from h2o3_tpu.serving import scorer_cache as sc
 
@@ -370,7 +428,7 @@ def phase_serve(model, X, p_full, sizes=(1, 64, 4096), repeats: int = 5,
         for n in sizes:
             body = json.dumps({"columns": FEATURES,
                                "rows": X[:n].tolist()}).encode()
-            for _ in range(repeats):
+            for _ in range(one_row_repeats if n == 1 else repeats):
                 req = urllib.request.Request(
                     url, data=body, method="POST",
                     headers={"Content-Type": "application/json"})
@@ -515,6 +573,8 @@ def main(argv=None) -> int:
     else:
         t0 = time.perf_counter()
         _emit("kernels", t0, phase_kernels(args.seed))
+        t0 = time.perf_counter()
+        _emit("walk", t0, phase_walk_exact(WALK_ROWS, NTREES, args.seed))
         t0 = time.perf_counter()
         _emit("ingest", t0, phase_ingest(INGEST_ROWS, args.seed, OUT_DIR))
         t0 = time.perf_counter()

@@ -41,12 +41,20 @@ import numpy as np
 
 from h2o3_tpu.obs import metrics as _om
 from h2o3_tpu.parallel import compat as _compat
+from h2o3_tpu.parallel import mesh as _mesh
 from h2o3_tpu.obs.timeline import span as _span
 
 # Σ rows·trees processed — the headline GBM throughput numerator; bench.py
 # and /metrics read the same counter (per-ensemble rate = Δcounter/Δt)
 ROW_TREES = _om.counter("h2o3_gbm_row_trees_total",
                         "rows x trees processed by the tree engines")
+# which body of the scoring walk a predict_ensemble call took (a call made
+# under an outer jit counts once, when that program is traced)
+WALKS = _om.counter(
+    "h2o3_tree_walk_total",
+    "predict_ensemble calls by the scoring walk's body: path=dense (every "
+    "node of a level, no per-row index) or gather (categorical or deep "
+    "trees)")
 _LEVEL_SECONDS = _om.histogram(
     "h2o3_tree_level_seconds",
     "per-level wall time of the tree engines, labeled by engine "
@@ -384,14 +392,153 @@ jax.tree_util.register_pytree_node(TreeArrays, _trees_flatten,
                                    _trees_unflatten)
 
 
-@_compat.guard_collective
+# ---- the scoring walk -------------------------------------------------------
+# Two bodies of one algorithm; `_walk_path` picks by shape, at trace time.
+#
+# dense: no per-row index anywhere. A tile of rows meets EVERY node of a tree
+# with dense arithmetic: the node's feature is selected on the MXU by a
+# one-hot product over the BYTES of the f32 features (exact for every bit
+# pattern, NaN and ±inf included); the top levels are matched together
+# against the tree's path matrix, on the MXU again; the levels under them
+# by a position one-hot on the VPU. It does 2^depth node evaluations a row
+# and tree where the gather body does `depth` dependent gathers. One v5e
+# retires 24-54 M gather steps a second and 100-176 G dense evaluations
+# (2,750,000 x 28; PERF.md §6, PR 28): at depth 8 the dense body is 207 x
+# the faster, at depth 14 5.9 x, and the two would cross at depth 16-17.
+# _DENSE_MAX_CELLS bounds (2^depth - 1) x columns, the size of a tree's
+# selection matrix: depth 14 at 28 columns, the deepest shape measured, is
+# the last to take the dense body.
+_DENSE_MAX_CELLS = 1 << 19
+# levels 0.._PATH_LEVELS-1 are matched in ONE (256, 256) product; a level
+# walked by position instead costs a compare, a select and a lane reduction
+# over (rows, 2^level): 15 ms a frame at depth 8, where the product is ~0
+_PATH_LEVELS = 8
+# rows of a tile x nodes of a tree (at least a vreg row of 128 lanes): a
+# tile's (rows, nodes) f32 intermediates are 32 MB each, whatever the depth
+# (2^21..2^24 read within 6 % of each other)
+_WALK_TILE_CELLS = 1 << 23
+
+
+def _walk_path(depth: int, n_cols: int, has_cat: bool) -> str:
+    """Which body scores this shape: "dense" or "gather"."""
+    dense = not has_cat and depth >= 1 \
+        and ((1 << depth) - 1) * n_cols <= _DENSE_MAX_CELLS
+    return "dense" if dense else "gather"
+
+
+def _path_matrix(levels: int) -> np.ndarray:
+    """(2^levels, 2^levels) in {-1, 0, +1}: column p is the path from the
+    root to position p of level `levels` — +1 where it turns right at node
+    j, -1 where it turns left, 0 off the path. Nodes are numbered from 1
+    (node j's children are 2j and 2j + 1; row 0 is unused), so a row of ±1
+    decisions times this matrix reads `levels` exactly at the position the
+    row reaches, and less everywhere else."""
+    W = 1 << levels
+    P = np.zeros((W, W), np.float32)
+    pos = np.arange(W)
+    for d in range(levels):
+        node = (1 << d) + (pos >> (levels - d))
+        P[node, pos] = 2.0 * ((pos >> (levels - d - 1)) & 1) - 1.0
+    return P
+
+
+def _perfect_tree(col, thr, nal, val, n_cols, depth):
+    """The (T, nodes) heap arrays as a perfect tree of `depth` levels with
+    nodes numbered from 1 (level d is [2^d, 2^(d+1)): every level starts at
+    a multiple of its own width; slot 0 is a node no path visits). An early
+    leaf keeps any route and its value is pushed down to every bottom slot
+    under it, so a row always takes `depth` steps and ends on the value the
+    gather walk would have stopped at. Returns the byte-select matrices
+    (T, 2C, 2^depth) bf16, thr and na_left (T, 2^depth), and the bottom
+    level's values (T, 2^depth)."""
+    inner = (1 << depth) - 1
+    T = col.shape[0]
+    stopped = col[:, :1] < 0
+    leafv = val[:, :1]
+    for d in range(1, depth + 1):
+        lo, hi = (1 << d) - 1, (1 << (d + 1)) - 1
+        above = jnp.repeat(stopped, 2, axis=1)
+        leafv = jnp.where(above, jnp.repeat(leafv, 2, axis=1), val[:, lo:hi])
+        stopped = above | (col[:, lo:hi] < 0)
+
+    def from_one(a, fill):
+        return jnp.concatenate(
+            [jnp.full((T, 1), fill, a.dtype), a[:, :inner]], axis=1)
+
+    cols = jnp.arange(n_cols, dtype=col.dtype)[None, :, None]
+    sel = (from_one(col, -1)[:, None, :] == cols).astype(jnp.bfloat16)
+    # [low byte | high byte] of a 16-bit half -> low + 256 * high
+    sel2 = jnp.concatenate([sel, 256 * sel], axis=1)
+    return sel2, from_one(thr, 0), from_one(nal, False), leafv
+
+
+# the two bodies are jitted for the tests that set one against the other;
+# inside `_ensemble_walk` a nested jit inlines
+@functools.partial(jax.jit, static_argnames=("depth",))
+def _walk_dense(X, col, thr, nal, val, tw, *, depth):
+    """Σ_t tw[t] · value[t, leaf_t(row)] with no per-row index: bit for bit
+    what `_walk_gather` returns for numeric-only trees."""
+    n, C = X.shape
+    L = 1 << depth
+    top = min(depth, _PATH_LEVELS)
+    Wt = 1 << top
+    sel2, thr1, nal1, leafv = _perfect_tree(col, thr, nal, val, C, depth)
+    paths = jnp.asarray(_path_matrix(top), jnp.bfloat16)
+    t = max(1, min(n, _WALK_TILE_CELLS // max(L, 128)))
+
+    def tile(i, out):
+        s = jnp.minimum(i * t, n - t)   # the last tile overlaps the one before
+        bits = jax.lax.bitcast_convert_type(
+            jax.lax.dynamic_slice_in_dim(X, s, t, axis=0), jnp.int32)
+        # the four bytes of every feature: whole numbers under 256 are
+        # exact in bfloat16, and a one-hot column picks ONE of them, so the
+        # f32 accumulator holds low + 256 * high of a 16-bit half exactly
+        b = [((bits >> k) & 0xFF).astype(jnp.bfloat16)
+             for k in (0, 8, 16, 24)]
+        halves = (jnp.concatenate(b[:2], axis=1),
+                  jnp.concatenate(b[2:], axis=1))
+
+        def per_tree(acc, tree):
+            s2, th, na, lv, w = tree
+            with jax.named_scope("walk.level"):
+                lo, hi = (jnp.dot(h, s2, preferred_element_type=jnp.float32)
+                          .astype(jnp.int32) for h in halves)
+                x = jax.lax.bitcast_convert_type((hi << 16) | lo,
+                                                 jnp.float32)
+                right = jnp.where(jnp.isnan(x), ~na[None, :],
+                                  x > th[None, :])
+                # levels 0..top-1 at once: the row's ±1 decisions match the
+                # path to exactly one position of level `top` in all of them
+                turn = jnp.where(right[:, :Wt], 1.0, -1.0) \
+                    .astype(jnp.bfloat16)
+                at = jnp.dot(turn, paths,
+                             preferred_element_type=jnp.float32) == top
+                if top < depth:
+                    pos = jnp.sum(jnp.where(at, jnp.arange(Wt)[None, :], 0),
+                                  axis=1)
+                    for d in range(top, depth):
+                        W = 1 << d
+                        here = jnp.arange(W)[None, :] == pos[:, None]
+                        pos = 2 * pos + jnp.any(right[:, W:2 * W] & here,
+                                                axis=1)
+                    at = jnp.arange(L)[None, :] == pos[:, None]
+            with jax.named_scope("walk.leaf"):
+                v = jnp.sum(jnp.where(at, lv[None, :], 0.0), axis=1)
+                return acc + w * v, None
+
+        with jax.named_scope("walk.tree"):
+            acc, _ = jax.lax.scan(per_tree, jnp.zeros(t, jnp.float32),
+                                  (sel2, thr1, nal1, leafv, tw))
+        return jax.lax.dynamic_update_slice_in_dim(out, acc, s, axis=0)
+
+    return jax.lax.fori_loop(0, -(-n // t), tile, jnp.zeros(n, jnp.float32))
+
+
 @functools.partial(jax.jit, static_argnames=("depth", "has_cat"))
-def _ensemble_walk(X, col, thr, nal, val, tw, catbits, iscat, *, depth,
-                   has_cat):
-    """Module-level jitted gather walk: cached per (shapes, depth, has_cat)
-    signature. Defining this as a closure inside predict_ensemble gave the
-    jit a fresh function identity per call — every single ensemble predict
-    retraced AND recompiled, which dominated serving latency."""
+def _walk_gather(X, col, thr, nal, val, tw, catbits, iscat, *, depth,
+                 has_cat):
+    """Σ_t tw[t] · value[t, leaf_t(row)] by a fixed-depth chain of gathers
+    per tree: categorical SET splits and deep trees."""
     n = X.shape[0]
     if has_cat:
         nb = catbits.shape[-1] * 32
@@ -429,10 +576,49 @@ def _ensemble_walk(X, col, thr, nal, val, tw, catbits, iscat, *, depth,
     return out
 
 
+def _rows_mesh(X):
+    """The mesh whose rows axis X's rows are sharded over, or None (one
+    shard; a tracer, which carries no placement). The dense body's tile
+    loop slices rows, so over a sharded X it runs once per shard
+    (shard_map) and no shard asks for another's rows."""
+    sh = getattr(X, "sharding", None)
+    # h2o3-ok: R025 a placement, not a value: a concrete array's sharding is host metadata and a tracer has none (-> None, the unsharded program)
+    if isinstance(sh, jax.sharding.NamedSharding) and len(sh.spec) \
+            and sh.spec[0] == _mesh.ROWS and sh.mesh.shape[_mesh.ROWS] > 1:
+        return sh.mesh
+    return None
+
+
+@_compat.guard_collective
+@functools.partial(jax.jit, static_argnames=("depth", "has_cat", "mesh"))
+def _ensemble_walk(X, col, thr, nal, val, tw, catbits, iscat, *, depth,
+                   has_cat, mesh=None):
+    """Module-level jitted scoring walk: cached per (shapes, depth, has_cat)
+    signature. Defining this as a closure inside predict_ensemble gave the
+    jit a fresh function identity per call — every single ensemble predict
+    retraced AND recompiled, which dominated serving latency. The shape
+    picks the body (`_walk_path`); the XLA module is `jit__ensemble_walk`
+    either way. `mesh`: where X's rows are sharded (`_rows_mesh`)."""
+    if _walk_path(depth, X.shape[1], has_cat) == "gather":
+        return _walk_gather(X, col, thr, nal, val, tw, catbits, iscat,
+                            depth=depth, has_cat=has_cat)
+    dense = functools.partial(_walk_dense, depth=depth)
+    if mesh is not None:
+        P = jax.sharding.PartitionSpec
+        dense = jax.shard_map(dense, mesh=mesh, out_specs=P(_mesh.ROWS),
+                              in_specs=(P(_mesh.ROWS),) + (P(),) * 5,
+                              check_vma=False)
+    return dense(X, col, thr, nal, val, tw)
+
+
 def predict_ensemble(X, trees: TreeArrays, weights=None):
-    """Σ_t value[t, leaf_t(row)] — fixed-depth gather walk per tree.
-    Categorical SET-split nodes route by bitset membership of the level id
-    (hex/genmodel GenModel.bitSetContains analog)."""
+    """Σ_t value[t, leaf_t(row)]. Numeric-only ensembles of moderate depth
+    are scored densely, every node of a tree for a tile of rows
+    (`_walk_dense`); categorical SET splits — a node routes by bitset
+    membership of the level id (hex/genmodel GenModel.bitSetContains
+    analog) — and deep trees take a fixed-depth gather walk per tree
+    (`_walk_gather`). The two agree bit for bit; `_walk_path` picks from
+    (depth, columns, has-categoricals) and h2o3_tree_walk_total counts it."""
     col = jnp.asarray(trees.col)
     thr = jnp.asarray(trees.thr)
     nal = jnp.asarray(trees.na_left)
@@ -448,8 +634,11 @@ def predict_ensemble(X, trees: TreeArrays, weights=None):
         # fixed dummy shapes so the no-cat program signature is stable
         catbits = jnp.zeros((1, 1, 1), jnp.uint32)
         iscat = jnp.zeros(1, bool)
+    path = _walk_path(trees.depth, X.shape[1], has_cat)
+    WALKS.inc(path=path)
     return _ensemble_walk(X, col, thr, nal, val, tw, catbits, iscat,
-                          depth=trees.depth, has_cat=has_cat)
+                          depth=trees.depth, has_cat=has_cat,
+                          mesh=_rows_mesh(X) if path == "dense" else None)
 
 
 @_compat.guard_collective

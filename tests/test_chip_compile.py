@@ -15,6 +15,10 @@ inside a module-scoped, non-autouse fixture; nothing touches the TPU
 library at import, in a skipif condition or in a parametrize argument;
 and every such compile lives in THIS one file.
 
+Also here: the scoring walk's dense body (engine._ensemble_walk) at the
+benchmark's frame, 2,750,000 x 28, for its two ensembles — XLA, no kernel
+— on one chip and row-sharded over the host's four.
+
 Left out on purpose: `sbh_hist_radix` at 32 columns, which the compiler
 refuses (RESOURCE_EXHAUSTED ... vmem ... f32[1,32,64,16]) after ~3 minutes
 — the reason the radix family is opt-in; `radix_not_default` pins that no
@@ -32,6 +36,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from h2o3_tpu.models.tree import binned as BN
+from h2o3_tpu.models.tree import engine as E
 from h2o3_tpu.ops import hist_pallas as HP
 from h2o3_tpu.ops import parity
 from h2o3_tpu.parallel import mesh as MESH
@@ -214,3 +219,47 @@ def test_compiles_for_v5e_at_higgs_width(name, topo, sds,
         n_ar = len(re.findall(r" all-reduce(?:-start)?\(", text))
         assert n_ar == DEPTH, n_ar
         assert "all-gather" not in text and "all-to-all" not in text
+
+
+SCORE_ROWS = 2_750_000      # a quarter of HIGGS: the benchmark's frame
+
+
+def _walk_args(sds_of, rows, ntrees, depth):
+    nodes = 2 ** (depth + 1) - 1
+    tbl = [sds_of((ntrees, nodes), d, P())
+           for d in (jnp.int32, jnp.float32, jnp.bool_, jnp.float32)]
+    return (sds_of((rows, C_REAL), jnp.float32, P(MESH.ROWS)), *tbl,
+            sds_of((ntrees,), jnp.float32, P()),
+            sds_of((1, 1, 1), jnp.uint32, P()), sds_of((1,), jnp.bool_, P()))
+
+
+@pytest.mark.parametrize("ntrees,depth,chips", [(10, 8, 1), (20, 5, 1),
+                                                (10, 8, 4)])
+def test_dense_walk_compiles_for_v5e_at_frame_size(ntrees, depth, chips,
+                                                   topo, sds,
+                                                   no_persistent_cache):
+    """gbm_higgs (10 x depth 8) and gbm_higgs_defaults (20 x depth 5): the
+    shape picks the dense body, the program holds no gather, keeps its
+    name, and a tile's intermediates stay far under a frame-sized one
+    (2,750,000 x 256 f32 is 2.8 GB)."""
+    assert E._walk_path(depth, C_REAL, False) == "dense"
+    mesh = None
+    if chips == 1:
+        args = _walk_args(lambda shape, dt, _: sds(shape, dt), SCORE_ROWS,
+                          ntrees, depth)
+    else:
+        mesh = Mesh(np.array(topo.devices).reshape(-1, 1),
+                    (MESH.ROWS, MESH.MODEL))
+        args = _walk_args(
+            lambda shape, dt, spec: jax.ShapeDtypeStruct(
+                shape, dt, sharding=NamedSharding(mesh, spec)),
+            MESH.Cloud(mesh).padded_rows(SCORE_ROWS), ntrees, depth)
+    compiled = E._ensemble_walk.__wrapped__.lower(
+        *args, depth=depth, has_cat=False, mesh=mesh).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit__ensemble_walk")
+    assert not re.search(r" gather\(", text)
+    for op in ("all-gather", "all-reduce", "all-to-all",
+               "collective-permute"):
+        assert op not in text, op       # each chip walks its own rows
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20

@@ -49,6 +49,12 @@ def test_ingest_phase(cloud8, tmp_path):
     assert not os.listdir(tmp_path)         # the CSV is removed again
 
 
+def test_walk_phase():
+    rec = cs.phase_walk_exact(20_000, 3, SEED)
+    assert (rec["cols"], rec["depth"]) == (28, 8)
+    assert rec["nodes_reached"] > 127 and rec["nonfinite_cells"] > 0
+
+
 def test_train_phase(trained):
     rec = trained[0]
     assert rec["train_auc"] > cs.AUC_MIN
@@ -65,7 +71,8 @@ def test_predict_phase(predicted):
 def test_serve_phase(trained, predicted):
     _, model, _, X = trained
     rec = cs.phase_serve(model, X, predicted["p_full"], sizes=(1, 64, 300),
-                         repeats=3)
+                         repeats=3, one_row_repeats=4)
+    assert rec["requests"]["1"]["count"] == 4
     assert rec["trace_error_fallbacks"] == 0
     assert sorted(rec["requests"]) == ["1", "300", "64"]
 
