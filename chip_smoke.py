@@ -12,7 +12,9 @@ depth 8, bernoulli; only the tree count is cut):
     ingest  -> seeded CSV through h2o3_tpu.import_file (native tokenizer)
     train   -> 11M x 28 Frame -> H2OGradientBoostingEstimator.train
     predict -> large-frame sharded path AND compiled-scorer fast path,
-               both against the exported artifact scored on the host
+               both against the exported artifact scored on the host;
+               the large path's prediction frame (planes made on the
+               device) == the frame built on the host from the same scores
     serve   -> REST POST /3/Predictions/models/{m}, 1 / 64 / 4096 rows
 
     python chip_smoke.py            # one chip: every phase above
@@ -55,6 +57,9 @@ INGEST_ROWS = 1_000_000
 SLICE_ROWS = 1_000_000       # <= scorer_cache._max_rows(): the fast path
 CHECK_ROWS = 10_000          # rows compared with the host scorer, per path
 WALK_ROWS = 1_000_000        # rows walked by both bodies of the scoring walk
+PARITY_ROWS = 1_100_003      # device-built frame == host-built: over the fast
+                             # path's 2^20 rows, not a multiple of the padding
+ONE_ROW_MS_PR28 = (7.41, 7.66)   # PERF.md's 1-row medians, to read "1" against
 ONE_ROW_REQUESTS = 300       # 1-row REST requests behind the median
 FOUR_CHIP_ROWS = 4_000_000   # cut so the 4-chip + 1-chip pair fits one call
 FOUR_CHIP_NTREES = 5
@@ -339,11 +344,78 @@ def _host_scores(model, X, idx, out_dir: str) -> np.ndarray:
     return out["probs"][:, 1]
 
 
+def _frame_parity(model, X, rows: int, seed: int) -> dict:
+    """The large-frame predict()'s frame — planes one device program made
+    from the walk's own output, nothing fetched — against the frame
+    _prediction_frame builds on the HOST from the fetched copy of the
+    same scores: every column through Vec.to_numpy(), type and domain,
+    over rows with NaN and ±inf cells and a row count the padding does
+    not divide. Then the rows no model produces (an all-NaN score row, a
+    tie): the device's argmax must follow NumPy's rules on this chip."""
+    import jax
+    import jax.numpy as jnp
+    import h2o3_tpu
+    from h2o3_tpu.obs import metrics as om
+    from h2o3_tpu.serving import scorer_cache as sc
+    assert rows > sc._max_rows(), (rows, sc._max_rows())
+    rng = np.random.default_rng(seed + 3)
+    Xp = X[:rows].copy()
+    for v, p in ((np.nan, 0.01), (np.inf, 0.001), (-np.inf, 0.001)):
+        Xp[rng.random(Xp.shape) < p] = v
+    fr = _frame(Xp)
+    assert fr.padded_len > rows, (fr.padded_len, rows)
+
+    def made():
+        m = om.REGISTRY.get("h2o3_predict_frame_columns_total")
+        return [m.value(algo="gbm", columns=w) for w in ("device", "host")]
+
+    def same(dev, host):
+        assert dev.names == host.names and dev.nrows == host.nrows == rows
+        for name in dev.names:
+            a, b = dev.vec(name), host.vec(name)
+            assert isinstance(a.data, jax.Array) and a.type == b.type
+            assert (a.domain is None) == (b.domain is None)
+            x, y = a.to_numpy(), b.to_numpy()
+            assert x.dtype == y.dtype and x.shape == y.shape == (rows,)
+            assert np.array_equal(x, y, equal_nan=True), \
+                (name, int((x != y).sum()))
+
+    made0 = made()
+    h2o3_tpu.remove(model.predict(fr).key)              # compile / load
+    t0 = time.perf_counter()
+    dev = model.predict(fr)
+    t_warm = time.perf_counter() - t0
+    assert made() == [made0[0] + 2, made0[1]], (made0, made())
+    scores = model._score_host(fr)
+    host = model._prediction_frame(scores, rows)
+    assert made() == [made0[0] + 2, made0[1] + 1], (made0, made())
+    same(dev, host)
+    p1 = dev.vec("ps").to_numpy()
+    assert np.isfinite(p1).all()        # a tree routes NaN/inf, never emits it
+    planted = jnp.asarray(scores).at[1].set(jnp.nan).at[2].set(0.5) \
+        .at[3, 1].set(jnp.nan)
+    dev_p = model._prediction_frame(planted, rows)
+    host_p = model._prediction_frame(np.asarray(planted), rows)
+    same(dev_p, host_p)
+    lab = dev_p.vec("predict").to_numpy()
+    assert (lab[1], lab[2], lab[3]) == (0.0, 0.0, 1.0), lab[:4]
+    assert np.isnan(dev_p.vec("ps").to_numpy()[[1, 3]]).all()
+    for k in (dev.key, host.key, dev_p.key, host_p.key, fr.key):
+        h2o3_tpu.remove(k)
+    return {"rows": rows, "padded": int(fr.padded_len),
+            "nonfinite_cells": int((~np.isfinite(Xp)).sum()),
+            "columns_compared": 3, "planted_rows": 3,
+            "predict_warm_s": round(t_warm, 4)}
+
+
 def phase_predict(model, fr, X, seed: int, out_dir: str,
-                  slice_rows: int, check_rows: int) -> dict:
-    """Both branches of ModelBase._score_host against the host scorer:
+                  slice_rows: int, check_rows: int,
+                  parity_rows: int = PARITY_ROWS) -> dict:
+    """Both branches of ModelBase._score_device against the host scorer:
     the whole frame (over the fast path's row ceiling -> the sharded
-    large-frame walk) and a slice under it (the compiled-scorer cache)."""
+    large-frame walk, its prediction frame made on the device) and a
+    slice under it (the compiled-scorer cache); then the large path's
+    frame against the host-built frame of the same scores."""
     import h2o3_tpu
     from h2o3_tpu.serving import scorer_cache as sc
     rows = fr.nrows
@@ -400,7 +472,9 @@ def phase_predict(model, fr, X, seed: int, out_dir: str,
             "seconds_warm": round(t_warm, 3), "compared": int(idx_s.size),
             "max_abs_dev": float(np.abs(p_slice[idx_s] - want_s).max()),
             **_since(c0)}
-    return {"large_frame_path": full, "fast_path": fast, "p_full": p_full}
+    return {"large_frame_path": full, "fast_path": fast,
+            "frame_parity": _frame_parity(model, X, parity_rows, seed),
+            "p_full": p_full}
 
 
 def phase_serve(model, X, p_full, sizes=(1, 64, 4096), repeats: int = 5,
@@ -458,6 +532,7 @@ def phase_serve(model, X, p_full, sizes=(1, 64, 4096), repeats: int = 5,
                  round(1e3 * v[0], 2),
                  "median_warm_ms": round(1e3 * float(np.median(v[1:])), 3)}
         for n, v in lat.items()},
+        "one_row_median_warm_ms_pr28": list(ONE_ROW_MS_PR28),
         "trace_error_fallbacks": trace_errors() - err0, **_since(c0)}
 
 

@@ -32,7 +32,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -113,6 +113,16 @@ def _decode_f32(data: jax.Array, codec: Codec, mask: Optional[jax.Array]):
     if mask is not None:
         x = jnp.where(mask != 0, jnp.float32(jnp.nan), x)
     return x
+
+
+class DevicePlanes(NamedTuple):
+    """One column as a device program packed it, ready to be a Vec's
+    storage: `data` (padded,) in the codec's dtype with NAs zeroed, `mask`
+    (padded,) uint8 with NAs AND the padding rows (>= `nrows`) set."""
+    data: jax.Array
+    mask: jax.Array
+    codec: Codec
+    nrows: int
 
 
 # one resident wrapper: a per-call jax.jit(_decode_f32) in as_f32 rebuilt
@@ -243,8 +253,19 @@ class Vec:
         # cached_jit: pack's closure is (pad, n) ints, so repeated
         # device-munger hand-offs at one size reuse one program
         packed, dmask = _mr.cached_jit(pack, out_shardings=(sh, sh))(col_j)
+        return Vec.from_device_planes(
+            DevicePlanes(packed, dmask, Codec("f32"), n), vtype, domain)
+
+    @staticmethod
+    def from_device_planes(planes: DevicePlanes, vtype=T_NUM,
+                           domain=None) -> "Vec":
+        """Adopt planes a device program already packed (the program that
+        made them may have made a whole frame's in one dispatch): no
+        dispatch here, no host copy, no host mirror — the pager fetches
+        codec bytes only if it ever demotes the chunk."""
         dom = np.asarray(domain, dtype=object) if domain is not None else None
-        return Vec(packed, Codec("f32"), dmask, n, vtype, dom)
+        return Vec(planes.data, planes.codec, planes.mask, planes.nrows,
+                   vtype, dom)
 
     @staticmethod
     def _from_strings(col: np.ndarray, force_type=None, domain=None) -> "Vec":

@@ -31,7 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from h2o3_tpu.core.frame import Frame, Vec, T_CAT, T_NUM
+from h2o3_tpu.core.frame import (Codec, DevicePlanes, Frame, Vec, T_CAT,
+                                 T_NUM)
 from h2o3_tpu.core.jobs import Job
 from h2o3_tpu.core.kvstore import DKV
 from h2o3_tpu.models import metrics as M
@@ -50,6 +51,12 @@ _PREDICT_ROWS = _om.counter(
     "h2o3_predict_rows_total",
     "rows scored by completed Model.predict() calls, by algorithm and "
     "scoring path")
+# how often the prediction frame is built without a host round trip
+_FRAME_COLUMNS = _om.counter(
+    "h2o3_predict_frame_columns_total",
+    "prediction frames built, by algorithm and by where their columns were "
+    "made: columns=device (planes from the device scores, one program) or "
+    "host (float64 host columns, packed and put)")
 
 
 def _predict_root(fn):
@@ -68,6 +75,32 @@ def _predict_root(fn):
         _PREDICT_ROWS.inc(rows, algo=self.algo, path=sp.attrs["path"])
         return out
     return predict
+
+
+def _prediction_planes(n: int, label: Optional[str]):
+    """The device program that packs raw scores into a prediction frame's
+    planes, [(data, mask), ...] in column order, each as long as the scores
+    (the scored frame's padded length) — what `_prediction_columns`
+    computes on the host, in the storage `Vec._from_floats` would choose
+    for it: `label` None (regression, `out` is (rows,)) gives the one score
+    plane; "i8" / "f32" (a classifier, `out` is (rows, K)) the label in
+    that storage — argmax with NumPy's rules: the first maximum, a NaN
+    counts as one — then a plane per class. A score is its own float32
+    with NaN as NA; rows >= n are NA."""
+    def planes(out):
+        padding = jnp.arange(out.shape[0]) >= n
+
+        def num(p):
+            p = p.astype(jnp.float32)
+            na = padding | jnp.isnan(p)
+            return jnp.where(na, 0.0, p), na.astype(jnp.uint8)
+        if label is None:
+            return [num(out)]
+        lab = jnp.where(padding, 0, jnp.argmax(out, axis=1))
+        lab = lab.astype(jnp.int8 if label == "i8" else jnp.float32)
+        return [(lab, padding.astype(jnp.uint8))] \
+            + [num(out[:, k]) for k in range(out.shape[1])]
+    return planes
 
 
 # ===========================================================================
@@ -635,18 +668,25 @@ class ModelBase:
 
     @_predict_root
     def predict(self, test_data: Frame) -> Frame:
-        out = self._score_host(test_data)
-        return self._prediction_frame(out, test_data.nrows)
+        n = test_data.nrows
+        # large frame: the columns, made on the device ahead of the wait;
+        # bucket path: the scorer cache's host scores as they are
+        out = self._score_device(
+            test_data, then=lambda out: self._prediction_columns(out, n))
+        return self._prediction_frame(out, n)
 
-    def _score_host(self, test_data: Frame) -> np.ndarray:
-        """Score a frame and fetch the result to host in ONE device→host
-        transfer. Serving-sized frames ride the compiled-scorer cache (no
-        recompile per row count); large frames take the legacy sharded
-        path, whose compile cost amortizes over the batch. The stages of
-        that path are the children of the `predict` root span; the cache's
-        own are scorer.warm_hit / scorer.compile."""
+    def _score_device(self, test_data: Frame, then=None):
+        """Score a frame and leave the result where it was computed.
+        Serving-sized frames ride the compiled-scorer cache (no recompile
+        per row count), which answers with HOST scores: those come back
+        as they are. Large frames take the legacy sharded path, whose
+        compile cost amortizes over the batch, and come back as the device
+        array, computed: `then(out)` — what the caller makes of the scores
+        on the device — is enqueued behind the walk inside
+        `predict.dispatch`, and `predict.wait` blocks on its result. The
+        stages of that path are the children of the `predict` root span;
+        the cache's own are scorer.warm_hit / scorer.compile."""
         from h2o3_tpu import serving
-        from h2o3_tpu.parallel import mrtask as _mrt
         out = serving.score_frame(self, test_data)
         if out is not None:
             root = _SPANS.current()
@@ -657,46 +697,94 @@ class ModelBase:
             X = self._dinfo.matrix(test_data)
         with _span("predict.dispatch"):     # returns at enqueue
             out = self._score_matrix(X)
+            if then is not None:
+                out = then(out)
         with _span("predict.wait"):
-            # the host blocked on the device; the fetch blocked here anyway
-            # h2o3-ok: R002 the span IS the wait: it splits device time from the fetch that follows
+            # the host blocked on the device: the caller gets results,
+            # not promises (predict() returns a finished frame)
+            # h2o3-ok: R002 the span IS the wait: it splits device time from the host work around it
             jax.block_until_ready(out)
+        return out
+
+    def _score_host(self, test_data: Frame) -> np.ndarray:
+        """_score_device for the callers that compute on host scores: the
+        result fetched in ONE device→host transfer."""
+        from h2o3_tpu.parallel import mrtask as _mrt
+        out = self._score_device(test_data)
+        if isinstance(out, np.ndarray):
+            return out
         with _span("predict.fetch") as sp:
             # h2o3-ok: R002,R015 the span IS the device→host fetch
             out = _mrt.host_fetch(out)
             sp.attrs["bytes"] = int(out.nbytes)
         return out
 
-    def _prediction_columns(self, out: np.ndarray, n: int) -> list:
-        """Host-side prediction column assembly — the ONE place that maps
-        raw scores to (name, float64 values, domain-or-None) columns.
-        Shared by _prediction_frame and the REST row-payload route, so
-        the two serving answers can never diverge. The classifier path
-        slices every p<level> column out of the ONE fetched copy — there
-        is exactly one device→host transfer per predict."""
-        if self._is_classifier:
+    def _prediction_columns(self, out, n: int) -> list:
+        """Prediction column assembly — the ONE place that maps raw
+        scores to (name, values, domain-or-None) columns. Shared by
+        predict(), _prediction_frame and the REST row-payload route, so
+        the serving answers can never diverge. The columns are made where
+        the scores already live: a device array stays on the device
+        (`DevicePlanes` out of one program, nothing of frame size crosses
+        the host link); host scores become float64 host columns, every
+        p<level> sliced out of the ONE fetched copy."""
+        dom = self._dinfo.response_domain if self._is_classifier else None
+        if isinstance(out, jax.Array):
+            return self._device_columns(out, n, dom)
+        if dom is not None:
             probs = np.asarray(out, np.float64)[:n]
             pred = probs.argmax(axis=1).astype(np.float64)
-            dom = self._dinfo.response_domain
             cols = [("predict", pred, dom)]
             cols += [(f"p{lvl}", probs[:, k], None)
                      for k, lvl in enumerate(dom)]
             return cols
         return [("predict", np.asarray(out, np.float64)[:n], None)]
 
-    def _prediction_frame(self, out: np.ndarray, n: int) -> Frame:
-        """Build the predictions Frame from host scores."""
+    @staticmethod
+    def _device_columns(out: jax.Array, n: int, dom) -> list:
+        """The host columns' values as device planes, out of ONE program
+        (`_prediction_planes`). The planes come out row-sharded like every
+        Vec's; over a row-sharded `out` that takes no collective."""
+        from h2o3_tpu.parallel import mrtask as _mrt
+        c = _mesh.cloud()
+        label = None if dom is None else "i8" if len(dom) <= 127 else "f32"
+        # cached_jit: the program's closure is (n, label), so every frame
+        # of one size replays one resident program
+        got = _mrt.cached_jit(_prediction_planes(n, label),
+                              out_shardings=c.rows_sharding(1))(out)
+        f32 = Codec("f32")
+        if label is None:
+            return [("predict", DevicePlanes(*got[0], f32, n), None)]
+        return [("predict", DevicePlanes(*got[0], Codec(label), n), dom)] \
+            + [(f"p{lvl}", DevicePlanes(*got[1 + k], f32, n), None)
+               for k, lvl in enumerate(dom)]
+
+    def _prediction_frame(self, out, n: int) -> Frame:
+        """Build the predictions Frame from raw scores — or from their
+        _prediction_columns, where the caller made those already (a list:
+        predict() enqueues them ahead of its wait). Each Vec is made from
+        what its column is: device planes are adopted as they are (host
+        bookkeeping only), a host column is packed and put."""
         names, vecs = [], []
         with _span("predict.frame") as sp:
-            for name, vals, dom in self._prediction_columns(out, n):
-                if dom is not None:
+            cols = out if isinstance(out, list) \
+                else self._prediction_columns(out, n)
+            for name, vals, dom in cols:
+                vtype = T_CAT if dom is not None else T_NUM
+                if isinstance(vals, DevicePlanes):
+                    vecs.append(Vec.from_device_planes(vals, vtype, dom))
+                elif dom is not None:
                     vecs.append(Vec._from_floats(
                         vals, np.zeros(n, bool), T_CAT,
                         np.asarray(dom, object)))
                 else:
                     vecs.append(Vec.from_numpy(vals))
                 names.append(name)
+            where = "device" if all(isinstance(v, DevicePlanes)
+                                    for _, v, _ in cols) else "host"
             sp.attrs["cols"] = len(names)
+            sp.attrs["columns"] = where
+            _FRAME_COLUMNS.inc(algo=self.algo, columns=where)
             return Frame(names, vecs)
 
     def model_performance(self, test_data: Optional[Frame] = None):

@@ -263,3 +263,39 @@ def test_dense_walk_compiles_for_v5e_at_frame_size(ntrees, depth, chips,
                "collective-permute"):
         assert op not in text, op       # each chip walks its own rows
     assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_prediction_planes_compile_for_v5e_at_frame_size(chips, topo, sds,
+                                                         no_persistent_cache):
+    """The one program that makes a prediction frame's planes from the
+    walk's output (ISSUE 31), at the benchmark's frame: three (data, mask)
+    pairs, the label int8; over a row-sharded `out` on the 2x2 host every
+    plane comes out row-sharded and the program holds no collective — each
+    chip packs its own rows."""
+    from h2o3_tpu.models.model import _prediction_planes
+    if chips == 1:
+        pad, out_sh = SCORE_ROWS, None
+        out = sds((pad, 2), jnp.float32)
+    else:
+        mesh = Mesh(np.array(topo.devices).reshape(-1, 1),
+                    (MESH.ROWS, MESH.MODEL))
+        pad = MESH.Cloud(mesh).padded_rows(SCORE_ROWS - 5)
+        out_sh = NamedSharding(mesh, P(MESH.ROWS))
+        out = jax.ShapeDtypeStruct(
+            (pad, 2), jnp.float32,
+            sharding=NamedSharding(mesh, P(MESH.ROWS, None)))
+    compiled = jax.jit(_prediction_planes(SCORE_ROWS - 5, "i8"),
+                       out_shardings=out_sh).lower(out).compile()
+    text = compiled.as_text()
+    for op in ("all-gather", "all-reduce", "all-to-all",
+               "collective-permute"):
+        assert op not in text, op
+    planes = compiled.out_info
+    assert [[p.shape for p in col] for col in planes] == [[(pad,)] * 2] * 3
+    assert [[str(p.dtype) for p in col] for col in planes] == \
+        [["int8", "uint8"], ["float32", "uint8"], ["float32", "uint8"]]
+    if chips == 4:
+        for sh in jax.tree_util.tree_leaves(compiled.output_shardings):
+            assert sh.is_equivalent_to(out_sh, 1), sh
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20

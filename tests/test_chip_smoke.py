@@ -40,7 +40,8 @@ def predicted(trained, tmp_path_factory):
         mp.setenv("H2O3_SCORE_FASTPATH_MAX_ROWS", str(MAX_FAST_ROWS))
         return cs.phase_predict(model, fr, X, SEED,
                                 str(tmp_path_factory.mktemp("mojo")),
-                                slice_rows=4096, check_rows=500)
+                                slice_rows=4096, check_rows=500,
+                                parity_rows=9001)
 
 
 def test_ingest_phase(cloud8, tmp_path):
@@ -68,11 +69,21 @@ def test_predict_phase(predicted):
         assert predicted[path]["max_abs_dev"] < 2e-5
 
 
+def test_predict_phase_sets_the_device_built_frame_against_the_hosts(
+        predicted):
+    rec = predicted["frame_parity"]
+    assert rec["rows"] == 9001 and rec["padded"] == 9024
+    assert rec["nonfinite_cells"] > 1000
+    assert (rec["columns_compared"], rec["planted_rows"]) == (3, 3)
+
+
 def test_serve_phase(trained, predicted):
     _, model, _, X = trained
     rec = cs.phase_serve(model, X, predicted["p_full"], sizes=(1, 64, 300),
                          repeats=3, one_row_repeats=4)
     assert rec["requests"]["1"]["count"] == 4
+    assert rec["requests"]["1"]["median_warm_ms"] > 0
+    assert rec["one_row_median_warm_ms_pr28"] == [7.41, 7.66]
     assert rec["trace_error_fallbacks"] == 0
     assert sorted(rec["requests"]) == ["1", "300", "64"]
 

@@ -8,6 +8,7 @@ import time
 import urllib.parse
 import urllib.request
 
+import jax
 import numpy as np
 import pytest
 
@@ -369,7 +370,7 @@ def _predict_rows(path):
 
 @pytest.mark.parametrize("path,children", [
     ("frame", ["predict.matrix", "predict.dispatch", "predict.wait",
-               "predict.fetch", "predict.frame"]),
+               "predict.frame"]),
     ("bucket", ["predict.frame"])])
 def test_predict_leaves_one_root_span_with_its_stages(
         small_gbm, monkeypatch, path, children):
@@ -380,9 +381,15 @@ def test_predict_leaves_one_root_span_with_its_stages(
     rows0, other0 = _predict_rows(path), _predict_rows(other)
     calls0 = REGISTRY.get("h2o3_predict_calls_total").value(algo="gbm",
                                                             path=path)
+    # where the prediction frame's columns are made: the large-frame path
+    # leaves the scores on the device, the bucket path answers on the host
+    where = "device" if path == "frame" else "host"
+    made = REGISTRY.get("h2o3_predict_frame_columns_total")
+    made0 = {w: made.value(algo="gbm", columns=w) for w in ("device", "host")}
     SPANS.clear()
     pred = m.predict(f)
     spans = SPANS.snapshot()
+    planes = [type(v.data) for v in pred.vecs]
     DKV.remove(pred.key)
     roots = [s for s in spans if s["name"] == "predict"]
     assert len(roots) == 1
@@ -398,10 +405,14 @@ def test_predict_leaves_one_root_span_with_its_stages(
     assert sum(s["duration_ms"] for s in kids) <= root["duration_ms"]
     assert all(root["start"] <= s["start"] and s["end"] <= root["end"]
                for s in kids)
-    if path == "frame":
-        fetch = next(s for s in kids if s["name"] == "predict.fetch")
-        assert fetch["attrs"]["bytes"] >= 4 * 2 * f.nrows
-    assert kids[-1]["attrs"]["cols"] == 3      # predict, pn, pp
+    # nothing of frame size crosses the host link on the large-frame path
+    assert not [s for s in spans
+                if s["name"] in ("predict.fetch", "mrtask.host_fetch")]
+    assert len(planes) == 3 and all(issubclass(t, jax.Array) for t in planes)
+    assert kids[-1]["attrs"] == {"cols": 3,    # predict, pn, pp
+                                 "columns": where}
+    assert {w: made.value(algo="gbm", columns=w) for w in made0} == \
+        {**made0, where: made0[where] + 1}
     assert _predict_rows(path) == rows0 + f.nrows
     assert _predict_rows(other) == other0
     assert REGISTRY.get("h2o3_predict_calls_total").value(
