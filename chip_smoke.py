@@ -313,9 +313,8 @@ def phase_train(rows: int, ntrees: int, seed: int, on_chip: bool = True):
     if on_chip:
         # the Pallas kernels are IN the compiled trainer: each entry was
         # traced into it (model_summary's "engine" string proves nothing)
-        kinds = {k for k, _ in picked}
-        assert {"hist", "fused", "route_f"} <= kinds, picked
-        assert not kinds & {"radix", "fused_radix"}, picked
+        assert {k for k, _ in picked} == {"hist", "fused", "route",
+                                          "route_f"}, picked
     else:
         assert not picked, picked
     summ = model._output.model_summary

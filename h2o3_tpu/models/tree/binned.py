@@ -306,32 +306,12 @@ class BinnedGrower:
                  min_split_improvement: float, reg_lambda: float = 0.0,
                  reg_alpha: float = 0.0, use_hess_denom: bool = False,
                  monotone: np.ndarray | None = None,
-                 axis_name: str | None = None,
-                 int8_stats: bool | None = None,
-                 use_radix_shallow: bool | None = None,
-                 fused_level: bool | None = None):
+                 axis_name: str | None = None):
         # axis_name: mesh axis the row dimension is sharded over. grow() then
         # runs shard-local and merges per-level histograms with ONE psum —
         # the reduce-tree of ScoreBuildHistogram.java:98 / MRTask.java:907
         # riding ICI. Split search stays replicated (identical on all shards).
         self.axis_name = axis_name
-        # int8_stats: quantize (w, wg, wh) to int8 per tree and accumulate
-        # histograms on the 2x-rate int8 MXU path with exact i32 sums
-        # (PERF_NOTES item 2; quantum |g|max/127). EXPLICIT OPT-IN: that
-        # the kernel compiles says nothing about end-to-end model accuracy
-        # against the f32 path; until an on-chip AUC-parity measurement
-        # lands, default stays off.
-        self.int8 = bool(int8_stats)
-        # use_radix_shallow: EXPLICIT OPT-IN (None = off). Mosaic refuses
-        # sbh_hist_radix at 32 columns (VMEM) and its fused variant costs
-        # minutes of compile (tests/test_chip_compile.py), so the default
-        # path never selects it; True selects it wherever the window
-        # qualifies and a compiler refusal then raises.
-        self.use_radix = bool(use_radix_shallow)
-        # fused_level: None/True = the level-fused route+hist kernel
-        # wherever HP._fused_applicable's shape rule admits the level;
-        # False forces the sequential pair (the parity baseline).
-        self.fused = None if fused_level in (None, True) else False
         self.spec = spec
         self.D = int(max_depth)
         self.L = 2 ** self.D
@@ -354,7 +334,7 @@ class BinnedGrower:
         return padded_rows(n, shards)
 
     def grow(self, codes, stats, F, *, eta, clip_val, key, mtries: int = 0,
-             tree_mask=None, level_cb=None):
+             tree_mask=None):
         """Grow ONE tree and apply its margin update — all device-resident.
 
         codes: uint8 (C_pad, n_pad) code plane from `quantize`, or the
@@ -363,10 +343,6 @@ class BinnedGrower:
                zero stats)
         stats: (S_STATS, n_pad) f32 — rows 0=w 1=w*grad 2=w*hess 3=0
         F:     (n_pad,) f32 margins (updated in the terminal route pass)
-        level_cb: optional host callback `cb(d, sync_array)` invoked after
-               each level's dispatches — ONLY for the eager per-level
-               instrumentation path (bench measure_level_seconds); must be
-               None under jit.
 
         Returns dict(col, bin, nal, route, val, cover, gains, F).
         Per-row state is ONE heap-id int32 array; no row reordering ever
@@ -392,26 +368,8 @@ class BinnedGrower:
         lo = jnp.full(1, -big)
         hi = jnp.full(1, big)
         any_cat = bool(spec.is_cat.any())
-        if self.int8:
-            # per-tree, per-stat-row symmetric quantization: stats are fixed
-            # for the whole tree, so ONE quantization pass serves every level
-            absmax = jnp.max(jnp.abs(stats), axis=1, keepdims=True)  # (S,1)
-            if self.axis_name:
-                # the quantum must be GLOBAL or shards' i32 sums would mix
-                # incompatible scales inside the psum
-                absmax = lax.pmax(absmax, self.axis_name)
-            scale = 127.0 / jnp.maximum(absmax, 1e-30)
-            stats_in = jnp.clip(jnp.round(stats * scale),
-                                -127, 127).astype(jnp.int32)
-            inv = jnp.maximum(absmax, 1e-30)[:, 0] / 127.0           # (S,)
-            hist_fn = HP.sbh_hist_i8
-        else:
-            stats_in = stats
-            hist_fn = HP.sbh_hist
         prev = None                    # routing tables of level d-1
-        hist_prev = None               # full histogram of level d-1 (native
-        #                                dtype: i32 when int8 — sibling
-        #                                subtraction stays exact)
+        hist_prev = None               # full histogram of level d-1
         did_prev = None                # split mask of level d-1
         # jax.named_scope below is metadata only: it names the stages of
         # the K-tree program in a device trace (tree.level.hist /
@@ -422,12 +380,12 @@ class BinnedGrower:
             base = L - 1
             if d == 0:
                 with jax.named_scope("tree.level.hist"):
-                    hacc = hist_fn(codes, heap, stats_in, base=base, L=L,
-                                   n_bins=BP, radix=self.use_radix)[:L, :C]
+                    hist = HP.sbh_hist(codes, heap, stats, base=base, L=L,
+                                       n_bins=BP)[:L, :C]
                     if self.axis_name:
                         # the ScoreBuildHistogram reduce: merge shard-local
                         # histograms in one collective per level
-                        hacc = lax.psum(hacc, self.axis_name)
+                        hist = lax.psum(hist, self.axis_name)
             else:
                 # ONE fused-or-sequential pass: route the previous level's
                 # splits, then (sibling subtraction) histogram LEFT
@@ -439,11 +397,9 @@ class BinnedGrower:
                 # masked to zero (their child slots are dead).
                 with jax.named_scope("tree.level.route_hist"):
                     heap, left = HP.sbh_route_hist(
-                        codes, heap, prev["tbl"], prev["route_f"], stats_in,
+                        codes, heap, prev["tbl"], prev["route_f"], stats,
                         base_r=(L >> 1) - 1, L_r=L >> 1, base_h=base, L_h=L,
-                        n_bins=BP, any_cat=any_cat, na_code=spec.b_val,
-                        int8=self.int8, fused=self.fused,
-                        radix=self.use_radix)
+                        n_bins=BP, any_cat=any_cat, na_code=spec.b_val)
                 with jax.named_scope("tree.level.hist"):
                     left = left[: L >> 1, :C]
                     if self.axis_name:
@@ -453,11 +409,9 @@ class BinnedGrower:
                     par = jnp.where(did_prev[:, None, None, None],
                                     hist_prev, jnp.zeros_like(hist_prev))
                     right = par - left
-                    hacc = jnp.stack([left, right], axis=1) \
+                    hist = jnp.stack([left, right], axis=1) \
                         .reshape(L, *left.shape[1:])
-            hist_prev = hacc
-            hist = hacc.astype(jnp.float32) * inv[None, None, :, None] \
-                if self.int8 else hacc
+            hist_prev = hist
 
             with jax.named_scope("tree.level.split"):
                 if mtries and mtries < c_real:
@@ -522,12 +476,6 @@ class BinnedGrower:
                 hi = jnp.stack([jnp.where(did, hi_l, hi),
                                 jnp.where(did, hi_r, hi)], 1).reshape(2 * L)
 
-            if level_cb is not None:
-                # eager instrumentation only (bench per-level breakdown):
-                # the callback syncs on the level's routing table — the
-                # array downstream of hist + find_splits
-                level_cb(d, prev["tbl"])
-
         # terminal pass: route the last level + fused F update
         L = 1 << D
         with jax.named_scope("tree.margin"):
@@ -541,44 +489,6 @@ class BinnedGrower:
                                    na_code=spec.b_val)
         return dict(col=colA, bin=binA, nal=nalA, route=routeA, val=valt,
                     cover=coverA, gains=gains[:C], F=F, heap=heap)
-
-
-# ===========================================================================
-def measure_level_seconds(grower: BinnedGrower, codes, stats, F, *,
-                          eta=0.1, clip_val=0.0, key=None):
-    """Grow ONE tree EAGERLY with a host sync after every level and record
-    each level's wall time into `h2o3_tree_level_seconds{engine="binned",
-    level=d}` — the ISSUE-1 arbiter for the per-level cost breakdown (the
-    jitted K-tree trainer is one opaque program; ad-hoc timers inside it
-    cannot attribute the residual cost to a level). Returns
-    [{"level": d, "seconds": s}, ...] for the bench record."""
-    import time as _time
-    from h2o3_tpu.models.tree import engine as _E
-
-    rows: list[dict] = []
-    last = [0.0]
-
-    def sync_cb(d, sync_arr):
-        jax.block_until_ready(sync_arr)
-
-    def cb(d, sync_arr):
-        sync_cb(d, sync_arr)
-        now = _time.perf_counter()
-        dt = now - last[0]
-        last[0] = now
-        _E._LEVEL_SECONDS.observe(dt, engine="binned", level=str(d))
-        rows.append({"level": d, "seconds": round(dt, 6)})
-
-    k = key if key is not None else jax.random.PRNGKey(0)
-    # warmup pass, synced but untimed: every level's static L compiles
-    # its own programs on first dispatch, and a compile (0.1-10 s) would
-    # swamp the ms-scale device cost the arbiter exists to expose
-    grower.grow(codes, stats, F, eta=eta, clip_val=clip_val, key=k,
-                level_cb=sync_cb)
-    last[0] = _time.perf_counter()
-    grower.grow(codes, stats, F, eta=eta, clip_val=clip_val, key=k,
-                level_cb=cb)
-    return rows
 
 
 # ===========================================================================

@@ -55,12 +55,6 @@ WALKS = _om.counter(
     "predict_ensemble calls by the scoring walk's body: path=dense (every "
     "node of a level, no per-row index) or gather (categorical or deep "
     "trees)")
-_LEVEL_SECONDS = _om.histogram(
-    "h2o3_tree_level_seconds",
-    "per-level wall time of the tree engines, labeled by engine "
-    "(adaptive = per-level dispatch enqueue; binned = the eager "
-    "instrumented pass of binned.measure_level_seconds, synced per "
-    "level) and by level index — the bench per-level cost arbiter")
 
 # Dense-matmul histogram path is used while (leaves × 3 stats) stays MXU-sized.
 # Measured on v5e: the one-hot matmul beats segment-sum scatter ~3× even at
@@ -719,9 +713,7 @@ class TreeGrower:
                 # span covers the level DISPATCH (histogram + split search
                 # + routing are one fused async program; on TPU the enqueue
                 # returns before the device finishes)
-                with _span("tree.level", depth=d), \
-                        _LEVEL_SECONDS.time(engine="adaptive",
-                                            level=str(d)):
+                with _span("tree.level", depth=d):
                     leaf, heap, active, colA, thrA, nalA, valA, gains = \
                         _level_step(
                             X, stats, w, leaf, heap, active, colA, thrA,

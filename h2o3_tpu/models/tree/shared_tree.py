@@ -62,16 +62,6 @@ class SharedTreeEstimator(ModelBase):
         # at 255 (a root histogram at 1024 bins halved 2 levels ≈ 256).
         # None = derive from nbins alone (the engine's own default).
         "nbins_top_level": None,
-        # TPU extensions (ops/hist_pallas.py). int8_hist: int8-quantized
-        # histogram stats on the 2x-rate int8 MXU path — opt-in (None =
-        # off). radix_shallow: the radix-factored shallow-window
-        # histogram kernel — opt-in (None = off; the chip's compiler
-        # refuses it at 32 columns). fused_level: the level-fused
-        # route+hist kernel — None = on wherever the level's shape
-        # qualifies, False = force the sequential pair.
-        "int8_hist": None,
-        "radix_shallow": None,
-        "fused_level": None,
     }
 
     def _cat_mode(self):
@@ -198,10 +188,7 @@ class SharedTreeEstimator(ModelBase):
             min_rows=float(p["min_rows"]),
             min_split_improvement=float(p["min_split_improvement"]),
             monotone=mono if mc else None,
-            axis_name=MESH.ROWS if multi else None,
-            int8_stats=p.get("int8_hist"),
-            use_radix_shallow=p.get("radix_shallow"),
-            fused_level=p.get("fused_level"))
+            axis_name=MESH.ROWS if multi else None)
         n_pad = grower.layout(n, shards=shards if multi else 1)
         # uint8 code plane (1 byte/code in HBM), packed to the Pallas
         # kernels' i32 word layout on TPU — the row axis is untouched so

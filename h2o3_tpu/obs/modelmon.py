@@ -95,7 +95,6 @@ SKIPPED = _om.counter(
 _LOCK = make_lock("modelmon")
 _TLS = threading.local()        # .suppress: tap off for baseline scoring
 _STATE: dict = {}               # model key -> _ModelState
-_OVERRIDE = [None]              # set_enabled override (None = env)
 _EVAL_THREAD = [None]
 _LAST_EVAL: dict = {}           # model key -> last drift document
 
@@ -108,19 +107,8 @@ _LAPLACE = 0.5                  # add-half count smoothing: an empty bin
 # env surface
 
 
-def _env_enabled() -> bool:
-    return env_bool("H2O3_MODELMON", True)
-
-
 def enabled() -> bool:
-    ov = _OVERRIDE[0]
-    return _env_enabled() if ov is None else bool(ov)
-
-
-def set_enabled(on):
-    """Override the H2O3_MODELMON switch from code (None restores the
-    env reading) — the bench's monitor on/off A-B loop."""
-    _OVERRIDE[0] = on
+    return env_bool("H2O3_MODELMON", True)
 
 
 def _n_bins() -> int:
@@ -830,13 +818,12 @@ def _eval_loop(period: float):
 
 def reset():
     """Test isolation: drop all monitored state and the per-model
-    series; restore the env-driven enable switch."""
+    series."""
     with _LOCK:
         keys = list(_STATE.keys())
     for k in keys:
         forget(k)
     _LAST_EVAL.clear()
-    _OVERRIDE[0] = None
     DRIFT.clear()
     PRED_DRIFT.clear()
     GEN_SKEW.clear()

@@ -9,8 +9,8 @@ replayed spans with the ORIGINATING request's trace. `GET /3/Trace/{id}`
 stitches the fragments back together cloud-wide.
 
 This module is intentionally dependency-free (stdlib only): it is
-imported by the span timeline, the REST layer, the micro-batcher, mrtask
-and bench.py, and must never pull jax or the metrics registry in.
+imported by the span timeline, the REST layer, the micro-batcher and
+mrtask, and must never pull jax or the metrics registry in.
 
 Env surface:
   H2O3_TRACING  "0" disables trace-id minting at the REST layer (spans

@@ -83,7 +83,6 @@ _LEDGER: dict = {}                # (principal, model, kind) -> [s, calls, rows]
 _TOTAL = [0.0]                    # cumulative device seconds, all series
 _RATE: deque = deque(maxlen=4096)   # (monotonic, cumulative) rate samples
 _KNOWN_MODELS: set = set()
-_OVERRIDE: list = [None]          # set_enabled() override (None = env)
 _TIER_PREV = [None]               # (monotonic, faults) for the fault rate
 _TIER_RATE = [0.0]                # last fault rate over a full interval
 _LAST_PRESSURE: dict = {}         # last evaluate_pressure() doc (gauge feed)
@@ -98,7 +97,7 @@ _TIER_FAULT_SATURATION = 100.0
 _TIER_MIN_INTERVAL_S = 0.25
 
 
-def _env_enabled() -> bool:
+def enabled() -> bool:
     """H2O3_USAGE master switch (attribution + stage recording)."""
     return env_bool("H2O3_USAGE", True)
 
@@ -110,17 +109,6 @@ def _max_models() -> int:
 def _rate_window_s() -> float:
     """Trailing window for the device-seconds rate → utilization."""
     return env_float("H2O3_USAGE_RATE_WINDOW_S", 60.0)
-
-
-def enabled() -> bool:
-    ov = _OVERRIDE[0]
-    return _env_enabled() if ov is None else bool(ov)
-
-
-def set_enabled(on):
-    """Override the H2O3_USAGE switch from code (None restores the env
-    reading) — the bench's ledger on/off A-B loop."""
-    _OVERRIDE[0] = on
 
 
 # ---------------------------------------------------------------------------

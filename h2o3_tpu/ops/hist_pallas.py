@@ -59,17 +59,11 @@ Kernels per level:
     the unfused route+hist pair serves the deeper levels and is the XLA
     path.
 
-  * sbh_hist_radix — radix-factored shallow-window histogram (PERF_NOTES
-    item 1): code = hi*16+lo with the leaf slot fused into the hi key
-    kills the 256-wide VPU one-hot floor at effective windows <= 2.
-    EXPLICIT OPT-IN only: Mosaic refuses it at 32 columns (VMEM) and its
-    fused variant costs minutes of compile (tests/test_chip_compile.py
-    holds the default rules to that), so no default path selects it.
-
-Kernel selection is by SHAPE RULE only (`is_packed`, `_fused_applicable`,
-`_radix_shape_ok`). There is one installation; what its Mosaic compiles at
-the widths users train at is pinned by tests/test_chip_compile.py, and a
-kernel the compiler refuses raises — it never degrades to a slower path.
+One kernel per regime, selected by SHAPE RULE only (`is_packed`,
+`_fused_applicable`) — no option picks a kernel. There is one
+installation; what its Mosaic compiles at the widths users train at is
+pinned by tests/test_chip_compile.py, and a kernel the compiler refuses
+raises — it never degrades to a slower path.
 
 Stats panel rows (S_STATS=4): 0=w, 1=w*grad, 2=w*hess, 3=spare(0) —
 (w, wg, wh) feed split gain, min_rows and Newton leaf values
@@ -250,15 +244,14 @@ def _route_math(words, heap, tbl, route, *, base, L, n_bins, any_cat,
     return jnp.where(splits, 2 * heap + 1 + go.astype(jnp.int32), heap)
 
 
-def _stats_panel(heap, stats, *, base, L, gwe, p, half, int8):
+def _stats_panel(heap, stats, *, base, L, gwe, p, half):
     """The (gwe*S_STATS, R) MXU lhs panel A: row (slot, s) holds stat s of
     rows whose leaf sits in window slot `slot` of pass `p`. With half=True
     only EVEN leaf indices (left children) are accumulated — window slot =
     leaf >> 1 — and the caller derives right children by sibling
     subtraction (parent minus left; the same trick xgboost/lightgbm use —
     valid because routing moves EVERY row of a split leaf to a child, so
-    parent = left + right exactly; i32 accumulation makes it lossless on
-    the int8-stats path)."""
+    parent = left + right exactly)."""
     R = heap.shape[0]
     leaf = heap - base
     if half:
@@ -270,10 +263,6 @@ def _stats_panel(heap, stats, *, base, L, gwe, p, half, int8):
     inw = inw & (slot >= 0) & (slot < gwe)
     slot_c = jnp.where(inw, slot, 0)
     iota_s = lax.broadcasted_iota(jnp.int32, (gwe, R), 0)
-    if int8:
-        sel = (iota_s == slot_c[None, :]) & inw[None, :]      # (gwe, R)
-        return (jnp.where(sel[:, None, :], stats[None, :, :], 0)
-                .reshape(gwe * S_STATS, R)).astype(jnp.int8)
     inw_f = inw.astype(jnp.float32)
     ohs = ((iota_s == slot_c[None, :]).astype(jnp.float32)
            * inw_f[None, :])                                  # (gwe, R)
@@ -281,68 +270,25 @@ def _stats_panel(heap, stats, *, base, L, gwe, p, half, int8):
         .reshape(gwe * S_STATS, R).astype(jnp.bfloat16)
 
 
-def _dense_parts(words, A, *, n_bins, int8):
+def _dense_parts(words, A, *, n_bins):
     """Per-column histogram dots for one packed-word tile: byte-extract
     each code INSIDE the tile (never widened in HBM), one-hot it, dot
     against the stats panel. Returns 4*W parts of (M, nb)."""
     R = words.shape[1]
-    if int8:
-        iota_b = lax.broadcasted_iota(jnp.int32, (R, n_bins), 1)
-    else:
-        # one-hot built TRANSPOSED (nb, R): bins on sublanes, rows on
-        # lanes. Measured 1.9x faster than the (R, nb) orientation — the
-        # compare broadcast is a major-dim insert (free) instead of a
-        # minor-dim layout change, and the dot contracts the rhs on dim 1.
-        iota_b = lax.broadcasted_iota(jnp.int32, (n_bins, R), 0)
+    # one-hot built TRANSPOSED (nb, R): bins on sublanes, rows on
+    # lanes. Measured 1.9x faster than the (R, nb) orientation — the
+    # compare broadcast is a major-dim insert (free) instead of a
+    # minor-dim layout change, and the dot contracts the rhs on dim 1.
+    iota_b = lax.broadcasted_iota(jnp.int32, (n_bins, R), 0)
     parts = []
     for w in range(words.shape[0]):
         word = words[w, :]                                    # (R,) static w
         for k in range(PACK):
             code = (word >> (8 * k)) & 255
-            if int8:
-                oh = (iota_b == code[:, None]).astype(jnp.int8)
-                h = lax.dot_general(A, oh, (((1,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.int32)
-            else:
-                ohT = (iota_b == code[None, :]).astype(jnp.bfloat16)
-                h = lax.dot_general(A, ohT, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
+            ohT = (iota_b == code[None, :]).astype(jnp.bfloat16)
+            h = lax.dot_general(A, ohT, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
             parts.append(h)                                   # (M, nb)
-    return parts
-
-
-def _radix_parts(words, slot_c, stats, *, gwe, n_bins, int8):
-    """Radix-factored per-column accumulation: code = hi*16 + lo with the
-    leaf slot fused into the hi key — a gwe*16-wide joint compare plus a
-    16-wide lo one-hot replaces the 256-wide dense compare (2.7x fewer
-    VPU element-ops at window 1; see PERF_NOTES item 1). `slot_c` is the
-    window slot with dead rows already pushed out of range (>= gwe)."""
-    NH = RADIX_NH
-    nl = n_bins // NH
-    R = words.shape[1]
-    iota_k = lax.broadcasted_iota(jnp.int32, (gwe * NH, R), 0)
-    iota_lo = lax.broadcasted_iota(jnp.int32, (nl, R), 0)
-    parts = []
-    for w in range(words.shape[0]):
-        word = words[w, :]
-        for k in range(PACK):
-            code = (word >> (8 * k)) & 255
-            key = slot_c * NH + code // nl
-            lo = code % nl
-            J = iota_k == key[None, :]                        # (gwe*NH, R)
-            if int8:
-                A = jnp.where(J[:, None, :], stats[None, :, :], 0) \
-                    .reshape(gwe * NH * S_STATS, R).astype(jnp.int8)
-                ohlo = (iota_lo == lo[None, :]).astype(jnp.int8)
-                h = lax.dot_general(A, ohlo, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.int32)
-            else:
-                A = jnp.where(J[:, None, :], stats[None, :, :], 0.0) \
-                    .reshape(gwe * NH * S_STATS, R).astype(jnp.bfloat16)
-                ohlo = (iota_lo == lo[None, :]).astype(jnp.bfloat16)
-                h = lax.dot_general(A, ohlo, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            parts.append(h)                                   # (gwe*NH*S, nl)
     return parts
 
 
@@ -398,54 +344,45 @@ def sbh_route_pallas(codesP, heap, tbl, route_f, valtab=None, F=None, *,
     nblk = n_pad // BLOCK_ROWS
     n_bins = route_f.shape[1]
     KERNEL_TRACES.inc(kernel="route_f" if emit_f else "route", L=str(L))
-    if not emit_f:
+
+    def row():
+        return pl.BlockSpec((1, BLOCK_ROWS), lambda j: (0, j))
+
+    in_specs = [
+        pl.BlockSpec((w_pad, BLOCK_ROWS), lambda j: (0, j)),
+        row(),
+        pl.BlockSpec(tbl.shape, lambda j: (0, 0)),
+        pl.BlockSpec(route_f.shape, lambda j: (0, 0)),
+    ]
+    args = (codesP, heap.reshape(1, n_pad), tbl, route_f)
+    heap_shape = jax.ShapeDtypeStruct((1, n_pad), jnp.int32)
+    if emit_f:
+        kernel = functools.partial(_route_kernel_f, base=base, L=L,
+                                   n_bins=n_bins, eta=eta, any_cat=any_cat,
+                                   na_code=na_code)
+        in_specs += [pl.BlockSpec(valtab.shape, lambda j: (0, 0)), row()]
+        args += (valtab, F.reshape(1, n_pad))
+        out_specs = [row(), row()]
+        out_shape = [heap_shape,
+                     jax.ShapeDtypeStruct((1, n_pad), jnp.float32)]
+    else:
         kernel = functools.partial(_route_kernel, base=base, L=L,
                                    n_bins=n_bins, any_cat=any_cat,
                                    na_code=na_code)
-        newheap = pl.pallas_call(
-            kernel,
-            name="sbh_route",
-            grid=(nblk,),
-            in_specs=[
-                pl.BlockSpec((w_pad, BLOCK_ROWS), lambda j: (0, j)),
-                pl.BlockSpec((1, BLOCK_ROWS), lambda j: (0, j)),
-                pl.BlockSpec(tbl.shape, lambda j: (0, 0)),
-                pl.BlockSpec(route_f.shape, lambda j: (0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, BLOCK_ROWS), lambda j: (0, j)),
-            out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary",)),
-        )(codesP, heap.reshape(1, n_pad), tbl, route_f)
-        return newheap[0], None
-    kernel = functools.partial(_route_kernel_f, base=base, L=L,
-                               n_bins=n_bins, eta=eta, any_cat=any_cat,
-                               na_code=na_code)
-    newheap, newF = pl.pallas_call(
+        out_specs, out_shape = row(), heap_shape
+    out = pl.pallas_call(
         kernel,
-        name="sbh_route_f",
+        name="sbh_route_f" if emit_f else "sbh_route",
         grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec((w_pad, BLOCK_ROWS), lambda j: (0, j)),
-            pl.BlockSpec((1, BLOCK_ROWS), lambda j: (0, j)),
-            pl.BlockSpec(tbl.shape, lambda j: (0, 0)),
-            pl.BlockSpec(route_f.shape, lambda j: (0, 0)),
-            pl.BlockSpec(valtab.shape, lambda j: (0, 0)),
-            pl.BlockSpec((1, BLOCK_ROWS), lambda j: (0, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, BLOCK_ROWS), lambda j: (0, j)),
-            pl.BlockSpec((1, BLOCK_ROWS), lambda j: (0, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
-            jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
-        ],
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-    )(codesP, heap.reshape(1, n_pad), tbl, route_f, valtab,
-      F.reshape(1, n_pad))
-    return newheap[0], newF[0]
+    )(*args)
+    if emit_f:
+        return out[0][0], out[1][0]
+    return out[0], None
 
 
 def sbh_route_xla(codesT, heap, tbl, route_f, valtab=None, F=None, *,
@@ -484,7 +421,7 @@ def sbh_route(codes, heap, tbl, route_f, valtab=None, F=None, *, base, L,
 # ===========================================================================
 # Phase 2: leaf-window histogram accumulation
 def _hist_kernel(codesP_ref, heap_ref, stats_ref, out_ref, *, base, L,
-                 n_bins, gwe, half, int8):
+                 n_bins, gwe, half):
     """Grid (pass, word-block, row-tile): accumulate the (4*W, gwe*S, nb)
     window block over the row sweep; gwe = min(l_eff, GW) leaves/pass."""
     p = pl.program_id(0)
@@ -495,13 +432,19 @@ def _hist_kernel(codesP_ref, heap_ref, stats_ref, out_ref, *, base, L,
         out_ref[...] = jnp.zeros_like(out_ref)
 
     A = _stats_panel(heap_ref[0, :], stats_ref[...], base=base, L=L,
-                     gwe=gwe, p=p, half=half, int8=int8)
-    parts = _dense_parts(codesP_ref[...], A, n_bins=n_bins, int8=int8)
+                     gwe=gwe, p=p, half=half)
+    parts = _dense_parts(codesP_ref[...], A, n_bins=n_bins)
     out_ref[...] = out_ref[...] + jnp.stack(parts)[None]
 
 
-def _hist_pallas(codesP, heap, stats, *, base, L, n_bins, half, int8):
-    KERNEL_TRACES.inc(kernel="hist_i8" if int8 else "hist", L=str(L))
+@functools.partial(jax.jit, static_argnames=("base", "L", "n_bins", "half"))
+def sbh_hist_pallas(codesP, heap, stats, *, base, L, n_bins, half=False):
+    """codesP (W_pad, n_pad) i32 packed plane; heap (n_pad,) i32;
+    stats (S, n_pad) f32. Returns (L_pad, c_pack, S_STATS, n_bins) f32
+    with L_pad = npass*gwe and c_pack = 4*W_pad:
+    hist[l] = per-(col, stat, bin) sums over rows with heap == base + l
+    (half=True: over rows with heap == base + 2l — left children only)."""
+    KERNEL_TRACES.inc(kernel="hist", L=str(L))
     w_pad, n_pad = codesP.shape
     cw = min(w_pad, WORD_TILE)
     ncw = w_pad // cw
@@ -514,7 +457,7 @@ def _hist_pallas(codesP, heap, stats, *, base, L, n_bins, half, int8):
     r_blk = BLOCK_ROWS if gwe * S_STATS <= 128 else BLOCK_ROWS // 2
     nblk = n_pad // r_blk
     kernel = functools.partial(_hist_kernel, base=base, L=L, n_bins=n_bins,
-                               gwe=gwe, half=half, int8=int8)
+                               gwe=gwe, half=half)
     out = pl.pallas_call(
         kernel,
         name="sbh_hist",
@@ -528,8 +471,7 @@ def _hist_pallas(codesP, heap, stats, *, base, L, n_bins, half, int8):
             (1, cc, gwe * S_STATS, n_bins),
             lambda p, g, j: (p * ncw + g, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(
-            (npass * ncw, cc, gwe * S_STATS, n_bins),
-            jnp.int32 if int8 else jnp.float32),
+            (npass * ncw, cc, gwe * S_STATS, n_bins), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
     )(codesP, heap.reshape(1, n_pad), stats)
@@ -537,28 +479,6 @@ def _hist_pallas(codesP, heap, stats, *, base, L, n_bins, half, int8):
     out = out.reshape(npass, ncw, cc, gwe, S_STATS, n_bins)
     return out.transpose(0, 3, 1, 2, 4, 5).reshape(
         npass * gwe, ncw * cc, S_STATS, n_bins)
-
-
-@functools.partial(jax.jit, static_argnames=("base", "L", "n_bins", "half"))
-def sbh_hist_pallas(codesP, heap, stats, *, base, L, n_bins, half=False):
-    """codesP (W_pad, n_pad) i32 packed plane; heap (n_pad,) i32;
-    stats (S, n_pad) f32. Returns (L_pad, c_pack, S_STATS, n_bins) f32
-    with L_pad = npass*gwe and c_pack = 4*W_pad:
-    hist[l] = per-(col, stat, bin) sums over rows with heap == base + l
-    (half=True: over rows with heap == base + 2l — left children only)."""
-    return _hist_pallas(codesP, heap, stats, base=base, L=L, n_bins=n_bins,
-                        half=half, int8=False)
-
-
-@functools.partial(jax.jit, static_argnames=("base", "L", "n_bins", "half"))
-def sbh_hist_pallas_i8(codesP, heap, stats_i8, *, base, L, n_bins,
-                       half=False):
-    """int8-stats variant: stats (S, n_pad) int32 holding [-127, 127]
-    (i32 input dtype: Mosaic's (S, R) int8 blocks don't meet the
-    32-sublane granule; the kernel casts to i8 in VMEM), exact i32
-    accumulation on the 2x-rate int8 MXU path (127 * 11M rows < 2^31)."""
-    return _hist_pallas(codesP, heap, stats_i8, base=base, L=L,
-                        n_bins=n_bins, half=half, int8=True)
 
 
 @functools.partial(jax.jit, static_argnames=("base", "L", "n_bins", "half"))
@@ -591,120 +511,12 @@ def sbh_hist_xla(codesT, heap, stats, *, base, L, n_bins, half=False):
              .transpose(1, 0, 3, 2)
 
 
-def sbh_hist(codes, heap, stats, *, base, L, n_bins, half=False,
-             radix=False):
-    """Histogram dispatch. `radix=True` engages the radix shallow-window
-    kernel wherever the window qualifies (`_radix_shape_ok` — the
-    factorization only exists for those); a compiler refusal raises."""
+def sbh_hist(codes, heap, stats, *, base, L, n_bins, half=False):
     if is_packed(codes):
-        if radix and _radix_applicable(L, n_bins, half):
-            return sbh_hist_radix(codes, heap, stats, base=base, L=L,
-                                  n_bins=n_bins, half=half, int8=False)
         return sbh_hist_pallas(codes, heap, stats, base=base, L=L,
                                n_bins=n_bins, half=half)
     return sbh_hist_xla(codes, heap, stats, base=base, L=L, n_bins=n_bins,
                         half=half)
-
-
-def sbh_hist_i8(codes, heap, stats_i8, *, base, L, n_bins, half=False,
-                radix=None):
-    """int8-stats histogram dispatch: i32 in [-127,127] per stat row, i32
-    out (exact accumulation). The XLA fallback is the same segment-sum
-    with integer dtype passthrough — bit-identical for the CPU tests."""
-    if is_packed(codes):
-        if radix and _radix_applicable(L, n_bins, half):
-            return sbh_hist_radix(codes, heap, stats_i8, base=base, L=L,
-                                  n_bins=n_bins, half=half, int8=True)
-        return sbh_hist_pallas_i8(codes, heap, stats_i8, base=base, L=L,
-                                  n_bins=n_bins, half=half)
-    return sbh_hist_xla(codes, heap, stats_i8, base=base, L=L,
-                        n_bins=n_bins, half=half)
-
-
-# ===========================================================================
-# Radix-factored shallow-window histogram (PERF_NOTES item 1, measured-win
-# regime only). VPU element-ops per (row, col): gwe*16*(1+S) + 16 vs dense
-# 256 + gwe*S: 2.7x at window 1, 1.5x at window 2, WORSE at window 4 — so
-# the dispatch engages only for effective windows <= 2, i.e. levels 0-2
-# once sibling subtraction halves the window. Reference semantics
-# unchanged: identical histograms to sbh_hist (parity-gated).
-RADIX_NH = 16
-RADIX_MAX_WINDOW = 2
-
-
-def _radix_shape_ok(l_eff: int, n_bins: int) -> bool:
-    return (l_eff <= RADIX_MAX_WINDOW and n_bins % RADIX_NH == 0
-            and n_bins // RADIX_NH >= 8)
-
-
-def _radix_applicable(L, n_bins, half) -> bool:
-    l_eff = (L + 1) // 2 if half else L
-    return _radix_shape_ok(l_eff, n_bins)
-
-
-def _radix_kernel(codesP_ref, heap_ref, stats_ref, out_ref, *, base, L,
-                  n_bins, gwe, half, int8):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    heap = heap_ref[0, :]
-    leaf = heap - base
-    if half:
-        # left children only; caller derives right = parent - left
-        slot = leaf >> 1
-        inw = (leaf >= 0) & (leaf < L) & ((leaf & 1) == 0)
-    else:
-        slot = leaf
-        inw = (leaf >= 0) & (leaf < L)
-    slot_c = jnp.where(inw, slot, gwe)     # dead rows -> key out of range
-    parts = _radix_parts(codesP_ref[...], slot_c, stats_ref[...],
-                         gwe=gwe, n_bins=n_bins, int8=int8)
-    out_ref[...] = out_ref[...] + jnp.stack(parts)[None]
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("base", "L", "n_bins", "half", "int8"))
-def sbh_hist_radix(codesP, heap, stats, *, base, L, n_bins, half=False,
-                   int8=False):
-    """Radix-factored histogram for effective windows <= RADIX_MAX_WINDOW.
-    Same contract as sbh_hist_pallas but returns exactly (l_eff, c_pack,
-    S_STATS, n_bins); f32 out (bf16 accumulation) or i32 when int8."""
-    KERNEL_TRACES.inc(kernel="radix", L=str(L))
-    w_pad, n_pad = codesP.shape
-    cw = min(w_pad, WORD_TILE)
-    ncw = w_pad // cw
-    cc = cw * PACK
-    l_eff = (L + 1) // 2 if half else L
-    gwe = max(1, l_eff)
-    NH = RADIX_NH
-    nl = n_bins // NH
-    nblk = n_pad // BLOCK_ROWS
-    kernel = functools.partial(_radix_kernel, base=base, L=L, n_bins=n_bins,
-                               gwe=gwe, half=half, int8=int8)
-    out = pl.pallas_call(
-        kernel,
-        name="sbh_hist_radix",
-        grid=(ncw, nblk),
-        in_specs=[
-            pl.BlockSpec((cw, BLOCK_ROWS), lambda g, j: (g, j)),
-            pl.BlockSpec((1, BLOCK_ROWS), lambda g, j: (0, j)),
-            pl.BlockSpec((S_STATS, BLOCK_ROWS), lambda g, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((1, cc, gwe * NH * S_STATS, nl),
-                               lambda g, j: (g, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct(
-            (ncw, cc, gwe * NH * S_STATS, nl),
-            jnp.int32 if int8 else jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
-    )(codesP, heap.reshape(1, n_pad), stats)
-    # (ncw, cc, gwe, NH, S, nl) -> (gwe, c_pack, S, NH*nl = n_bins)
-    out = out.reshape(ncw, cc, gwe, RADIX_NH, S_STATS, nl)
-    return out.transpose(2, 0, 1, 4, 3, 5).reshape(
-        gwe, ncw * cc, S_STATS, n_bins)
 
 
 # ===========================================================================
@@ -732,7 +544,7 @@ def _fused_applicable(L_h: int, n_bins: int, c_pack: int) -> bool:
 
 def _fused_kernel(codesP_ref, heap_ref, tbl_ref, route_ref, stats_ref,
                   heap_out_ref, hist_ref, *, base_r, L_r, base_h, L_h,
-                  n_bins, any_cat, na_code, gwe, int8, radix):
+                  n_bins, any_cat, na_code, gwe):
     j = pl.program_id(0)
 
     @pl.when(j == 0)
@@ -745,34 +557,22 @@ def _fused_kernel(codesP_ref, heap_ref, tbl_ref, route_ref, stats_ref,
                           n_bins=n_bins, any_cat=any_cat, na_code=na_code)
     heap_out_ref[0, :] = newheap
     # histogram over the UPDATED heap: left children of [base_h, base_h+L_h)
-    stats = stats_ref[...]
-    if radix:
-        leaf = newheap - base_h
-        slot = leaf >> 1
-        inw = (leaf >= 0) & (leaf < L_h) & ((leaf & 1) == 0)
-        slot_c = jnp.where(inw, slot, gwe)
-        parts = _radix_parts(words, slot_c, stats, gwe=gwe,
-                             n_bins=n_bins, int8=int8)
-    else:
-        A = _stats_panel(newheap, stats, base=base_h, L=L_h, gwe=gwe,
-                         p=0, half=True, int8=int8)
-        parts = _dense_parts(words, A, n_bins=n_bins, int8=int8)
+    A = _stats_panel(newheap, stats_ref[...], base=base_h, L=L_h, gwe=gwe,
+                     p=0, half=True)
+    parts = _dense_parts(words, A, n_bins=n_bins)
     hist_ref[...] = hist_ref[...] + jnp.stack(parts)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("base_r", "L_r", "base_h", "L_h",
-                                    "n_bins", "any_cat", "na_code", "int8",
-                                    "radix"))
+                                    "n_bins", "any_cat", "na_code"))
 def sbh_route_hist_fused_pallas(codesP, heap, tbl, route_f, stats, *,
                                 base_r, L_r, base_h, L_h, n_bins,
-                                any_cat=True, na_code=255, int8=False,
-                                radix=False):
+                                any_cat=True, na_code=255):
     """ONE kernel: route splits of [base_r, base_r+L_r), then accumulate
     the half (left-children) histogram of [base_h, base_h+L_h) over the
     updated heap. Returns (newheap, hist (l_eff, c_pack, S, n_bins))."""
-    KERNEL_TRACES.inc(kernel="fused_radix" if radix else "fused",
-                      L=str(L_h))
+    KERNEL_TRACES.inc(kernel="fused", L=str(L_h))
     w_pad, n_pad = codesP.shape
     c_pack = w_pad * PACK
     l_eff = (L_h + 1) // 2
@@ -780,16 +580,10 @@ def sbh_route_hist_fused_pallas(codesP, heap, tbl, route_f, stats, *,
     nblk = n_pad // BLOCK_ROWS
     n_bins_rf = route_f.shape[1]
     assert n_bins_rf == n_bins
-    if radix:
-        NH = RADIX_NH
-        nl = n_bins // NH
-        hist_shape = (c_pack, gwe * NH * S_STATS, nl)
-    else:
-        hist_shape = (c_pack, gwe * S_STATS, n_bins)
+    hist_shape = (c_pack, gwe * S_STATS, n_bins)
     kernel = functools.partial(_fused_kernel, base_r=base_r, L_r=L_r,
                                base_h=base_h, L_h=L_h, n_bins=n_bins,
-                               any_cat=any_cat, na_code=na_code, gwe=gwe,
-                               int8=int8, radix=radix)
+                               any_cat=any_cat, na_code=na_code, gwe=gwe)
     newheap, hist = pl.pallas_call(
         kernel,
         name="sbh_route_hist_fused",
@@ -807,41 +601,31 @@ def sbh_route_hist_fused_pallas(codesP, heap, tbl, route_f, stats, *,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
-            jax.ShapeDtypeStruct(hist_shape,
-                                 jnp.int32 if int8 else jnp.float32),
+            jax.ShapeDtypeStruct(hist_shape, jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(codesP, heap.reshape(1, n_pad), tbl, route_f, stats)
-    if radix:
-        nl = n_bins // RADIX_NH
-        hist = hist.reshape(c_pack, gwe, RADIX_NH, S_STATS, nl) \
-            .transpose(1, 0, 3, 2, 4).reshape(gwe, c_pack, S_STATS, n_bins)
-    else:
-        hist = hist.reshape(c_pack, gwe, S_STATS, n_bins) \
-            .transpose(1, 0, 2, 3)
+    hist = hist.reshape(c_pack, gwe, S_STATS, n_bins).transpose(1, 0, 2, 3)
     return newheap[0], hist
 
 
 def sbh_route_hist(codes, heap, tbl, route_f, stats, *, base_r, L_r,
-                   base_h, L_h, n_bins, any_cat=True, na_code=255,
-                   int8=False, fused=None, radix=False):
+                   base_h, L_h, n_bins, any_cat=True, na_code=255):
     """Fused-or-sequential level pass: route the previous level's splits,
     then accumulate the new level's half (left-children) histogram over
-    the updated heap. `fused`: None = engage the fused Pallas program
-    wherever the level qualifies (`_fused_applicable`), False = always
-    sequential; the sequential path is also the XLA/CPU path and is
-    semantically identical (tier-1 gated). Returns (newheap, hist)."""
-    if (is_packed(codes) and fused is not False
+    the updated heap. The fused Pallas program wherever the level
+    qualifies (`_fused_applicable`); the sequential pair elsewhere — it
+    is also the XLA/CPU path and is semantically identical (tier-1
+    gated). Returns (newheap, hist)."""
+    if (is_packed(codes)
             and _fused_applicable(L_h, n_bins, codes.shape[0] * PACK)):
-        use_radix = bool(radix) and _radix_applicable(L_h, n_bins, True)
         return sbh_route_hist_fused_pallas(
             codes, heap, tbl, route_f, stats, base_r=base_r, L_r=L_r,
             base_h=base_h, L_h=L_h, n_bins=n_bins, any_cat=any_cat,
-            na_code=na_code, int8=int8, radix=use_radix)
+            na_code=na_code)
     newheap, _ = sbh_route(codes, heap, tbl, route_f, base=base_r, L=L_r,
                            any_cat=any_cat, na_code=na_code)
-    hist_fn = sbh_hist_i8 if int8 else sbh_hist
-    hist = hist_fn(codes, newheap, stats, base=base_h, L=L_h,
-                   n_bins=n_bins, half=True, radix=radix)
+    hist = sbh_hist(codes, newheap, stats, base=base_h, L=L_h,
+                    n_bins=n_bins, half=True)
     return newheap, hist

@@ -17,12 +17,6 @@ therefore also proves the pack/extract round trip on-chip.
 
 Every kernel the selection rules can pick is checked, with no gate in
 front: a kernel this installation's compiler refuses FAILS the check.
-`kernel_parity_check` covers what the default rules select at that width.
-`optin_parity_check` covers the two opt-in families (int8 stats, radix)
-at 16 columns: at HIGGS's 32 Mosaic refuses the radix kernel and every
-int8 window below 64 leaves (VMEM; the first chip run of ISSUE 22 and the
-same compile for a described chip) — which is why neither is on a default
-path.
 """
 
 from __future__ import annotations
@@ -47,30 +41,28 @@ LEVELS = (1, 8, 128)
 HIST_TOL = 1e-3
 
 
-def _rand_inputs(seed, L, c_pad=C_PAD, n_pad=N_PAD):
+def _rand_inputs(seed, L):
     """Random uint8 codes incl. NA codes + their packed plane + heap
     spread over [base, base+L) + bf16-representable f32 stats."""
     rng = np.random.default_rng(seed)
-    codes = rng.integers(0, B_VAL, (c_pad, n_pad)).astype(np.uint8)
-    codes[rng.random((c_pad, n_pad)) < 0.05] = B_VAL          # NA code
+    codes = rng.integers(0, B_VAL, (C_PAD, N_PAD)).astype(np.uint8)
+    codes[rng.random((C_PAD, N_PAD)) < 0.05] = B_VAL          # NA code
     base = L - 1
-    heap = rng.integers(base, base + L, n_pad).astype(np.int32)
-    stats = rng.normal(0, 1, (HP.S_STATS, n_pad)).astype(np.float32)
+    heap = rng.integers(base, base + L, N_PAD).astype(np.int32)
+    stats = rng.normal(0, 1, (HP.S_STATS, N_PAD)).astype(np.float32)
     stats[3] = 0.0
     stats = jnp.asarray(stats).astype(jnp.bfloat16).astype(jnp.float32)
-    si = jnp.asarray(
-        rng.integers(-127, 128, stats.shape).astype(np.int32))
     u8 = jnp.asarray(codes)
-    return u8, HP.pack_codes(u8), jnp.asarray(heap), stats, si, base
+    return u8, HP.pack_codes(u8), jnp.asarray(heap), stats, base
 
 
-def _route_tables(rng, L, c_pad=C_PAD):
+def _route_tables(rng, L):
     """Random split tables incl. categorical SET routing + NA dir. The
     pallas numeric fast path reads tbl rows 2/3 while the xla fallback
     always reads route_f — route_num is built consistent with both."""
     Lp = max(8, L)
     tbl = np.zeros((8, Lp), np.float32)
-    tbl[0, :L] = rng.integers(0, c_pad, L)
+    tbl[0, :L] = rng.integers(0, C_PAD, L)
     tbl[1, :L] = rng.random(L) < 0.8
     tbl[2, :L] = rng.integers(0, B_VAL - 1, L)       # numeric split bin
     tbl[3, :L] = rng.random(L) < 0.5                 # NA goes left
@@ -107,33 +99,28 @@ def _run_cases(cases) -> dict:
             d = float(jnp.max(jnp.abs(a.astype(jnp.float32)
                                       - b.astype(jnp.float32))))
             devs[f"{tag}#{k}"] = d
-            # heaps and i32 histograms are exact; f32 within `tol`
+            # heaps are exact; f32 within `tol`
             assert d == 0 if exact else d < tol, (tag, k, d)
     return devs
 
 
-def _add_hist(cases, seed, int8=False, c_pad=C_PAD, n_pad=N_PAD):
-    """Dense histogram at every (L, window). int8 stats: only the (L,
-    window) pairs grow() reaches (the full window only at the root)."""
-    kernel = HP.sbh_hist_pallas_i8 if int8 else HP.sbh_hist_pallas
+def _add_hist(cases, seed):
+    """Dense histogram at every (L, window)."""
     for L in LEVELS:
-        u8, packed, heap, stats, si, base = _rand_inputs(
-            seed + L, L, c_pad=c_pad, n_pad=n_pad)
-        st = si if int8 else stats
+        u8, packed, heap, stats, base = _rand_inputs(seed + L, L)
         for half in (False, True):
-            if int8 and half != (L > 1):
-                continue
             l_eff = (L + 1) // 2 if half else L
             kw = dict(base=base, L=L, n_bins=N_BINS, half=half)
 
             def cut(h, l_eff=l_eff):
-                return h[:l_eff, :c_pad]
+                return h[:l_eff, :C_PAD]
             cases.append((
-                f"hist_L={L}_half={half}_i8={int8}",
-                lambda c, h, s, kw=kw, cut=cut: cut(kernel(c, h, s, **kw)),
+                f"hist_L={L}_half={half}",
+                lambda c, h, s, kw=kw, cut=cut: cut(
+                    HP.sbh_hist_pallas(c, h, s, **kw)),
                 lambda c, h, s, kw=kw, cut=cut: cut(
                     HP.sbh_hist_xla(c, h, s, **kw)),
-                (packed, heap, st), (u8, heap, st), HIST_TOL))
+                (packed, heap, stats), (u8, heap, stats), HIST_TOL))
 
 
 def _add_route(cases, seed):
@@ -141,7 +128,7 @@ def _add_route(cases, seed):
     and categorical SET tables; terminal (heap + fused F update — the
     same code for both table kinds) at the last level."""
     for L in LEVELS:
-        u8, packed, heap, _, _, base = _rand_inputs(seed + 10 + L, L)
+        u8, packed, heap, _, base = _rand_inputs(seed + 10 + L, L)
         rng = np.random.default_rng(seed + 20 + L)
         tbl, route_cat, route_num = _route_tables(rng, L)
         for any_cat, route_f in ((False, route_num), (True, route_cat)):
@@ -172,29 +159,25 @@ def _add_route(cases, seed):
             (u8, heap, tbl, route_num, valtab, F), 1e-5))
 
 
-def _add_fused(cases, seed, specs, c_pad=C_PAD, n_pad=N_PAD):
+def _add_fused(cases, seed, specs):
     """Level-fused route+hist vs the sequential XLA pair (the exact
     grow() level-d contract: route [base_r, base_r+L_r) then half-hist
-    [base_h, base_h+L_h)). `specs` = (L_h, any_cat, int8, radix)."""
-    for L_h, any_cat, int8, radix in specs:
+    [base_h, base_h+L_h)). `specs` = (L_h, any_cat)."""
+    for L_h, any_cat in specs:
         L_r = L_h >> 1
         base_r, base_h = L_r - 1, L_h - 1
         l_eff = (L_h + 1) // 2
-        u8, packed, heap, stats, si, _ = _rand_inputs(
-            seed + 30 + L_h, L_r, c_pad=c_pad, n_pad=n_pad)
+        u8, packed, heap, stats, _ = _rand_inputs(seed + 30 + L_h, L_r)
         rng = np.random.default_rng(seed + 40 + L_h)
-        tbl, route_cat, route_num = _route_tables(rng, L_r, c_pad=c_pad)
+        tbl, route_cat, route_num = _route_tables(rng, L_r)
         route_f = route_cat if any_cat else route_num
-        st = si if int8 else stats
 
         def fused(c, h, t, r, s, L_r=L_r, base_r=base_r, base_h=base_h,
-                  L_h=L_h, any_cat=any_cat, int8=int8, radix=radix,
-                  l_eff=l_eff):
+                  L_h=L_h, any_cat=any_cat, l_eff=l_eff):
             nh, hist = HP.sbh_route_hist_fused_pallas(
                 c, h, t, r, s, base_r=base_r, L_r=L_r, base_h=base_h,
-                L_h=L_h, n_bins=N_BINS, any_cat=any_cat, na_code=B_VAL,
-                int8=int8, radix=radix)
-            return nh, hist[:l_eff, :c_pad]
+                L_h=L_h, n_bins=N_BINS, any_cat=any_cat, na_code=B_VAL)
+            return nh, hist[:l_eff, :C_PAD]
 
         def pair(c, h, t, r, s, L_r=L_r, base_r=base_r, base_h=base_h,
                  L_h=L_h, any_cat=any_cat, l_eff=l_eff):
@@ -202,12 +185,12 @@ def _add_fused(cases, seed, specs, c_pad=C_PAD, n_pad=N_PAD):
                                      any_cat=any_cat, na_code=B_VAL)
             return nh, HP.sbh_hist_xla(c, nh, s, base=base_h, L=L_h,
                                        n_bins=N_BINS, half=True)[:l_eff]
-        cases.append((f"fused_L={L_h}_cat={any_cat}_i8={int8}_radix={radix}",
-                  fused, pair, (packed, heap, tbl, route_f, st),
-                  (u8, heap, tbl, route_f, st), HIST_TOL))
+        cases.append((f"fused_L={L_h}_cat={any_cat}",
+                      fused, pair, (packed, heap, tbl, route_f, stats),
+                      (u8, heap, tbl, route_f, stats), HIST_TOL))
 
 
-def fused_levels(c_pack=C_PAD, n_bins=N_BINS):
+def fusable_levels(c_pack=C_PAD, n_bins=N_BINS):
     """Every L_h of a depth<=10 tree the fused shape rule admits."""
     return [1 << d for d in range(1, 11)
             if HP._fused_applicable(1 << d, n_bins, c_pack)]
@@ -222,37 +205,9 @@ def kernel_parity_check(seed=0):
     # one program pair per family: a refusal names its family, and each
     # family's Mosaic kernels still compile in parallel
     for add in (_add_hist, _add_route, functools.partial(
-            _add_fused, specs=[(L_h, False, False, False)
-                               for L_h in fused_levels()]
-            + [(LEVELS[1], True, False, False)])):
+            _add_fused, specs=[(L_h, False) for L_h in fusable_levels()]
+            + [(LEVELS[1], True)])):
         cases = []
         add(cases, seed)
         devs.update(_run_cases(cases))
     return devs
-
-
-def optin_parity_check(seed=0, c_pad=16):
-    """The opt-in families, for the chip run that decides whether they
-    live (ROADMAP D2): int8 stats (hist at the windows grow() reaches,
-    fused) and radix at effective window 1 (full at the root, half at
-    L=2; f32 + i8; standalone and fused). Radix at effective window 2 —
-    (L=2, full), (L=4, half) — is left out: Mosaic refuses it at 16
-    columns in f32 and even at 8 with int8 stats."""
-    n_pad = 2 * HP.BLOCK_ROWS
-    cases = []
-    _add_hist(cases, seed, int8=True, c_pad=c_pad, n_pad=n_pad)
-    for Lw, half in ((1, False), (2, True)):
-        u8, packed, heap, stats, si, base = _rand_inputs(
-            seed + 50 + Lw, Lw, c_pad=c_pad, n_pad=n_pad)
-        kw = dict(base=base, L=Lw, n_bins=N_BINS, half=half)
-        for int8, st in ((False, stats), (True, si)):
-            cases.append((
-                f"radix_L={Lw}_half={half}_i8={int8}",
-                lambda c, h, s, kw=kw, int8=int8: HP.sbh_hist_radix(
-                    c, h, s, int8=int8, **kw)[:1, :c_pad],
-                lambda c, h, s, kw=kw: HP.sbh_hist_xla(c, h, s, **kw)[:1],
-                (packed, heap, st), (u8, heap, st), HIST_TOL))
-    _add_fused(cases, seed,
-               [(LEVELS[1], True, True, False), (2, True, False, True),
-                (2, True, True, True)], c_pad=c_pad, n_pad=n_pad)
-    return _run_cases(cases)

@@ -578,8 +578,8 @@ class ParamStore:
                 self._pinned.discard(model_key)
 
     def demote_key(self, model_key: str, to_tier: str = TIER_HOST):
-        """Demote every device-resident placement of a model (tests,
-        bench, and operator tooling)."""
+        """Demote every device-resident placement of a model (tests and
+        operator tooling)."""
         with self._lock:
             ps = [p for k, p in self._placements.items()
                   if k[0] == model_key]

@@ -15,7 +15,7 @@ def main():
     pid, nproc, coord_port, rest_port = (int(a) for a in sys.argv[1:5])
     join = len(sys.argv) > 5 and sys.argv[5] == "join"
     # CPU children by construction, whatever the environment says: the
-    # parent (a test, or bench.py on a machine with a chip) may hold the
+    # parent (a test on a machine with a chip) may hold the
     # accelerator, and a chip belongs to one process at a time
     import jax
     jax.config.update("jax_platforms", "cpu")

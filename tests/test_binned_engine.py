@@ -8,7 +8,6 @@ hex/tree/ScoreBuildHistogram2.java histogram semantics.
 """
 
 import numpy as np
-import jax
 import jax.numpy as jnp
 import pytest
 
@@ -180,7 +179,7 @@ def test_binned_matches_adaptive_quality():
 
 # ===========================================================================
 # Round-4 gates: uint8 code planes end-to-end, packed-plane round trip,
-# fused route+hist, radix factorization math (promoted from experiments/).
+# fused route+hist.
 def test_quantize_emits_uint8_codes():
     rng = np.random.default_rng(5)
     X = rng.normal(0, 1, (700, 5)).astype(np.float32)
@@ -238,10 +237,9 @@ def test_uint8_vs_i32_code_planes_bit_exact():
 
 def test_fused_route_hist_matches_sequential():
     """sbh_route_hist (fused dispatcher) == explicit route then half-hist,
-    heaps and histograms, f32 and int8 stats (ISSUE 14 acceptance: fused
-    vs unfused identical; on CPU both ride the XLA reference pair — the
-    on-chip fused Pallas program is held to the same contract by
-    ops/parity.py)."""
+    heaps and histograms (ISSUE 14 acceptance: fused vs unfused identical;
+    on CPU both ride the XLA reference pair — the on-chip fused Pallas
+    program is held to the same contract by ops/parity.py)."""
     rng = np.random.default_rng(8)
     n_pad, c_pad, nb, b_val = 2048, 8, 128, 100
     L_h = 8
@@ -250,110 +248,40 @@ def test_fused_route_hist_matches_sequential():
     u8 = jnp.asarray(rng.integers(0, b_val + 1, (c_pad, n_pad)), jnp.uint8)
     heap = jnp.asarray(rng.integers(base_r, base_r + L_r, n_pad), jnp.int32)
     stats = jnp.asarray(rng.normal(0, 1, (4, n_pad)), jnp.float32)
-    stats_i8 = jnp.asarray(rng.integers(-127, 128, (4, n_pad)), jnp.int32)
     tbl = np.zeros((8, 8), np.float32)
     tbl[0, :L_r] = rng.integers(0, c_pad, L_r)
     tbl[1, :L_r] = rng.random(L_r) < 0.8
     tbl = jnp.asarray(tbl)
     route_f = jnp.asarray((rng.random((8, nb)) < 0.5).astype(np.float32))
-    for int8, st in ((False, stats), (True, stats_i8)):
-        for fused in (None, False):
-            nh, hist = HP.sbh_route_hist(
-                u8, heap, tbl, route_f, st, base_r=base_r, L_r=L_r,
-                base_h=base_h, L_h=L_h, n_bins=nb, na_code=b_val,
-                int8=int8, fused=fused)
-            nh_ref, _ = HP.sbh_route_xla(u8, heap, tbl, route_f,
-                                         base=base_r, L=L_r, na_code=b_val)
-            hist_ref = HP.sbh_hist_xla(u8, nh_ref, st, base=base_h,
-                                       L=L_h, n_bins=nb, half=True)
-            np.testing.assert_array_equal(np.asarray(nh), np.asarray(nh_ref))
-            np.testing.assert_array_equal(np.asarray(hist),
-                                          np.asarray(hist_ref))
+    nh, hist = HP.sbh_route_hist(
+        u8, heap, tbl, route_f, stats, base_r=base_r, L_r=L_r,
+        base_h=base_h, L_h=L_h, n_bins=nb, na_code=b_val)
+    nh_ref, _ = HP.sbh_route_xla(u8, heap, tbl, route_f,
+                                 base=base_r, L=L_r, na_code=b_val)
+    hist_ref = HP.sbh_hist_xla(u8, nh_ref, stats, base=base_h,
+                               L=L_h, n_bins=nb, half=True)
+    np.testing.assert_array_equal(np.asarray(nh), np.asarray(nh_ref))
+    np.testing.assert_array_equal(np.asarray(hist), np.asarray(hist_ref))
 
 
-def test_grow_radix_fused_flags_bit_identical():
-    """BinnedGrower(use_radix_shallow/fused_level any combination) must
-    produce bit-identical trees and margins — the flags select kernels,
-    never semantics. On CPU the uint8 plane routes every combination
-    through the XLA reference pair, so this gates the flag PLUMBING
-    (auto/off wiring cannot change the grow); the on-chip Pallas kernels
-    behind the flags are held to the reference by ops/parity.py and the
-    sbh-level identity tests above."""
-    rng = np.random.default_rng(9)
-    n, C = 3000, 4
-    X = rng.normal(0, 1, (n, C)).astype(np.float32)
-    y = (X[:, 0] + 0.5 * X[:, 1] > 0).astype(np.float32)
-    spec = BN.make_bins(X, np.zeros(C, bool), 32)
-    n_pad = BN.padded_rows(n)
-    codes = BN.quantize(jnp.asarray(X), spec, n_pad=n_pad)
-    w1 = BN.pad_rows(jnp.ones(n, jnp.float32), n_pad)
-    y1 = BN.pad_rows(jnp.asarray(y), n_pad)
-    stats = jnp.stack([w1, w1 * (y1 - 0.5), w1 * 0.25,
-                       jnp.zeros_like(w1)], axis=0)
-    F = jnp.zeros(n_pad, jnp.float32)
-    outs = []
-    for radix, fused in ((None, None), (False, False), (None, False),
-                         (False, None)):
-        g = BN.BinnedGrower(spec, max_depth=4, min_rows=2.0,
-                            min_split_improvement=0.0,
-                            use_radix_shallow=radix, fused_level=fused)
-        out = g.grow(codes, stats, F, eta=0.1, clip_val=0.0,
-                     key=jax.random.PRNGKey(0))
-        outs.append(out)
-    ref = outs[0]
-    for o in outs[1:]:
-        for k in ("col", "bin", "val", "F"):
-            np.testing.assert_array_equal(np.asarray(ref[k]),
-                                          np.asarray(o[k]))
-
-
-def _radix_math(codes, heap, stats, *, base, L, nb):
-    """Pure-jnp replica of the radix kernel's factorization (promoted
-    from experiments/radix_hist.py check_math into tier-1): key =
-    slot*16 + hi fused compare, 16-wide lo one-hot, vs the dense XLA
-    reference."""
-    NH = HP.RADIX_NH
-    S = HP.S_STATS
-    c_pad, n_pad = codes.shape
-    nl = nb // NH
-    leaf = heap - base
-    inw = (leaf >= 0) & (leaf < L)
-    leaf_c = jnp.where(inw, leaf, L)
-    outs = []
-    for c in range(c_pad):
-        code = codes[c].astype(jnp.int32)
-        key = leaf_c * NH + code // nl
-        lo = code % nl
-        J = jax.nn.one_hot(key, L * NH, dtype=jnp.float32)
-        A = (J[:, :, None] * stats.T[:, None, :]).reshape(n_pad, L * NH * S)
-        ohlo = jax.nn.one_hot(lo, nl, dtype=jnp.float32)
-        h = A.T @ ohlo
-        outs.append(h.reshape(L, NH, S, nl).transpose(0, 2, 1, 3)
-                    .reshape(L, S, nb))
-    return jnp.stack(outs, axis=1)
-
-
-def test_radix_factorization_math():
-    rng = np.random.default_rng(0)
-    n, c_pad, nb = 4096, 8, 256
-    for L in (1, 2, 4):
-        codes = jnp.asarray(rng.integers(0, nb, (c_pad, n)), jnp.uint8)
-        base = L - 1
-        heap = jnp.asarray(rng.integers(base, base + L + 1, n), jnp.int32)
-        stats = jnp.asarray(rng.normal(0, 1, (4, n)), jnp.float32)
-        got = _radix_math(codes, heap, stats, base=base, L=L, nb=nb)
-        want = HP.sbh_hist_xla(codes, heap, stats, base=base, L=L,
-                               n_bins=nb)
-        d = float(jnp.max(jnp.abs(got - want[:L])))
-        assert d < 1e-2, (L, d)
-        # int8-stats variant: the factorization must be EXACT in integers
-        si = jnp.asarray(rng.integers(-127, 128, (4, n)), jnp.int32)
-        got_i = _radix_math(codes, heap, si.astype(jnp.float32),
-                            base=base, L=L, nb=nb)
-        want_i = HP.sbh_hist_xla(codes, heap, si, base=base, L=L,
-                                 n_bins=nb)
-        di = float(jnp.max(jnp.abs(got_i - want_i[:L].astype(jnp.float32))))
-        assert di == 0.0, (L, di)
+@pytest.mark.parametrize("flag", ["int8_hist", "radix_shallow",
+                                  "fused_level"])
+def test_kernel_selecting_parameters_are_gone(flag):
+    """ISSUE 32: no option selects a histogram kernel — the three former
+    estimator parameters are unknown, like any other misspelling."""
+    import h2o3_tpu.models  # noqa: F401 — registers every tree estimator
+    from h2o3_tpu.models.tree.shared_tree import (
+        H2OGradientBoostingEstimator, SharedTreeEstimator)
+    with pytest.raises(ValueError, match="unknown parameters"):
+        H2OGradientBoostingEstimator(**{flag: True})
+    assert flag not in SharedTreeEstimator._tree_defaults
+    todo, seen = [SharedTreeEstimator], []
+    while todo:
+        cls = todo.pop()
+        seen.append(cls)
+        todo += cls.__subclasses__()
+        assert flag not in getattr(cls, "_defaults", {}), cls.__name__
+    assert len(seen) >= 6, seen     # GBM, DRF, XGBoost, IF, EIF + the base
 
 
 def test_tree_codes_plane_registered_with_pager(monkeypatch):

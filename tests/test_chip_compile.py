@@ -18,11 +18,6 @@ and every such compile lives in THIS one file.
 Also here: the scoring walk's dense body (engine._ensemble_walk) at the
 benchmark's frame, 2,750,000 x 28, for its two ensembles — XLA, no kernel
 — on one chip and row-sharded over the host's four.
-
-Left out on purpose: `sbh_hist_radix` at 32 columns, which the compiler
-refuses (RESOURCE_EXHAUSTED ... vmem ... f32[1,32,64,16]) after ~3 minutes
-— the reason the radix family is opt-in; `radix_not_default` pins that no
-default rule selects it.
 """
 
 import functools
@@ -135,7 +130,7 @@ FAMILIES = {
     "route_terminal": lambda sds: [
         _route(sds, 1, True), _route(sds, LAST, True)],
     "fused": lambda sds: [
-        _fused(sds, L_h) for L_h in parity.fused_levels()],
+        _fused(sds, L_h) for L_h in parity.fusable_levels()],
 }
 
 
@@ -177,14 +172,14 @@ def _higgs_trainer(monkeypatch, sds, mesh=None, k_trees=2):
 # histogram fits VMEM, the route + half-hist pair below, terminal route
 DEFAULT_KERNELS = ({("hist", 1), ("hist", 64), ("hist", 128),
                     ("route", 32), ("route", 64), ("route_f", 128)}
-                   | {("fused", L_h) for L_h in parity.fused_levels()})
+                   | {("fused", L_h) for L_h in parity.fusable_levels()})
 
 
 @pytest.mark.parametrize("name", sorted(FAMILIES) + [
-    "trainer", "trainer_4chips", "radix_not_default"])
+    "trainer", "trainer_4chips", "default_kernels"])
 def test_compiles_for_v5e_at_higgs_width(name, topo, sds,
                                          no_persistent_cache, monkeypatch):
-    if name == "radix_not_default":
+    if name == "default_kernels":
         # tracing alone shows which kernels the default rules pick; the
         # cleared caches make every inner jit run its Python body again
         jax.clear_caches()

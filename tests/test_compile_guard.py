@@ -58,12 +58,12 @@ def test_one_bucket_three_row_counts_at_most_one_compile():
 
 
 def test_binned_level_loop_dispatch_bounded():
-    """ISSUE 14 dispatch-count guard: the eager per-level grow loop (the
-    bench's instrumented path) must dispatch a BOUNDED number of compiled
-    programs per level — a change that sneaks a per-leaf or per-column
-    jit into the loop (a closure jit, an unhashable static arg, a fresh
-    lambda) shows up here as a compile-count explosion; and a second
-    identical run must add ZERO compiles (every program is cached)."""
+    """ISSUE 14 dispatch-count guard: the eager per-level grow loop must
+    dispatch a BOUNDED number of compiled programs per level — a change
+    that sneaks a per-leaf or per-column jit into the loop (a closure
+    jit, an unhashable static arg, a fresh lambda) shows up here as a
+    compile-count explosion; and a second identical run must add ZERO
+    compiles (every program is cached)."""
     import jax
     import jax.numpy as jnp
     from h2o3_tpu.models.tree import binned as BN

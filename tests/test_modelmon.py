@@ -44,7 +44,7 @@ def _fresh_modelmon(monkeypatch):
     # background evaluators stay off: tests drive evaluate() explicitly.
     # The tap's duty-cycle throttle and stride cap are disabled so the
     # sketches see every row deterministically (the throttle has its own
-    # unit tests below; bench.py measures it at the defaults).
+    # unit tests below).
     monkeypatch.setenv("H2O3_MODELMON_EVAL_S", "0")
     monkeypatch.setenv("H2O3_MODELMON_TAP_PCT", "100")
     monkeypatch.setenv("H2O3_MODELMON_TAP_ROWS", "0")

@@ -251,13 +251,10 @@ def test_metrics_endpoint_prometheus(server, gbm_via_rest):
     assert m and int(m.group(1)) >= 3 * 200, "rows*trees counter"
     m = re.search(r'^h2o3_dkv_objects\{what="keys"\} (\d+)$', text, re.M)
     assert m and int(m.group(1)) >= 1, "dkv gauge"
-    # level histogram is labeled per (engine, level) now: 3 trees land
-    # 3+ observations on each adaptive level series
+    # the REST fit itself: the model-build POST and the job polls
     counts = [int(v) for v in re.findall(
-        r'^h2o3_tree_level_seconds_count\{engine="adaptive",'
-        r'level="\d+"\} (\d+)$', text, re.M)]
-    assert len(counts) >= 3 and sum(counts) >= 9, \
-        "level histogram (3 trees x 3 lvls)"
+        r'^h2o3_rest_request_seconds_count\{[^}]*\} (\d+)$', text, re.M)]
+    assert counts and sum(counts) >= 2, "request histogram"
 
 
 def test_timeline_endpoint_spans_and_nesting(server, gbm_via_rest):
@@ -492,7 +489,7 @@ def test_span_is_an_event_of_the_host_plane_under_a_capture(tmp_path):
     try:
         t0 = time.time()
         with span("t.on_the_host_plane"):
-            time.sleep(0.01)
+            time.sleep(0.05)
     finally:
         jax.profiler.stop_trace()
     path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
@@ -503,7 +500,11 @@ def test_span_is_an_event_of_the_host_plane_under_a_capture(tmp_path):
     assert len(found) == 1, [pl.name for pl in pd.planes]
     plane, dur_ns = found[0]
     assert "host" in plane.lower() and not plane.startswith("/device:")
-    ring = SPANS.snapshot()[-1]
-    assert ring["name"] == "t.on_the_host_plane" and ring["start"] >= t0
-    # the event and the ring's span time the same block
-    assert abs(dur_ns / 1e6 - ring["duration_ms"]) < 5.0
+    # by name: other threads of the suite append to the ring too
+    ring, = [s for s in SPANS.snapshot()
+             if s["name"] == "t.on_the_host_plane" and s["start"] >= t0]
+    # the event and the ring's span time the same block: they differ by
+    # what the host does between their clock reads, a share of the span
+    assert ring["duration_ms"] >= 50.0
+    assert abs(dur_ns / 1e6 - ring["duration_ms"]) \
+        < 0.25 * ring["duration_ms"]

@@ -38,7 +38,6 @@ def _fresh_usage():
     qos.reset()
     usage.reset()
     yield
-    usage.set_enabled(None)
     qos.reset()
     usage.reset()
 
@@ -99,8 +98,8 @@ def test_device_rate_nonzero_under_sustained_charging():
     assert usage.device_rate(window_s=1.0) > 0.0
 
 
-def test_ledger_disabled_is_free():
-    usage.set_enabled(False)
+def test_ledger_disabled_is_free(monkeypatch):
+    monkeypatch.setenv("H2O3_USAGE", "0")
     with usage.meter("score", model="m", rows=1):
         time.sleep(0.001)
     usage.begin_request()
