@@ -8,7 +8,8 @@ depth 8, bernoulli; only the tree count is cut):
 
     device  -> jax.devices() must be a TPU, else exit non-zero
     kernels -> Pallas kernels == their XLA twins at HIGGS width, on chip
-    walk    -> the dense scoring walk == the gather walk, leaf for leaf
+    walk    -> the dense scoring walk == the gather walk, leaf for leaf,
+               at both cells' shapes (10 x depth 8, 20 x depth 5)
     ingest  -> seeded CSV through h2o3_tpu.import_file (native tokenizer)
     train   -> 11M x 28 Frame -> H2OGradientBoostingEstimator.train
     predict -> large-frame sharded path AND compiled-scorer fast path,
@@ -57,6 +58,7 @@ INGEST_ROWS = 1_000_000
 SLICE_ROWS = 1_000_000       # <= scorer_cache._max_rows(): the fast path
 CHECK_ROWS = 10_000          # rows compared with the host scorer, per path
 WALK_ROWS = 1_000_000        # rows walked by both bodies of the scoring walk
+WALK_SHAPES = ((10, 8), (20, 5))   # (ntrees, depth) of the benchmark's two cells
 PARITY_ROWS = 1_100_003      # device-built frame == host-built: over the fast
                              # path's 2^20 rows, not a multiple of the padding
 ONE_ROW_MS_PR28 = (7.41, 7.66)   # PERF.md's 1-row medians, to read "1" against
@@ -167,56 +169,75 @@ def phase_kernels(seed: int) -> dict:
             "kernels": sorted({k for k, _ in HP.kernel_traces()})}
 
 
-def phase_walk_exact(rows: int, ntrees: int, seed: int) -> dict:
+def phase_walk_exact(rows: int, shapes, seed: int,
+                     on_chip: bool = True) -> dict:
     """engine._walk_dense against engine._walk_gather on this device, at
-    HIGGS width and depth 8: thresholds drawn from the data's own values
-    (so `x == thr` happens), early leaves, NaN and ±inf among the rows.
-    Tree by tree the value walked to is the NODE's own number, so `==`
-    is leaf for leaf; then once more with random values and weights, the
+    HIGGS width, for each (ntrees, depth) of `shapes` — the two cells'
+    ensembles: 10 x depth 8 (two node blocks a tree) and 20 x depth 5 (four
+    trees a block): thresholds drawn from the data's own values (so
+    `x == thr` happens), early leaves, NaN and ±inf among the rows. Tree by
+    tree the value walked to is the NODE's own number, so `==` is leaf
+    for leaf; then once more with random values and weights, the
     ensemble's sum bit for bit. The CPU's matmul is exact whatever its
     operands; only the chip can show that its bfloat16 products select a
-    feature's bytes exactly."""
+    feature's bytes exactly, and that the fused kernel (`walk_dense_tile`)
+    is what ran."""
     import jax.numpy as jnp
     from h2o3_tpu.models.tree import engine as E
-    assert E._walk_path(DEPTH, COLS, False) == "dense"
+    from h2o3_tpu.ops import hist_pallas as HP
     rng = np.random.default_rng(seed + 2)
     X, _ = higgs_like(rows, seed)
     for v, p in ((np.nan, 0.01), (np.inf, 0.001), (-np.inf, 0.001),
                  (0.0, 0.001), (-0.0, 0.001)):
         X[rng.random(X.shape) < p] = v
-    nodes, inner = 2 ** (DEPTH + 1) - 1, 2 ** DEPTH - 1
-    col = rng.integers(0, COLS, size=(ntrees, nodes)).astype(np.int32)
-    col[:, inner:] = -1
-    col[:, 1:inner][rng.random((ntrees, inner - 1)) < 0.1] = -1
-    thr = X[rng.integers(0, rows, size=col.shape), np.maximum(col, 0)]
-    nal = rng.random(col.shape) < 0.5
-    ids = np.broadcast_to(np.arange(nodes, dtype=np.float32), col.shape)
     no_bits = (jnp.zeros((1, 1, 1), jnp.uint32), jnp.zeros(1, bool))
     Xd = jnp.asarray(X)
-    tbl = [jnp.asarray(a) for a in (col, thr, nal)]
+    traces0 = HP.kernel_traces()
+    recs = []
+    for ntrees, depth in shapes:
+        assert E._walk_path(depth, COLS, False) == "dense"
+        nodes, inner = 2 ** (depth + 1) - 1, 2 ** depth - 1
+        col = rng.integers(0, COLS, size=(ntrees, nodes)).astype(np.int32)
+        col[:, inner:] = -1
+        col[:, 1:inner][rng.random((ntrees, inner - 1)) < 0.1] = -1
+        thr = X[rng.integers(0, rows, size=col.shape), np.maximum(col, 0)]
+        nal = rng.random(col.shape) < 0.5
+        ids = np.broadcast_to(np.arange(nodes, dtype=np.float32), col.shape)
+        tbl = [jnp.asarray(a) for a in (col, thr, nal)]
 
-    def both(val, tw):
-        args = (Xd, *tbl, jnp.asarray(val), jnp.asarray(tw))
-        return (np.asarray(E._walk_dense(*args, depth=DEPTH)),
-                np.asarray(E._walk_gather(*args, *no_bits, depth=DEPTH,
-                                          has_cat=False)))
+        def both(val, tw):
+            args = (Xd, *tbl, jnp.asarray(val), jnp.asarray(tw))
+            return (np.asarray(E._walk_dense(*args, depth=depth)),
+                    np.asarray(E._walk_gather(*args, *no_bits, depth=depth,
+                                              has_cat=False)))
 
-    reached = set()
-    for t in range(ntrees):
-        dense, gather = both(ids, np.eye(ntrees, dtype=np.float32)[t])
-        assert np.array_equal(dense, gather), \
-            (t, int((dense != gather).sum()))
-        reached.update(np.unique(gather).astype(int).tolist())
-    on_thr = int((X[:, col[0, 0]] == thr[0, 0]).sum())
-    assert on_thr >= 1 and len(reached) > inner // 2, (on_thr, len(reached))
-    t0 = time.perf_counter()
-    dense, gather = both(rng.standard_normal(col.shape).astype(np.float32),
-                         (rng.random(ntrees) + 0.5).astype(np.float32))
-    assert np.array_equal(dense, gather), int((dense != gather).sum())
-    return {"rows": rows, "cols": COLS, "ntrees": ntrees, "depth": DEPTH,
-            "nodes_reached": len(reached), "rows_on_root_thr": on_thr,
+        reached = set()
+        for t in range(ntrees):
+            dense, gather = both(ids, np.eye(ntrees, dtype=np.float32)[t])
+            assert np.array_equal(dense, gather), \
+                (depth, t, int((dense != gather).sum()))
+            reached.update(np.unique(gather).astype(int).tolist())
+        on_thr = int((X[:, col[0, 0]] == thr[0, 0]).sum())
+        assert on_thr >= 1 and len(reached) > inner // 2, \
+            (on_thr, len(reached))
+        t0 = time.perf_counter()
+        dense, gather = both(
+            rng.standard_normal(col.shape).astype(np.float32),
+            (rng.random(ntrees) + 0.5).astype(np.float32))
+        assert np.array_equal(dense, gather), int((dense != gather).sum())
+        recs.append({"ntrees": ntrees, "depth": depth,
+                     "block": E._block_label(depth),
+                     "nodes_reached": len(reached),
+                     "rows_on_root_thr": on_thr,
+                     "both_bodies_s": round(time.perf_counter() - t0, 2)})
+    picked = sorted(k for k, v in HP.kernel_traces().items()
+                    if v > traces0.get(k, 0))
+    # on the chip the dense body IS the fused kernel, at both regimes
+    want = [("walk_dense_tile", 1 << d) for d in sorted({d for _, d in shapes})]
+    assert picked == (want if on_chip else []), picked
+    return {"rows": rows, "cols": COLS,
             "nonfinite_cells": int((~np.isfinite(X)).sum()),
-            "both_bodies_s": round(time.perf_counter() - t0, 2)}
+            "pallas_kernels_traced": picked, "shapes": recs}
 
 
 def write_csv(path: str, X, y):
@@ -313,8 +334,10 @@ def phase_train(rows: int, ntrees: int, seed: int, on_chip: bool = True):
     if on_chip:
         # the Pallas kernels are IN the compiled trainer: each entry was
         # traced into it (model_summary's "engine" string proves nothing)
+        # (the training metrics' scoring walk is the fifth)
         assert {k for k, _ in picked} == {"hist", "fused", "route",
-                                          "route_f"}, picked
+                                          "route_f",
+                                          "walk_dense_tile"}, picked
     else:
         assert not picked, picked
     summ = model._output.model_summary
@@ -648,7 +671,7 @@ def main(argv=None) -> int:
         t0 = time.perf_counter()
         _emit("kernels", t0, phase_kernels(args.seed))
         t0 = time.perf_counter()
-        _emit("walk", t0, phase_walk_exact(WALK_ROWS, NTREES, args.seed))
+        _emit("walk", t0, phase_walk_exact(WALK_ROWS, WALK_SHAPES, args.seed))
         t0 = time.perf_counter()
         _emit("ingest", t0, phase_ingest(INGEST_ROWS, args.seed, OUT_DIR))
         t0 = time.perf_counter()

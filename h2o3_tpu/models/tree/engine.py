@@ -43,6 +43,7 @@ from h2o3_tpu.obs import metrics as _om
 from h2o3_tpu.parallel import compat as _compat
 from h2o3_tpu.parallel import mesh as _mesh
 from h2o3_tpu.obs.timeline import span as _span
+from h2o3_tpu.ops import walk_pallas as _wp
 
 # Σ rows·trees processed — the headline GBM throughput numerator; bench.py
 # and /metrics read the same counter (per-ensemble rate = Δcounter/Δt)
@@ -53,8 +54,8 @@ ROW_TREES = _om.counter("h2o3_gbm_row_trees_total",
 WALKS = _om.counter(
     "h2o3_tree_walk_total",
     "predict_ensemble calls by the scoring walk's body: path=dense (every "
-    "node of a level, no per-row index) or gather (categorical or deep "
-    "trees)")
+    "node of a level, no per-row index; block = {trees a 128-slot node "
+    "block}x{slots a tree}) or gather (categorical or deep trees)")
 
 # Dense-matmul histogram path is used while (leaves × 3 stats) stays MXU-sized.
 # Measured on v5e: the one-hot matmul beats segment-sum scatter ~3× even at
@@ -390,26 +391,36 @@ jax.tree_util.register_pytree_node(TreeArrays, _trees_flatten,
 # Two bodies of one algorithm; `_walk_path` picks by shape, at trace time.
 #
 # dense: no per-row index anywhere. A tile of rows meets EVERY node of a tree
-# with dense arithmetic: the node's feature is selected on the MXU by a
-# one-hot product over the BYTES of the f32 features (exact for every bit
+# with dense arithmetic, a BLOCK of 128 node slots at a time (one MXU tile
+# wide, one vreg row of lanes): the node's feature is selected on the MXU by
+# a one-hot product over the BYTES of the f32 features (exact for every bit
 # pattern, NaN and ±inf included); the top levels are matched together
-# against the tree's path matrix, on the MXU again; the levels under them
-# by a position one-hot on the VPU. It does 2^depth node evaluations a row
-# and tree where the gather body does `depth` dependent gathers. One v5e
-# retires 24-54 M gather steps a second and 100-176 G dense evaluations
-# (2,750,000 x 28; PERF.md §6, PR 28): at depth 8 the dense body is 207 x
-# the faster, at depth 14 5.9 x, and the two would cross at depth 16-17.
-# _DENSE_MAX_CELLS bounds (2^depth - 1) x columns, the size of a tree's
-# selection matrix: depth 14 at 28 columns, the deepest shape measured, is
-# the last to take the dense body.
+# against the block's path matrix, on the MXU again; the levels under them
+# by a position one-hot on the VPU. A block holds 128 / 2^depth shallow
+# trees side by side, one tree of 7 levels, or half of a deeper tree's top
+# 8 levels ({root, left subtree}, {root, right subtree}); `_block_regime`.
+# It does 2^depth node evaluations a row and tree where the gather body
+# does `depth` dependent gathers. One v5e retires 24-54 M gather steps a
+# second and, in the fused kernel, 181-208 G dense evaluations (2,750,000 x
+# 28: 9.7 ms at 20 x depth 5, 33.8 at 10 x depth 8, 485 at 2 x depth 14,
+# where the gather body takes 3,055: PERF.md §6, PR 33; the XLA body 17.0 /
+# 45.0 / 517): at depth 8 the dense body is ~270 x the faster, at depth 14
+# 6.3 x, and the two would cross at depth 16-17. _DENSE_MAX_CELLS bounds
+# (2^depth - 1) x columns, the size of a tree's selection matrix: depth 14
+# at 28 columns, the deepest shape measured, is the last to take the dense
+# body.
 _DENSE_MAX_CELLS = 1 << 19
-# levels 0.._PATH_LEVELS-1 are matched in ONE (256, 256) product; a level
-# walked by position instead costs a compare, a select and a lane reduction
-# over (rows, 2^level): 15 ms a frame at depth 8, where the product is ~0
-_PATH_LEVELS = 8
-# rows of a tile x nodes of a tree (at least a vreg row of 128 lanes): a
-# tile's (rows, nodes) f32 intermediates are 32 MB each, whatever the depth
-# (2^21..2^24 read within 6 % of each other)
+# levels 0.._PATH_LEVELS-1 are matched by path products, (128, 128) each; a
+# level walked by position instead costs a compare, a select and a
+# reduction over (rows, 2^level)
+_PATH_LEVELS = _wp.PATH_LEVELS
+assert 1 << _PATH_LEVELS == 2 * _wp.BLOCK, "a tree's top is two blocks"
+# a tree shallower than this is scored as one of this many levels, its
+# leaves pushed down: a tree's slots then fill whole (8, 128) vregs
+_MIN_LEVELS = 3
+# the XLA body's tile: rows x slots of a scan step (128, or a deep tree's
+# 2^depth): its (rows, slots) f32 intermediates are 32 MB each, whatever
+# the depth (2^21..2^24 read within 6 % of each other: PR 28)
 _WALK_TILE_CELLS = 1 << 23
 
 
@@ -418,6 +429,24 @@ def _walk_path(depth: int, n_cols: int, has_cat: bool) -> str:
     dense = not has_cat and depth >= 1 \
         and ((1 << depth) - 1) * n_cols <= _DENSE_MAX_CELLS
     return "dense" if dense else "gather"
+
+
+def _block_regime(depth: int):
+    """(levels, trees, blocks) of one step of the dense body: the perfect
+    tree's levels, and how many trees and 128-slot blocks a step holds — 128
+    / 2^levels trees in one block up to 7 levels, one tree from 8 on, its
+    top 8 levels in two blocks."""
+    levels = max(depth, _MIN_LEVELS)
+    top = 1 << min(levels, _PATH_LEVELS)
+    return levels, max(1, _wp.BLOCK // top), max(1, top // _wp.BLOCK)
+
+
+def _block_label(depth: int) -> str:
+    """`h2o3_tree_walk_total`'s `block`: "{trees a block}x{slots a tree}" of
+    the path-matched levels — 4x32, 1x128, 0.5x256."""
+    levels, trees, blocks = _block_regime(depth)
+    per = trees if blocks == 1 else 1 / blocks
+    return f"{per:g}x{1 << min(levels, _PATH_LEVELS)}"
 
 
 def _path_matrix(levels: int) -> np.ndarray:
@@ -436,20 +465,60 @@ def _path_matrix(levels: int) -> np.ndarray:
     return P
 
 
-def _perfect_tree(col, thr, nal, val, n_cols, depth):
-    """The (T, nodes) heap arrays as a perfect tree of `depth` levels with
-    nodes numbered from 1 (level d is [2^d, 2^(d+1)): every level starts at
-    a multiple of its own width; slot 0 is a node no path visits). An early
-    leaf keeps any route and its value is pushed down to every bottom slot
-    under it, so a row always takes `depth` steps and ends on the value the
-    gather walk would have stopped at. Returns the byte-select matrices
-    (T, 2C, 2^depth) bf16, thr and na_left (T, 2^depth), and the bottom
-    level's values (T, 2^depth)."""
-    inner = (1 << depth) - 1
+def _block_paths(depth: int) -> np.ndarray:
+    """(blocks, 128, 128): the path matrix of each block of a step. Shallow
+    trees side by side: their path matrices down the diagonal. A tree's top
+    8 levels: the 7-level matrix of a subtree with the ROOT in slot 0,
+    which must have turned left for the first block's positions (0..127 of
+    level 8) and right for the second's."""
+    levels, trees, blocks = _block_regime(depth)
+    if blocks == 1:
+        return np.kron(np.eye(trees, dtype=np.float32),
+                       _path_matrix(min(levels, _PATH_LEVELS)))[None]
+    P = np.stack([_path_matrix(_PATH_LEVELS - 1)] * 2)
+    P[0, 0, :], P[1, 0, :] = -1.0, 1.0
+    return P
+
+
+def _split_top(a):
+    """The first 256 slots of the last axis (nodes numbered from 1, slot 0
+    unused) as two blocks of 128: [root, left subtree], [root, right
+    subtree], each numbered from 1 again below its slot 0."""
+    halves = ([a[..., 1:2]], [a[..., 1:2]])
+    for d in range(1, _PATH_LEVELS):
+        lo, mid, hi = 1 << d, 3 << (d - 1), 2 << d
+        halves[0].append(a[..., lo:mid])
+        halves[1].append(a[..., mid:hi])
+    return jnp.concatenate(
+        halves[0] + halves[1] + [a[..., 1 << _PATH_LEVELS:]], axis=-1)
+
+
+def _perfect_tree(col, thr, nal, val, tw, n_cols, depth):
+    """The (T, nodes) heap arrays as the dense body's steps (`_block_regime`):
+    perfect trees of `levels` levels with nodes numbered from 1 (level d is
+    [2^d, 2^(d+1)): every level starts at a multiple of its own width).
+    An early leaf keeps any route and its value is pushed down to every
+    bottom slot under it, so a row always takes `levels` steps and ends on
+    the value the gather walk would have stopped at. Shallow trees are
+    laid side by side, G to a step, the last step filled up with trees of
+    weight 0 and value 0 (acc + 0 * 0 is acc); a tree of 8 levels or more
+    has its top 256 slots as `_split_top` leaves them. Returns the one-hot
+    feature select (U, C, S) bf16, thr and na_left (U, S), the bottom
+    level's values (U, S) and the weights (U, G): S = 128, or 2^levels."""
+    levels, G, _ = _block_regime(depth)
     T = col.shape[0]
+    real, inner, L = (1 << depth) - 1, (1 << levels) - 1, 1 << levels
+
+    def deepen(a, fill):
+        return jnp.concatenate(
+            [a, jnp.full((T, 2 * L - 1 - a.shape[1]), fill, a.dtype)], axis=1)
+
+    # the bottom level is all leaves, and so is every level added under it
+    col = deepen(col[:, :real], -1)
+    thr, nal, val = deepen(thr, 0), deepen(nal, False), deepen(val, 0)
     stopped = col[:, :1] < 0
     leafv = val[:, :1]
-    for d in range(1, depth + 1):
+    for d in range(1, levels + 1):
         lo, hi = (1 << d) - 1, (1 << (d + 1)) - 1
         above = jnp.repeat(stopped, 2, axis=1)
         leafv = jnp.where(above, jnp.repeat(leafv, 2, axis=1), val[:, lo:hi])
@@ -459,11 +528,23 @@ def _perfect_tree(col, thr, nal, val, n_cols, depth):
         return jnp.concatenate(
             [jnp.full((T, 1), fill, a.dtype), a[:, :inner]], axis=1)
 
+    col1, thr1, nal1 = from_one(col, -1), from_one(thr, 0), \
+        from_one(nal, False)
+    if levels >= _PATH_LEVELS:
+        col1, thr1, nal1 = _split_top(col1), _split_top(thr1), \
+            _split_top(nal1)
+    U = -(-T // G)
+
+    def steps(a, fill):
+        a = jnp.concatenate(
+            [a, jnp.full((U * G - T,) + a.shape[1:], fill, a.dtype)])
+        return a.reshape((U, G * a.shape[1]) + a.shape[2:])
+
+    col1, thr1, nal1, leafv = steps(col1, -1), steps(thr1, 0), \
+        steps(nal1, False), steps(leafv, 0)
     cols = jnp.arange(n_cols, dtype=col.dtype)[None, :, None]
-    sel = (from_one(col, -1)[:, None, :] == cols).astype(jnp.bfloat16)
-    # [low byte | high byte] of a 16-bit half -> low + 256 * high
-    sel2 = jnp.concatenate([sel, 256 * sel], axis=1)
-    return sel2, from_one(thr, 0), from_one(nal, False), leafv
+    sel = (col1[:, None, :] == cols).astype(jnp.bfloat16)
+    return sel, thr1, nal1, leafv, steps(tw[:, None], 0)
 
 
 # the two bodies are jitted for the tests that set one against the other;
@@ -471,14 +552,28 @@ def _perfect_tree(col, thr, nal, val, n_cols, depth):
 @functools.partial(jax.jit, static_argnames=("depth",))
 def _walk_dense(X, col, thr, nal, val, tw, *, depth):
     """Σ_t tw[t] · value[t, leaf_t(row)] with no per-row index: bit for bit
-    what `_walk_gather` returns for numeric-only trees."""
-    n, C = X.shape
-    L = 1 << depth
-    top = min(depth, _PATH_LEVELS)
-    Wt = 1 << top
-    sel2, thr1, nal1, leafv = _perfect_tree(col, thr, nal, val, C, depth)
-    paths = jnp.asarray(_path_matrix(top), jnp.bfloat16)
-    t = max(1, min(n, _WALK_TILE_CELLS // max(L, 128)))
+    what `_walk_gather` returns for numeric-only trees. On the TPU it runs
+    as ONE fused kernel a row tile (ops/walk_pallas.py); `_walk_dense_xla`
+    is its twin everywhere else."""
+    tables = _perfect_tree(col, thr, nal, val, tw, X.shape[1], depth)
+    body = _wp.walk_dense_tile if _wp.use_pallas() else _walk_dense_xla
+    return body(X, *tables, _block_paths(depth),
+                levels=_block_regime(depth)[0])
+
+
+def _walk_dense_xla(X, sel, thr1, nal1, leafv, tws, paths, *, levels):
+    """The dense body in plain XLA: row tiles in a `fori_loop` (the last
+    tile overlaps the one before), the steps of `_perfect_tree` in a
+    `scan`."""
+    n = X.shape[0]
+    L = 1 << levels
+    top = min(levels, _PATH_LEVELS)
+    S, G = thr1.shape[1], tws.shape[1]
+    B = _wp.BLOCK
+    # [low byte | high byte] of a 16-bit half -> low + 256 * high
+    sel2 = jnp.concatenate([sel, 256 * sel], axis=1)
+    paths = jnp.asarray(paths, jnp.bfloat16)
+    t = max(1, min(n, _WALK_TILE_CELLS // S))
 
     def tile(i, out):
         s = jnp.minimum(i * t, n - t)   # the last tile overlaps the one before
@@ -492,8 +587,8 @@ def _walk_dense(X, col, thr, nal, val, tw, *, depth):
         halves = (jnp.concatenate(b[:2], axis=1),
                   jnp.concatenate(b[2:], axis=1))
 
-        def per_tree(acc, tree):
-            s2, th, na, lv, w = tree
+        def step(acc, tables):
+            s2, th, na, lv, ws = tables
             with jax.named_scope("walk.level"):
                 lo, hi = (jnp.dot(h, s2, preferred_element_type=jnp.float32)
                           .astype(jnp.int32) for h in halves)
@@ -501,28 +596,38 @@ def _walk_dense(X, col, thr, nal, val, tw, *, depth):
                                                  jnp.float32)
                 right = jnp.where(jnp.isnan(x), ~na[None, :],
                                   x > th[None, :])
-                # levels 0..top-1 at once: the row's ±1 decisions match the
-                # path to exactly one position of level `top` in all of them
-                turn = jnp.where(right[:, :Wt], 1.0, -1.0) \
+                # the path-matched levels at once, a block at a time: the
+                # row's ±1 decisions match the path to exactly one
+                # position of level `top` of each tree in all of them
+                turn = jnp.where(right[:, :B * len(paths)], 1.0, -1.0) \
                     .astype(jnp.bfloat16)
-                at = jnp.dot(turn, paths,
+                at = jnp.concatenate(
+                    [jnp.dot(turn[:, B * k:B * (k + 1)], P,
                              preferred_element_type=jnp.float32) == top
-                if top < depth:
+                     for k, P in enumerate(paths)], axis=1)
+                if top < levels:
+                    Wt = 1 << top
                     pos = jnp.sum(jnp.where(at, jnp.arange(Wt)[None, :], 0),
                                   axis=1)
-                    for d in range(top, depth):
+                    for d in range(top, levels):
                         W = 1 << d
                         here = jnp.arange(W)[None, :] == pos[:, None]
                         pos = 2 * pos + jnp.any(right[:, W:2 * W] & here,
                                                 axis=1)
                     at = jnp.arange(L)[None, :] == pos[:, None]
             with jax.named_scope("walk.leaf"):
-                v = jnp.sum(jnp.where(at, lv[None, :], 0.0), axis=1)
-                return acc + w * v, None
+                v = jnp.sum(jnp.where(at, lv[None, :], 0.0)
+                            .reshape(t, G, -1), axis=2)
+                for g in range(G):      # tree by tree, in tree order
+                    acc = acc + ws[g] * v[:, g]
+                return acc, None
 
+        # the sum starts from a zero the compiler cannot fold away: with one
+        # step unrolled, 0 + w0 v0 + w1 v1 would leave it two products to
+        # choose from when it contracts the add into a multiply-add (CPU)
+        zero = jax.lax.optimization_barrier(jnp.zeros(t, jnp.float32))
         with jax.named_scope("walk.tree"):
-            acc, _ = jax.lax.scan(per_tree, jnp.zeros(t, jnp.float32),
-                                  (sel2, thr1, nal1, leafv, tw))
+            acc, _ = jax.lax.scan(step, zero, (sel2, thr1, nal1, leafv, tws))
         return jax.lax.dynamic_update_slice_in_dim(out, acc, s, axis=0)
 
     return jax.lax.fori_loop(0, -(-n // t), tile, jnp.zeros(n, jnp.float32))
@@ -629,7 +734,8 @@ def predict_ensemble(X, trees: TreeArrays, weights=None):
         catbits = jnp.zeros((1, 1, 1), jnp.uint32)
         iscat = jnp.zeros(1, bool)
     path = _walk_path(trees.depth, X.shape[1], has_cat)
-    WALKS.inc(path=path)
+    WALKS.inc(path=path,
+              block=_block_label(trees.depth) if path == "dense" else "")
     return _ensemble_walk(X, col, thr, nal, val, tw, catbits, iscat,
                           depth=trees.depth, has_cat=has_cat,
                           mesh=_rows_mesh(X) if path == "dense" else None)

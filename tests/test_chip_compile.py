@@ -16,8 +16,9 @@ library at import, in a skipif condition or in a parametrize argument;
 and every such compile lives in THIS one file.
 
 Also here: the scoring walk's dense body (engine._ensemble_walk) at the
-benchmark's frame, 2,750,000 x 28, for its two ensembles — XLA, no kernel
-— on one chip and row-sharded over the host's four.
+benchmark's frame, 2,750,000 x 28, for its two ensembles — the fused kernel
+`walk_dense_tile` (ops/walk_pallas.py) — on one chip and row-sharded over
+the host's four.
 """
 
 import functools
@@ -33,6 +34,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from h2o3_tpu.models.tree import binned as BN
 from h2o3_tpu.models.tree import engine as E
 from h2o3_tpu.ops import hist_pallas as HP
+from h2o3_tpu.ops import walk_pallas as WP
 from h2o3_tpu.ops import parity
 from h2o3_tpu.parallel import mesh as MESH
 
@@ -229,15 +231,22 @@ def _walk_args(sds_of, rows, ntrees, depth):
 
 
 @pytest.mark.parametrize("ntrees,depth,chips", [(10, 8, 1), (20, 5, 1),
-                                                (10, 8, 4)])
+                                                (10, 8, 4), (50, 5, 1),
+                                                (3, 9, 1)])
 def test_dense_walk_compiles_for_v5e_at_frame_size(ntrees, depth, chips,
                                                    topo, sds,
-                                                   no_persistent_cache):
-    """gbm_higgs (10 x depth 8) and gbm_higgs_defaults (20 x depth 5): the
-    shape picks the dense body, the program holds no gather, keeps its
-    name, and a tile's intermediates stay far under a frame-sized one
-    (2,750,000 x 256 f32 is 2.8 GB)."""
+                                                   no_persistent_cache,
+                                                   monkeypatch):
+    """gbm_higgs (10 x depth 8: two blocks a tree), gbm_higgs_defaults (20 x
+    depth 5: four trees a block), the defaults' published 50 trees (12.5
+    groups) and a tree with a level under the path-matched eight: the shape
+    picks the dense body, on the TPU the fused kernel
+    (`walk_dense_tile`, which Mosaic must accept at these shapes); the
+    program holds no gather, keeps its name, and nothing of (rows x slots)
+    size is left outside the kernel (2,750,000 x 256 f32 is 2.8 GB)."""
     assert E._walk_path(depth, C_REAL, False) == "dense"
+    # the dispatcher asks the default backend, which is the CPU here
+    monkeypatch.setattr(WP, "use_pallas", lambda: True)
     mesh = None
     if chips == 1:
         args = _walk_args(lambda shape, dt, _: sds(shape, dt), SCORE_ROWS,
@@ -249,10 +258,15 @@ def test_dense_walk_compiles_for_v5e_at_frame_size(ntrees, depth, chips,
             lambda shape, dt, spec: jax.ShapeDtypeStruct(
                 shape, dt, sharding=NamedSharding(mesh, spec)),
             MESH.Cloud(mesh).padded_rows(SCORE_ROWS), ntrees, depth)
+    before = HP.kernel_traces()
     compiled = E._ensemble_walk.__wrapped__.lower(
         *args, depth=depth, has_cat=False, mesh=mesh).compile()
+    picked = {k for k, v in HP.kernel_traces().items()
+              if v > before.get(k, 0)}
+    assert picked == {("walk_dense_tile", 1 << depth)}
     text = compiled.as_text()
     assert text.startswith("HloModule jit__ensemble_walk")
+    assert "tpu_custom_call" in text
     assert not re.search(r" gather\(", text)
     for op in ("all-gather", "all-reduce", "all-to-all",
                "collective-permute"):

@@ -51,9 +51,13 @@ def test_ingest_phase(cloud8, tmp_path):
 
 
 def test_walk_phase():
-    rec = cs.phase_walk_exact(20_000, 3, SEED)
-    assert (rec["cols"], rec["depth"]) == (28, 8)
-    assert rec["nodes_reached"] > 127 and rec["nonfinite_cells"] > 0
+    rec = cs.phase_walk_exact(20_000, ((3, 8), (5, 5)), SEED, on_chip=False)
+    assert rec["cols"] == 28 and rec["nonfinite_cells"] > 0
+    assert [(r["depth"], r["block"]) for r in rec["shapes"]] == \
+        [(8, "0.5x256"), (5, "4x32")]
+    assert rec["shapes"][0]["nodes_reached"] > 127
+    assert rec["shapes"][1]["nodes_reached"] > 15
+    assert rec["pallas_kernels_traced"] == []   # the XLA twin ran here
 
 
 def test_train_phase(trained):
