@@ -10,6 +10,9 @@ depth 8, bernoulli; only the tree count is cut):
     kernels -> Pallas kernels == their XLA twins at HIGGS width, on chip
     walk    -> the dense scoring walk == the gather walk, leaf for leaf,
                at both cells' shapes (10 x depth 8, 20 x depth 5)
+    walk_sets -> the same with categorical SET splits, at the airline
+               cell's shape (8 columns, 6 categorical, 2 past a code
+               byte; 20 x depth 5)
     ingest  -> seeded CSV through h2o3_tpu.import_file (native tokenizer)
     train   -> 11M x 28 Frame -> H2OGradientBoostingEstimator.train
     predict -> large-frame sharded path AND compiled-scorer fast path,
@@ -59,6 +62,9 @@ SLICE_ROWS = 1_000_000       # <= scorer_cache._max_rows(): the fast path
 CHECK_ROWS = 10_000          # rows compared with the host scorer, per path
 WALK_ROWS = 1_000_000        # rows walked by both bodies of the scoring walk
 WALK_SHAPES = ((10, 8), (20, 5))   # (ntrees, depth) of the benchmark's two cells
+# the airline cell's shape: levels a column (0: numeric), 20 trees x depth 5
+WALK_SET_ROWS = 400_000
+WALK_SET_LEVELS = (12, 31, 7, 0, 29, 340, 340, 0)
 PARITY_ROWS = 1_100_003      # device-built frame == host-built: over the fast
                              # path's 2^20 rows, not a multiple of the padding
 ONE_ROW_MS_PR28 = (7.41, 7.66)   # PERF.md's 1-row medians, to read "1" against
@@ -238,6 +244,77 @@ def phase_walk_exact(rows: int, shapes, seed: int,
     return {"rows": rows, "cols": COLS,
             "nonfinite_cells": int((~np.isfinite(X)).sum()),
             "pallas_kernels_traced": picked, "shapes": recs}
+
+
+def phase_walk_sets(rows: int, levels, shape, seed: int) -> dict:
+    """The dense body with categorical SET splits against the gather body
+    on this device, at the airline cell's shape: `levels` a column (0:
+    numeric; two columns past a code byte), `shape` = (ntrees, depth).
+    Random trees that mix numeric and SET splits, level ids drawn over
+    every level, with NaN, ids past the column's levels, negative and
+    fractional values among them. Tree by tree the value walked to is the
+    NODE's own number, so `==` is leaf for leaf; then random values and
+    weights, the ensemble's sum bit for bit. The set match is a bfloat16
+    product of {0, 1} summed in f32: only the chip can show that its MXU
+    keeps it exact."""
+    import jax.numpy as jnp
+    from h2o3_tpu.models.tree import engine as E
+    rng = np.random.default_rng(seed + 5)
+    levels = np.asarray(levels)
+    C, (ntrees, depth) = levels.size, shape
+    X = rng.standard_normal((rows, C)).astype(np.float32) * 700 + 1200
+    for c in np.flatnonzero(levels):
+        X[:, c] = rng.integers(0, levels[c], size=rows)
+        odd = rng.random(rows) < 0.02
+        X[odd, c] = rng.choice([-3.0, -0.5, 0.5, levels[c] - 0.25,
+                                levels[c], levels[c] + 77.0, 1e9],
+                               size=int(odd.sum()))
+    for v, p in ((np.nan, 0.01), (np.inf, 0.001), (-np.inf, 0.001)):
+        X[rng.random(X.shape) < p] = v
+    nodes, inner = 2 ** (depth + 1) - 1, 2 ** depth - 1
+    W = -(-int(levels.max()) // 128) * 4
+    col = rng.integers(0, C, size=(ntrees, nodes)).astype(np.int32)
+    col[:, inner:] = -1
+    col[:, 1:inner][rng.random((ntrees, inner - 1)) < 0.1] = -1
+    thr = X[rng.integers(0, rows, size=col.shape), np.maximum(col, 0)]
+    nal = rng.random(col.shape) < 0.5
+    bits = rng.integers(0, 2 ** 32, size=col.shape + (W,),
+                        dtype=np.uint64).astype(np.uint32)
+    ta = E.TreeArrays(col=col, thr=thr, na_left=nal, value=thr, depth=depth,
+                      catbits=bits, col_is_cat=levels > 0, cat_levels=levels)
+    cats = E._cat_layout(ta, C)
+    K = sum(k for _, k in cats)
+    assert E._walk_path(depth, C, K) == "dense", (depth, C, K)
+    hold = np.zeros(C, np.int32)
+    hold[[c for c, _ in cats]] = [k for _, k in cats]
+    Xd, tbl = jnp.asarray(X), [jnp.asarray(a) for a in (col, thr, nal)]
+    sets = (jnp.asarray(bits), jnp.asarray(levels > 0), jnp.asarray(hold))
+    ids = np.broadcast_to(np.arange(nodes, dtype=np.float32), col.shape)
+
+    def both(val, tw):
+        args = (Xd, *tbl, jnp.asarray(val), jnp.asarray(tw))
+        return (np.asarray(E._walk_dense(*args, sets[0], depth=depth,
+                                         cats=cats)),
+                np.asarray(E._walk_gather(*args, *sets, depth=depth,
+                                          has_cat=True)))
+
+    reached = set()
+    for t in range(ntrees):
+        dense, gather = both(ids, np.eye(ntrees, dtype=np.float32)[t])
+        assert np.array_equal(dense, gather), \
+            (depth, t, int((dense != gather).sum()))
+        reached.update(np.unique(gather).astype(int).tolist())
+    assert len(reached) > inner // 2, len(reached)
+    dense, gather = both(rng.standard_normal(col.shape).astype(np.float32),
+                         (rng.random(ntrees) + 0.5).astype(np.float32))
+    assert np.array_equal(dense, gather), int((dense != gather).sum())
+    is_set = (col >= 0) & (levels > 0)[np.maximum(col, 0)]
+    return {"rows": rows, "cols": C, "ntrees": ntrees, "depth": depth,
+            "level_rows": K, "set_words": W, "set_nodes": int(is_set.sum()),
+            "split_nodes": int((col >= 0).sum()),
+            "rows_past_a_byte": int((X[:, levels > 255] >= 256).sum()),
+            "nodes_reached": len(reached),
+            "nonfinite_cells": int((~np.isfinite(X)).sum())}
 
 
 def write_csv(path: str, X, y):
@@ -672,6 +749,9 @@ def main(argv=None) -> int:
         _emit("kernels", t0, phase_kernels(args.seed))
         t0 = time.perf_counter()
         _emit("walk", t0, phase_walk_exact(WALK_ROWS, WALK_SHAPES, args.seed))
+        t0 = time.perf_counter()
+        _emit("walk_sets", t0, phase_walk_sets(
+            WALK_SET_ROWS, WALK_SET_LEVELS, WALK_SHAPES[1], args.seed))
         t0 = time.perf_counter()
         _emit("ingest", t0, phase_ingest(INGEST_ROWS, args.seed, OUT_DIR))
         t0 = time.perf_counter()

@@ -745,6 +745,10 @@ def _check_r023(proj, idx: _Idx) -> list:
 # ---------------------------------------------------------------------------
 # R025: export contract for scoring programs
 _R025_ROOT_NAMES = {"_score_with_params", "_score_matrix"}
+# the observability layer runs on the host WHILE a scoring body is traced
+# (a span round the tables' placement, a counter): its arguments are names
+# and numbers, never tracers, and nothing of it enters the program
+_R025_HOST_ONLY = ("h2o3_tpu/obs/",)
 _FORBIDDEN_CALLBACKS = ("pure_callback", "io_callback")
 _STATIC_ATTRS = {"shape", "ndim", "dtype", "size"}
 
@@ -919,6 +923,9 @@ def _check_r025(proj) -> list:
         if fi is None:
             continue
         for callee, _ln, _h, _b, _s in fi.calls:
+            cf = proj.fns.get(callee)
+            if cf is not None and cf.mod.mod.rel.startswith(_R025_HOST_ONLY):
+                continue    # a span: host bookkeeping while the body traces
             if callee not in reach:
                 work.append(callee)
     for q in sorted(reach):

@@ -66,10 +66,12 @@ def _predict_root(fn):
     @functools.wraps(fn)
     def predict(self, test_data, *args, **kwargs):
         rows = int(getattr(test_data, "nrows", 0) or 0)
+        # h2o3-ok: R011 a family's own attrs are a fixed set it notes at publish (tree models: cat_levels, set_nodes)
         with _span("predict", model=self.key, algo=self.algo,
                    frame=getattr(test_data, "key", None), rows=rows,
                    cols=int(getattr(test_data, "ncols", 0) or 0),
-                   path="frame") as sp:
+                   path="frame",
+                   **(getattr(self, "_predict_attrs", None) or {})) as sp:
             out = fn(self, test_data, *args, **kwargs)
         _PREDICT_CALLS.inc(algo=self.algo, path=sp.attrs["path"])
         _PREDICT_ROWS.inc(rows, algo=self.algo, path=sp.attrs["path"])
@@ -524,6 +526,7 @@ class ModelBase:
         from h2o3_tpu.obs import modelmon as _modelmon
         from h2o3_tpu import serving
         with _span("model.publish", model=self.key):
+            self._note_published()
             _modelmon.install_baseline(self, frame)
             DKV.put(self.key, self)
             # optional serving pre-warm on publish (H2O3_SCORER_PREWARM=1):
@@ -532,6 +535,11 @@ class ModelBase:
             if serving.prewarm_enabled():
                 serving.prewarm(self)
         return self
+
+    def _note_published(self):
+        """What a family counts about a model as it is published (tree
+        models: their SET-split nodes); `_predict_attrs` = attrs its
+        `predict` root span then carries."""
 
     def _resolve_predictors(self, frame, x, y):
         if x is None:

@@ -44,6 +44,38 @@ R = HP.BLOCK_ROWS
 # ===========================================================================
 # Quantization (GlobalQuantilesCalc analog)
 @dataclass
+class Planes:
+    """A frame whose columns do not all fit a code byte, laid out as BYTE
+    PLANES for the kernels: a column with up to 255 codes (its NA code
+    among them) is one plane; a wider one — a categorical column past a
+    code byte, every level its own bin — takes several, plane k holding
+    the codes [255 k, 255 k + 255) as bytes 0..254 and byte 255 for a row
+    whose code lies in another plane. The kernels see `cp_pad` byte columns
+    of 256 bins; the split search sees the columns themselves, `n_search`
+    bins wide with the NA bin at `BinSpec.b_val`, through the index maps
+    below (all host constants)."""
+    nb: np.ndarray         # (C,) value bins of each column; its NA code
+    levels: np.ndarray     # (C,) levels of a categorical column, 0: numeric
+    src: np.ndarray        # (cp_pad,) the column a plane belongs to, -1: none
+    off: np.ndarray        # (cp_pad,) first code of the plane
+    multi: np.ndarray      # (cp_pad,) plane of a column that takes several
+    first: np.ndarray      # (c_pad,) first plane of each column
+    per: int               # most planes a column takes
+    cp_pad: int            # planes, padded to the kernels' column tile
+    n_search: int          # bins of the split search, padded (mult 128)
+    hist_src: np.ndarray   # (c_pad, n_search) -> slot of the planes' hist
+    route_dst: np.ndarray  # (c_pad, per * 256) -> bin of a node's route row
+    level_bin: np.ndarray  # (c_pad, set bits) -> bin of a level's route
+
+    @property
+    def grouped(self) -> dict:
+        """{column: (levels, bins)} of the categorical columns whose levels
+        share bins (more levels than nbins_cats)."""
+        return {int(c): (int(self.levels[c]), int(self.nb[c]))
+                for c in np.flatnonzero(self.levels > self.nb)}
+
+
+@dataclass
 class BinSpec:
     """Per-column binning of a training frame."""
     edges: np.ndarray        # (C, B_val-1) f32 — ascending cut points
@@ -51,38 +83,101 @@ class BinSpec:
     b_val: int               # number of value bins; NA code == b_val
     n_bins: int              # padded bin count used by the kernel (mult 128)
     c_pad: int               # padded column count (mult COL_TILE)
+    planes: Planes | None = None   # None: every column fits a code byte
 
     @property
     def na_code(self):
         return self.b_val
 
 
-def make_bins(X, is_cat, nbins: int, sample: int = 1 << 18) -> BinSpec:
+PLANE = 255     # codes of one byte plane of a column that takes several
+
+
+def _plane_layout(nb, levels, c_pad) -> Planes:
+    """The byte planes and index maps of columns with `nb` value bins."""
+    C = nb.size
+    b_val = int(nb.max())
+    n_search = -(-(b_val + 1) // 128) * 128
+    # planes a column takes: one while its codes (NA among them) fit a byte
+    ks = [1 if k + 1 <= 256 else -(-(int(k) + 1) // PLANE) for k in nb]
+    src, off, multi, first = [], [], [], np.zeros(c_pad, np.int32)
+    for c, k in enumerate(ks):
+        first[c] = len(src)
+        src += [c] * k
+        off += [PLANE * j for j in range(k)]
+        multi += [k > 1] * k
+    per = max(ks)
+    cp_pad = -(-len(src) // HP.COL_TILE) * HP.COL_TILE
+    pad = cp_pad - len(src)
+    src = np.asarray(src + [-1] * pad, np.int32)
+    off = np.asarray(off + [0] * pad, np.int32)
+    multi = np.asarray(multi + [False] * pad, bool)
+    # a column's code as (plane, byte), vectorised over every code 0..nb
+    zero_h, zero_r = cp_pad * 256, n_search
+    hist_src = np.full((c_pad, n_search), zero_h, np.int32)
+    route_dst = np.full((c_pad, per * 256), zero_r, np.int32)
+    bits = -(-int(max(levels.max(), 1)) // 32) * 32
+    level_bin = np.full((c_pad, bits), zero_r, np.int32)
+    for c in range(C):
+        codes = np.arange(int(nb[c]) + 1)            # the last is NA
+        plane = codes // PLANE if ks[c] > 1 else 0 * codes
+        byte = codes - PLANE * plane
+        where = np.where(codes < nb[c], codes, b_val)   # NA bin of the search
+        hist_src[c, where] = (first[c] + plane) * 256 + byte
+        route_dst[c, plane * 256 + byte] = where
+        if levels[c] > 0:
+            lv = np.minimum(np.arange(bits), levels[c] - 1)
+            level_bin[c] = lv * int(nb[c]) // int(levels[c])
+    return Planes(nb=nb, levels=levels, src=src, off=off, multi=multi,
+                  first=first, per=per, cp_pad=cp_pad, n_search=n_search,
+                  hist_src=hist_src, route_dst=route_dst, level_bin=level_bin)
+
+
+def make_bins(X, is_cat, nbins: int, sample: int = 1 << 18,
+              cat_levels=None, nbins_cats: int = 1024) -> BinSpec:
     """Global quantile edges from a row sample. X: (n, C) f32 with NaN NAs.
-    Categorical columns are identity-binned (code == level id, capped)."""
+    Categorical columns are identity-binned (code == level id): every
+    level its own bin. With `cat_levels` (levels of each column, 0 for a
+    numeric one) a categorical column past a code byte keeps every level
+    apart too, up to `nbins_cats` bins (`Planes`); past `nbins_cats` its
+    levels share bins in runs of consecutive ids, as H2O-3's DHistogram
+    steps them — `BinSpec.planes.grouped` says which. Without it, or
+    when every column fits, a column's codes are capped at `nbins`."""
     n, C = X.shape
     b_val = int(min(nbins, 255))
     stride = max(1, n // sample)
     Xs = np.asarray(X[::stride][:sample], np.float32)
-    edges = np.zeros((C, b_val - 1), np.float32)
-    qs = np.linspace(0.0, 1.0, b_val + 1)[1:-1]
+    is_cat = np.asarray(is_cat, bool)
+    levels = np.zeros(C, np.int64) if cat_levels is None else \
+        np.where(is_cat, np.asarray(cat_levels, np.int64), 0)
+    wide = bool((levels > min(b_val, int(nbins_cats))).any())
+    nb = np.where(levels > 0, np.minimum(levels, int(nbins_cats)), b_val) \
+        if wide else np.full(C, b_val, np.int64)
+    edges = np.full((C, int(nb.max()) - 1), np.inf, np.float32)
     for c in range(C):
+        k = int(nb[c])
         if is_cat[c]:
-            # identity binning: edge k at k+0.5 so code(level k)=k
-            edges[c] = np.arange(1, b_val, dtype=np.float32) - 0.5
+            # identity binning: edge j at j+0.5 so code(level j)=j; levels
+            # that share a bin: the edge under the first level of each bin
+            lv = levels[c] if wide else k
+            edges[c, :k - 1] = -(-np.arange(1, k) * lv // k) - 0.5
             continue
+        qs = np.linspace(0.0, 1.0, k + 1)[1:-1]
         col = Xs[:, c]
         col = col[~np.isnan(col)]
         if col.size == 0:
-            edges[c] = np.arange(1, b_val, dtype=np.float32)
+            edges[c, :k - 1] = np.arange(1, k, dtype=np.float32)
             continue
         e = np.quantile(col, qs).astype(np.float32)
         # strictly non-decreasing is fine: duplicate edges => empty bins
-        edges[c] = e
-    nb = max(128, -(-(b_val + 1) // 128) * 128)
+        edges[c, :k - 1] = e
     cp = -(-C // HP.COL_TILE) * HP.COL_TILE
-    return BinSpec(edges=edges, is_cat=np.asarray(is_cat, bool),
-                   b_val=b_val, n_bins=nb, c_pad=cp)
+    if wide:
+        return BinSpec(edges=edges, is_cat=is_cat, b_val=int(nb.max()),
+                       n_bins=256, c_pad=cp,
+                       planes=_plane_layout(nb, levels, cp))
+    return BinSpec(edges=edges, is_cat=is_cat, b_val=b_val, c_pad=cp,
+                   n_bins=max(128, -(-(b_val + 1) // 128) * 128))
 
 
 def row_granule() -> int:
@@ -127,13 +222,44 @@ def _quantize(X, edges, *, b_val, c_pad, n_pad, sharding=None):
     return out
 
 
+@functools.partial(jax.jit, static_argnames=("n_pad", "sharding"))
+def _quantize_planes(X, edges, nb, src, off, multi, *, n_pad, sharding=None):
+    """`_quantize` for columns that do not all fit a code byte (`Planes`):
+    a column's code is 0..nb-1, NA -> nb, and the plane of column src[j]
+    that starts at code off[j] holds it as a byte, 255 in a plane it does
+    not fall in."""
+    def one_col(x, e, k):
+        code = jnp.searchsorted(e, x, side="left").astype(jnp.int32)
+        return jnp.where(jnp.isnan(x), k, jnp.minimum(code, k - 1))
+
+    with jax.named_scope("bin.search"):
+        codes = jax.vmap(one_col, in_axes=(1, 0, 0), out_axes=0)(X, edges, nb)
+    with jax.named_scope("bin.planes"):
+        rel = codes[jnp.maximum(src, 0)] - off[:, None]        # (cp_pad, n)
+        byte = jnp.where(multi[:, None] & ((rel < 0) | (rel >= PLANE)),
+                         255, rel)
+        byte = jnp.where(src[:, None] < 0, 0, byte).astype(jnp.uint8)
+    out = jnp.zeros((src.shape[0], n_pad), jnp.uint8)
+    out = lax.dynamic_update_slice(out, byte, (0, 0))
+    if sharding is not None:
+        out = lax.with_sharding_constraint(out, sharding)
+    return out
+
+
 def quantize(X, spec: BinSpec, n_pad: int | None = None, sharding=None):
     """(n, C) f32 -> (C_pad, n_pad) uint8 code plane (the XLA-fallback /
-    canonical layout; `prepare_codes` derives the TPU kernel layout).
-    `sharding`: the plane's row sharding on a multi-device cloud."""
+    canonical layout; `prepare_codes` derives the TPU kernel layout); with
+    `spec.planes`, its byte planes. `sharding`: the plane's row sharding
+    on a multi-device cloud."""
     n = X.shape[0]
     if n_pad is None:
         n_pad = padded_rows(n)
+    pl = spec.planes
+    if pl is not None:
+        return _quantize_planes(
+            X, jnp.asarray(spec.edges), jnp.asarray(pl.nb, jnp.int32),
+            jnp.asarray(pl.src), jnp.asarray(pl.off), jnp.asarray(pl.multi),
+            n_pad=n_pad, sharding=sharding)
     return _quantize(X, jnp.asarray(spec.edges), b_val=spec.b_val,
                      c_pad=spec.c_pad, n_pad=n_pad, sharding=sharding)
 
@@ -165,13 +291,15 @@ def _se_gain(wl, gl, wr, gr_, wp, gp, lam):
     jax.jit,
     static_argnames=("b_val", "use_hess", "any_cat"))
 def find_splits_binned(hist, is_cat, mono, cmask, lo, hi, *, b_val,
-                       min_rows, msi, lam, use_hess, any_cat=True):
+                       min_rows, msi, lam, use_hess, any_cat=True, nb=None):
     """Vectorized bestCol over every (leaf, col, threshold/subset, NA-dir).
 
     hist: (L, C_pad, 4, BP) — stats rows 0=w 1=wg 2=wh (3 spare)
     is_cat: (C_pad,) bool; mono: (C_pad,) int32 in {-1,0,1}
     cmask: (L, C_pad) bool column availability (mtries / padding)
     lo, hi: (L,) f32 monotone value bounds for each leaf
+    nb: (C_pad,) value bins of each column where they differ (`Planes`):
+        a column's bins nb..b_val-1 are empty, and no cut lies among them
 
     Returns dict of per-leaf arrays: did, col, bin, nal, route (L, BP) bool,
     val_l, val_r (clamped), gain, plus per-leaf totals (w_t, val_t).
@@ -199,13 +327,16 @@ def find_splits_binned(hist, is_cat, mono, cmask, lo, hi, *, b_val,
 
     # ---- categorical: sort bins by mean gradient (optimal-subset order) --
     # (statically skipped when the frame has no categorical columns)
+    # (`tree.level.split.set` names the SET search's ops in a device trace)
     if any_cat:
-        ratio = jnp.where(v_den > 1e-30, v_wg / jnp.maximum(v_den, 1e-30),
-                          jnp.inf)                          # empty bins last
-        order = jnp.argsort(ratio, axis=-1)                 # (L, C, B)
-        sc_w = jnp.take_along_axis(v_w, order, -1)
-        sc_wg = jnp.take_along_axis(v_wg, order, -1)
-        sc_den = jnp.take_along_axis(v_den, order, -1)
+        with jax.named_scope("tree.level.split.set"):
+            ratio = jnp.where(v_den > 1e-30,
+                              v_wg / jnp.maximum(v_den, 1e-30),
+                              jnp.inf)                      # empty bins last
+            order = jnp.argsort(ratio, axis=-1)             # (L, C, B)
+            sc_w = jnp.take_along_axis(v_w, order, -1)
+            sc_wg = jnp.take_along_axis(v_wg, order, -1)
+            sc_den = jnp.take_along_axis(v_den, order, -1)
 
     def eval_axis(aw, awg, aden):
         """Prefix-split gains along the (possibly re-ordered) bin axis.
@@ -236,13 +367,17 @@ def find_splits_binned(hist, is_cat, mono, cmask, lo, hi, *, b_val,
 
     gn_num, nal_num = eval_axis(v_w, v_wg, v_den)           # natural order
     if any_cat:
-        gn_cat, nal_cat = eval_axis(sc_w, sc_wg, sc_den)    # sorted order
+        with jax.named_scope("tree.level.split.set"):
+            gn_cat, nal_cat = eval_axis(sc_w, sc_wg, sc_den)  # sorted order
         catC = is_cat[None, :, None]
         gain_all = jnp.where(catC, gn_cat, gn_num)          # (L, C, B-1)
         nal_all = jnp.where(catC, nal_cat, nal_num)
     else:
         gain_all, nal_all = gn_num, nal_num
     gain_all = jnp.where(cmask[:, :, None], gain_all, -jnp.inf)
+    if nb is not None:
+        cuts = jnp.arange(B - 1)[None, :] < nb[:, None] - 1
+        gain_all = jnp.where(cuts[None], gain_all, -jnp.inf)
 
     flat = gain_all.reshape(L, C * (B - 1))
     best = jnp.argmax(flat, axis=1)
@@ -259,10 +394,11 @@ def find_splits_binned(hist, is_cat, mono, cmask, lo, hi, *, b_val,
     bin_ids = jnp.arange(BP)[None, :]                       # (1, BP)
     num_right = bin_ids > bbin[:, None]                     # natural order
     if any_cat:
-        rank_of_bin = jnp.argsort(takeL(order), axis=-1)    # (L, B)
-        rank_pad = jnp.pad(rank_of_bin, ((0, 0), (0, BP - B)),
-                           constant_values=BP)
-        cat_right = rank_pad > bbin[:, None]
+        with jax.named_scope("tree.level.split.set"):
+            rank_of_bin = jnp.argsort(takeL(order), axis=-1)    # (L, B)
+            rank_pad = jnp.pad(rank_of_bin, ((0, 0), (0, BP - B)),
+                               constant_values=BP)
+            cat_right = rank_pad > bbin[:, None]
         leaf_cat = is_cat[bcol]
         route = jnp.where(leaf_cat[:, None], cat_right, num_right)
     else:
@@ -326,12 +462,42 @@ class BinnedGrower:
         self.mono = jnp.asarray(mono)
         self.is_cat_dev = jnp.asarray(
             np.pad(spec.is_cat, (0, spec.c_pad - spec.is_cat.size)))
+        # columns that do not all fit a code byte: the planes' index maps
+        pl = spec.planes
+        if pl is not None:
+            self.nb_dev = jnp.asarray(np.pad(
+                pl.nb, (0, spec.c_pad - pl.nb.size), constant_values=1))
+            self.first_dev = jnp.asarray(pl.first, jnp.float32)
+            self.hist_src, self.route_dst, self.level_bin = (
+                jnp.asarray(a) for a in (pl.hist_src, pl.route_dst,
+                                         pl.level_bin))
 
     # ---- static layout ---------------------------------------------------
     def layout(self, n: int, shards: int = 1):
         """Slots for n data rows + 1 dummy, padded to the kernel block
         (per-shard when the rows axis is sharded over `shards` devices)."""
         return padded_rows(n, shards)
+
+    def _columns(self, hist):
+        """The planes' histogram (L, cp_pad, 4, 256) as the columns' own,
+        (L, c_pad, 4, n_search) with the NA bin at b_val: a gather by a
+        host constant."""
+        L, CP, S, nb = hist.shape
+        flat = hist.transpose(0, 2, 1, 3).reshape(L, S, CP * nb)
+        flat = jnp.concatenate([flat, jnp.zeros((L, S, 1), flat.dtype)], -1)
+        return flat[:, :, self.hist_src].transpose(0, 2, 1, 3)
+
+    def sets(self, out):
+        """A grown tree's go-right sets as bitsets (`pack_route`): over the
+        bins of a code byte, or, with planes, over each node's column's
+        LEVELS (a level past the column's last goes its way)."""
+        spec = self.spec
+        if spec.planes is None:
+            return pack_route(out["route"], spec.n_bins, spec.b_val)
+        route = jnp.pad(out["route"], ((0, 0), (0, 1)))
+        lv = jnp.take_along_axis(
+            route, self.level_bin[jnp.maximum(out["col"], 0)], axis=1)
+        return pack_route(lv, lv.shape[1])
 
     def grow(self, codes, stats, F, *, eta, clip_val, key, mtries: int = 0,
              tree_mask=None):
@@ -353,13 +519,18 @@ class BinnedGrower:
         C = spec.c_pad
         n_pad = codes.shape[1]
         BP = spec.n_bins
+        # with planes the kernels see CH byte columns of BP bins, P of them
+        # a column at most, and the search the C columns, BS bins wide
+        pl = spec.planes
+        CH, BS, P = (C, BP, 1) if pl is None else \
+            (pl.cp_pad, pl.n_search, pl.per)
         big = jnp.float32(3e38)
         nodes_p = -(-(self.nodes + 1) // 128) * 128
         heap = jnp.zeros(n_pad, jnp.int32)
         colA = jnp.full(self.nodes, -1, jnp.int32)
         binA = jnp.full(self.nodes, -1, jnp.int32)
         nalA = jnp.zeros(self.nodes, bool)
-        routeA = jnp.zeros((self.nodes, BP), bool)
+        routeA = jnp.zeros((self.nodes, BS), bool)
         valA = jnp.zeros(self.nodes, jnp.float32)
         coverA = jnp.zeros(self.nodes, jnp.float32)
         gains = jnp.zeros(C + 1, jnp.float32)
@@ -381,7 +552,7 @@ class BinnedGrower:
             if d == 0:
                 with jax.named_scope("tree.level.hist"):
                     hist = HP.sbh_hist(codes, heap, stats, base=base, L=L,
-                                       n_bins=BP)[:L, :C]
+                                       n_bins=BP)[:L, :CH]
                     if self.axis_name:
                         # the ScoreBuildHistogram reduce: merge shard-local
                         # histograms in one collective per level
@@ -399,9 +570,10 @@ class BinnedGrower:
                     heap, left = HP.sbh_route_hist(
                         codes, heap, prev["tbl"], prev["route_f"], stats,
                         base_r=(L >> 1) - 1, L_r=L >> 1, base_h=base, L_h=L,
-                        n_bins=BP, any_cat=any_cat, na_code=spec.b_val)
+                        n_bins=BP, any_cat=any_cat, na_code=spec.b_val,
+                        planes=P)
                 with jax.named_scope("tree.level.hist"):
-                    left = left[: L >> 1, :C]
+                    left = left[: L >> 1, :CH]
                     if self.axis_name:
                         # psum BEFORE subtraction: hist_prev is already
                         # global
@@ -430,9 +602,11 @@ class BinnedGrower:
                     cmask = cmask & tree_mask[None, :]
 
                 s = find_splits_binned(
-                    hist, self.is_cat_dev, self.mono, cmask, lo, hi,
+                    hist if pl is None else self._columns(hist),
+                    self.is_cat_dev, self.mono, cmask, lo, hi,
                     b_val=spec.b_val, min_rows=self.min_rows, msi=self.msi,
-                    lam=self.lam, use_hess=self.use_hess, any_cat=any_cat)
+                    lam=self.lam, use_hess=self.use_hess, any_cat=any_cat,
+                    nb=None if pl is None else self.nb_dev)
 
             with jax.named_scope("tree.level.nodes"):
                 did = s["did"]
@@ -456,12 +630,21 @@ class BinnedGrower:
                 # ---- routing tables for the next level -------------------
                 Lp = max(8, L)
                 tbl = jnp.zeros((8, Lp), jnp.float32)
-                tbl = tbl.at[0, :L].set(s["col"].astype(jnp.float32))
+                if pl is None:
+                    kcol, kroute = s["col"].astype(jnp.float32), s["route"]
+                else:
+                    # the kernels route by the split column's first plane
+                    # and a route row laid out plane by plane
+                    kcol = self.first_dev[s["col"]]
+                    kroute = jnp.take_along_axis(
+                        jnp.pad(s["route"], ((0, 0), (0, 1))),
+                        self.route_dst[s["col"]], axis=1)
+                tbl = tbl.at[0, :L].set(kcol)
                 tbl = tbl.at[1, :L].set(did.astype(jnp.float32))
                 tbl = tbl.at[2, :L].set(s["bin"].astype(jnp.float32))
                 tbl = tbl.at[3, :L].set(s["nal"].astype(jnp.float32))
-                route_f = jnp.zeros((Lp, BP), jnp.float32)
-                route_f = route_f.at[:L].set(s["route"].astype(jnp.float32))
+                route_f = jnp.zeros((Lp, P * BP), jnp.float32)
+                route_f = route_f.at[:L].set(kroute.astype(jnp.float32))
                 prev = dict(tbl=tbl, route_f=route_f)
 
                 # ---- monotone bounds for children ------------------------
@@ -486,7 +669,7 @@ class BinnedGrower:
                                    prev["route_f"], valtab, F,
                                    base=(L >> 1) - 1, L=L >> 1, eta=eta,
                                    emit_f=True, any_cat=any_cat,
-                                   na_code=spec.b_val)
+                                   na_code=spec.b_val, planes=P)
         return dict(col=colA, bin=binA, nal=nalA, route=routeA, val=valt,
                     cover=coverA, gains=gains[:C], F=F, heap=heap)
 
@@ -630,8 +813,7 @@ def gbm_chunk_trainer(grower: BinnedGrower, n: int, *, dist: str, eta: float,
                 F = out["F"]
                 with jax.named_scope("tree.pack"):
                     tree = (out["col"], out["bin"], out["nal"],
-                            pack_route(out["route"], grower.spec.n_bins,
-                                       grower.spec.b_val),
+                            grower.sets(out),
                             out["val"], out["gains"], out["cover"])
                 return (F, key), tree
 
@@ -697,8 +879,7 @@ def gbm_multi_chunk_trainer(grower: BinnedGrower, n: int, *, n_classes: int,
                                       key=jax.random.fold_in(kt, k),
                                       mtries=mtries, tree_mask=tmask)
                     tree = (out["col"], out["bin"], out["nal"],
-                            pack_route(out["route"], grower.spec.n_bins,
-                                       grower.spec.b_val),
+                            grower.sets(out),
                             out["val"], out["gains"], out["cover"])
                     return None, (tree, out["F"])  # F==val[heap]: row pred
 
@@ -755,8 +936,7 @@ def drf_chunk_trainer(grower: BinnedGrower, n: int, *, sample_rate: float,
                 oob_sum = oob_sum + jnp.where(oob, pred, 0.0)
                 oob_cnt = oob_cnt + oob.astype(jnp.float32)
                 tree = (out["col"], out["bin"], out["nal"],
-                        pack_route(out["route"], grower.spec.n_bins,
-                                   grower.spec.b_val),
+                        grower.sets(out),
                         out["val"], out["gains"], out["cover"])
                 return (oob_sum, oob_cnt, key), tree
 

@@ -55,7 +55,7 @@ WALKS = _om.counter(
     "h2o3_tree_walk_total",
     "predict_ensemble calls by the scoring walk's body: path=dense (every "
     "node of a level, no per-row index; block = {trees a 128-slot node "
-    "block}x{slots a tree}) or gather (categorical or deep trees)")
+    "block}x{slots a tree}) or gather (deep trees, or too many level rows)")
 
 # Dense-matmul histogram path is used while (leaves × 3 stats) stays MXU-sized.
 # Measured on v5e: the one-hot matmul beats segment-sum scatter ~3× even at
@@ -340,10 +340,21 @@ class TreeArrays:
     # go-right bitset over level ids, plus which columns are categorical
     catbits: object = None      # (T, nodes, W) uint32 or None
     col_is_cat: object = None   # (C,) bool or None
+    # levels of each column (0: numeric, or not known): host metadata like
+    # col_is_cat. A categorical column's code is clipped to its levels, and
+    # the dense walk matches that many bits of a node's set, not all 32 W
+    cat_levels: object = None   # (C,) int or None
 
     @property
     def ntrees(self):
         return self.col.shape[0]
+
+    def __getstate__(self):
+        # the walk's placed tables (`_walk_tables`) are derived state, made
+        # on demand; never pickled
+        state = dict(self.__dict__)
+        state.pop("_tables", None)
+        return state
 
 
 def stack_trees(tree_list, depth) -> TreeArrays:
@@ -364,23 +375,27 @@ def stack_trees(tree_list, depth) -> TreeArrays:
 # whole ensembles as SHARED DEVICE ARGUMENTS into pjit'd scorer programs
 # (one HBM copy per model, every row-bucket program reuses it) instead
 # of baking them in as closure constants. Children are the per-node
-# arrays; `depth` is static trace structure, and `col_is_cat` stays HOST
-# data (aux) because predict_ensemble resolves the has-categoricals
-# branch with `np.any` at trace time.
+# arrays; `depth` is static trace structure, and `col_is_cat` and
+# `cat_levels` stay HOST data (aux) because predict_ensemble lays out the
+# categorical columns' level rows (`_cat_layout`) at trace time.
 def _trees_flatten(t: TreeArrays):
     aux = (t.depth,
            None if t.col_is_cat is None
-           else tuple(bool(b) for b in np.asarray(t.col_is_cat)))
+           else tuple(bool(b) for b in np.asarray(t.col_is_cat)),
+           None if t.cat_levels is None
+           else tuple(int(k) for k in np.asarray(t.cat_levels)))
     return (t.col, t.thr, t.na_left, t.value, t.cover, t.catbits), aux
 
 
 def _trees_unflatten(aux, children):
-    depth, cat = aux
+    depth, cat, levels = aux
     col, thr, nal, val, cover, catbits = children
     return TreeArrays(col=col, thr=thr, na_left=nal, value=val,
                       depth=depth, cover=cover, catbits=catbits,
                       col_is_cat=None if cat is None
-                      else np.asarray(cat, bool))
+                      else np.asarray(cat, bool),
+                      cat_levels=None if levels is None
+                      else np.asarray(levels, np.int64))
 
 
 jax.tree_util.register_pytree_node(TreeArrays, _trees_flatten,
@@ -409,7 +424,16 @@ jax.tree_util.register_pytree_node(TreeArrays, _trees_flatten,
 # (2^depth - 1) x columns, the size of a tree's selection matrix: depth 14
 # at 28 columns, the deepest shape measured, is the last to take the dense
 # body.
+#
+# A categorical SET split is decided densely too: a row tile's level
+# one-hots (one segment a categorical column, K rows in all) times the
+# blocks' bit matrices, on the MXU, gives every slot's bit (a row has one 1
+# in a column's segment and a slot is non-zero in one segment only: exact),
+# and the decision is where(slot splits a set, bit, x > thr) under the same
+# NaN rule. The K level rows count as columns in the bound.
 _DENSE_MAX_CELLS = 1 << 19
+# rows x (steps x slots) of the set match of one row tile, kept as booleans
+_SET_TILE_CELLS = 1 << 26
 # levels 0.._PATH_LEVELS-1 are matched by path products, (128, 128) each; a
 # level walked by position instead costs a compare, a select and a
 # reduction over (rows, 2^level)
@@ -424,11 +448,30 @@ _MIN_LEVELS = 3
 _WALK_TILE_CELLS = 1 << 23
 
 
-def _walk_path(depth: int, n_cols: int, has_cat: bool) -> str:
-    """Which body scores this shape: "dense" or "gather"."""
-    dense = not has_cat and depth >= 1 \
-        and ((1 << depth) - 1) * n_cols <= _DENSE_MAX_CELLS
+def _walk_path(depth: int, n_cols: int, cat_rows: int = 0) -> str:
+    """Which body scores this shape: "dense" or "gather". `cat_rows`: the
+    level rows of the categorical columns (`_cat_layout`), 0 for a numeric
+    ensemble; they count as columns."""
+    dense = depth >= 1 and \
+        ((1 << depth) - 1) * (n_cols + cat_rows) <= _DENSE_MAX_CELLS
     return "dense" if dense else "gather"
+
+
+def _cat_layout(trees, n_cols: int) -> tuple:
+    """((column, level rows), ...) of the ensemble's categorical columns:
+    a column's known levels, at most the 32 W bits a node's set holds; all
+    32 W where the levels are not known (a MOJO). () for a numeric
+    ensemble. Host metadata: static in every program."""
+    # h2o3-ok: R025 col_is_cat / cat_levels are host numpy model metadata (the pytree's aux, never a tracer); catbits is asked its static shape only
+    if trees.catbits is None or trees.col_is_cat is None:
+        return ()
+    nb = 32 * trees.catbits.shape[-1]
+    flags = np.asarray(trees.col_is_cat, bool)[:n_cols]
+    known = np.zeros(n_cols, np.int64) if trees.cat_levels is None else \
+        np.asarray(trees.cat_levels, np.int64)[:n_cols]
+    rows = np.where((known > 0) & (known < nb), known, nb)
+    # h2o3-ok: R025 host metadata, as above
+    return tuple((int(c), int(rows[c])) for c in np.flatnonzero(flags))
 
 
 def _block_regime(depth: int):
@@ -493,6 +536,27 @@ def _split_top(a):
         halves[0] + halves[1] + [a[..., 1 << _PATH_LEVELS:]], axis=-1)
 
 
+def _deepen(a, width, fill):
+    """Axis 1 filled up to `width` slots."""
+    return jnp.concatenate(
+        [a, jnp.full((a.shape[0], width - a.shape[1]), fill, a.dtype)],
+        axis=1)
+
+
+def _from_one(a, inner, fill):
+    """The first `inner` heap slots numbered from 1 (slot 0 unused)."""
+    return jnp.concatenate(
+        [jnp.full((a.shape[0], 1), fill, a.dtype), a[:, :inner]], axis=1)
+
+
+def _steps(a, U, G, fill):
+    """(T, slots, ...) as U steps of G trees side by side, the last step
+    filled up with `fill`."""
+    a = jnp.concatenate(
+        [a, jnp.full((U * G - a.shape[0],) + a.shape[1:], fill, a.dtype)])
+    return a.reshape((U, G * a.shape[1]) + a.shape[2:])
+
+
 def _perfect_tree(col, thr, nal, val, tw, n_cols, depth):
     """The (T, nodes) heap arrays as the dense body's steps (`_block_regime`):
     perfect trees of `levels` levels with nodes numbered from 1 (level d is
@@ -509,13 +573,10 @@ def _perfect_tree(col, thr, nal, val, tw, n_cols, depth):
     T = col.shape[0]
     real, inner, L = (1 << depth) - 1, (1 << levels) - 1, 1 << levels
 
-    def deepen(a, fill):
-        return jnp.concatenate(
-            [a, jnp.full((T, 2 * L - 1 - a.shape[1]), fill, a.dtype)], axis=1)
-
     # the bottom level is all leaves, and so is every level added under it
-    col = deepen(col[:, :real], -1)
-    thr, nal, val = deepen(thr, 0), deepen(nal, False), deepen(val, 0)
+    col = _deepen(col[:, :real], 2 * L - 1, -1)
+    thr, nal, val = _deepen(thr, 2 * L - 1, 0), \
+        _deepen(nal, 2 * L - 1, False), _deepen(val, 2 * L - 1, 0)
     stopped = col[:, :1] < 0
     leafv = val[:, :1]
     for d in range(1, levels + 1):
@@ -524,47 +585,83 @@ def _perfect_tree(col, thr, nal, val, tw, n_cols, depth):
         leafv = jnp.where(above, jnp.repeat(leafv, 2, axis=1), val[:, lo:hi])
         stopped = above | (col[:, lo:hi] < 0)
 
-    def from_one(a, fill):
-        return jnp.concatenate(
-            [jnp.full((T, 1), fill, a.dtype), a[:, :inner]], axis=1)
-
-    col1, thr1, nal1 = from_one(col, -1), from_one(thr, 0), \
-        from_one(nal, False)
+    col1, thr1, nal1 = _from_one(col, inner, -1), _from_one(thr, inner, 0), \
+        _from_one(nal, inner, False)
     if levels >= _PATH_LEVELS:
         col1, thr1, nal1 = _split_top(col1), _split_top(thr1), \
             _split_top(nal1)
     U = -(-T // G)
-
-    def steps(a, fill):
-        a = jnp.concatenate(
-            [a, jnp.full((U * G - T,) + a.shape[1:], fill, a.dtype)])
-        return a.reshape((U, G * a.shape[1]) + a.shape[2:])
-
-    col1, thr1, nal1, leafv = steps(col1, -1), steps(thr1, 0), \
-        steps(nal1, False), steps(leafv, 0)
+    col1, thr1, nal1, leafv = _steps(col1, U, G, -1), _steps(thr1, U, G, 0), \
+        _steps(nal1, U, G, False), _steps(leafv, U, G, 0)
     cols = jnp.arange(n_cols, dtype=col.dtype)[None, :, None]
     sel = (col1[:, None, :] == cols).astype(jnp.bfloat16)
-    return sel, thr1, nal1, leafv, steps(tw[:, None], 0)
+    return sel, thr1, nal1, leafv, _steps(tw[:, None], U, G, 0)
+
+
+def _perfect_sets(col, catbits, cats, depth):
+    """The nodes' go-right sets in `_perfect_tree`'s layout, by the same
+    moves that place `col`: B (K', U * S) bf16 in {0, 1} — row off_c + l of
+    slot s of step u is bit l of the node in that slot when it splits
+    categorical column c, else 0 (`cats` = `_cat_layout`: the segments in
+    order, K' = their sum filled up to a multiple of 128) — and which slots
+    split a set, (U, S) bool."""
+    levels, G, _ = _block_regime(depth)
+    T, nodes, W = catbits.shape
+    real, inner, L = (1 << depth) - 1, (1 << levels) - 1, 1 << levels
+    col1 = _from_one(_deepen(col[:, :real], 2 * L - 1, -1), inner, -1)
+    # bit l of a node's set is bit l % 32 of its word l // 32; the level
+    # axis goes in front of the slots, which then move as col's do
+    bits = ((catbits[:, :real, :, None] >> jnp.arange(32, dtype=jnp.uint32))
+            & 1).astype(jnp.int8).reshape(T, real, 32 * W)
+    bits = bits.transpose(0, 2, 1).reshape(T * 32 * W, real)
+    bits = _from_one(_deepen(bits, 2 * L - 1, 0), inner, 0)
+    if levels >= _PATH_LEVELS:
+        col1, bits = _split_top(col1), _split_top(bits)
+    U = -(-T // G)
+    col1 = _steps(col1, U, G, -1)                            # (U, S)
+    bits = _steps(bits.reshape(T, 32 * W, -1).transpose(0, 2, 1), U, G, 0) \
+        .transpose(0, 2, 1)                                  # (U, 32 W, S)
+    segs = [jnp.where((col1 == c)[:, None, :], bits[:, :k, :], 0)
+            for c, k in cats]
+    K = sum(k for _, k in cats)
+    B = jnp.concatenate(segs, axis=1).astype(jnp.bfloat16)
+    B = jnp.pad(B, ((0, 0), (0, -K % 128), (0, 0)))
+    is_set = functools.reduce(jnp.logical_or, [col1 == c for c, _ in cats])
+    return B.transpose(1, 0, 2).reshape(B.shape[1], -1), is_set
 
 
 # the two bodies are jitted for the tests that set one against the other;
 # inside `_ensemble_walk` a nested jit inlines
-@functools.partial(jax.jit, static_argnames=("depth",))
-def _walk_dense(X, col, thr, nal, val, tw, *, depth):
+@functools.partial(jax.jit, static_argnames=("depth", "cats"))
+def _walk_dense(X, col, thr, nal, val, tw, catbits=None, *, depth, cats=()):
     """Σ_t tw[t] · value[t, leaf_t(row)] with no per-row index: bit for bit
-    what `_walk_gather` returns for numeric-only trees. On the TPU it runs
-    as ONE fused kernel a row tile (ops/walk_pallas.py); `_walk_dense_xla`
-    is its twin everywhere else."""
+    what `_walk_gather` returns. A numeric ensemble runs on the TPU as ONE
+    fused kernel a row tile (ops/walk_pallas.py), `_walk_dense_xla` its twin
+    everywhere else; with categorical SET splits (`cats`, `_cat_layout`)
+    the XLA body on every backend, the set match beside the feature
+    select."""
     tables = _perfect_tree(col, thr, nal, val, tw, X.shape[1], depth)
+    levels = _block_regime(depth)[0]
+    if cats:
+        return _walk_dense_xla(
+            X, *tables, _block_paths(depth), levels=levels, cats=cats,
+            sets=_perfect_sets(col, catbits, cats, depth))
     body = _wp.walk_dense_tile if _wp.use_pallas() else _walk_dense_xla
-    return body(X, *tables, _block_paths(depth),
-                levels=_block_regime(depth)[0])
+    return body(X, *tables, _block_paths(depth), levels=levels)
 
 
-def _walk_dense_xla(X, sel, thr1, nal1, leafv, tws, paths, *, levels):
+def _cat_code(x, rows):
+    """A categorical feature's level id as the walk reads it: truncated,
+    NaN as 0, held to the column's `rows` levels."""
+    return jnp.clip(jnp.nan_to_num(x).astype(jnp.int32), 0, rows - 1)
+
+
+def _walk_dense_xla(X, sel, thr1, nal1, leafv, tws, paths, *, levels,
+                    cats=(), sets=None):
     """The dense body in plain XLA: row tiles in a `fori_loop` (the last
     tile overlaps the one before), the steps of `_perfect_tree` in a
-    `scan`."""
+    `scan`. `cats`, `sets` (`_perfect_sets`): the ensemble's categorical
+    columns and its nodes' go-right sets."""
     n = X.shape[0]
     L = 1 << levels
     top = min(levels, _PATH_LEVELS)
@@ -574,11 +671,21 @@ def _walk_dense_xla(X, sel, thr1, nal1, leafv, tws, paths, *, levels):
     sel2 = jnp.concatenate([sel, 256 * sel], axis=1)
     paths = jnp.asarray(paths, jnp.bfloat16)
     t = max(1, min(n, _WALK_TILE_CELLS // S))
+    tables = (sel2, thr1, nal1, leafv, tws)
+    if cats:
+        setB, is_set = sets
+        t = max(1, min(t, _SET_TILE_CELLS // setB.shape[1]))
+        tables += (is_set, jnp.arange(thr1.shape[0]))
+        # the level rows' own tables: where each categorical column's
+        # segment ends, and a row's level within its segment
+        ends = np.cumsum([k for _, k in cats])
+        level = np.full(setB.shape[0], -1, np.int32)
+        level[:ends[-1]] = np.concatenate([np.arange(k) for _, k in cats])
 
     def tile(i, out):
         s = jnp.minimum(i * t, n - t)   # the last tile overlaps the one before
-        bits = jax.lax.bitcast_convert_type(
-            jax.lax.dynamic_slice_in_dim(X, s, t, axis=0), jnp.int32)
+        Xt = jax.lax.dynamic_slice_in_dim(X, s, t, axis=0)
+        bits = jax.lax.bitcast_convert_type(Xt, jnp.int32)
         # the four bytes of every feature: whole numbers under 256 are
         # exact in bfloat16, and a one-hot column picks ONE of them, so the
         # f32 accumulator holds low + 256 * high of a 16-bit half exactly
@@ -586,16 +693,38 @@ def _walk_dense_xla(X, sel, thr1, nal1, leafv, tws, paths, *, levels):
              for k in (0, 8, 16, 24)]
         halves = (jnp.concatenate(b[:2], axis=1),
                   jnp.concatenate(b[2:], axis=1))
+        if cats:
+            # every slot's bit for the tile's rows, all steps at once: the
+            # rows' level one-hots (a segment a categorical column) times
+            # the sets. {0, 1} in bfloat16 with an f32 sum of one term: exact
+            # A level row's one-hot is (its column's code == its level):
+            # the code is chosen a segment at a time, elementwise, so no
+            # segment is built apart and joined and the one-hot is the
+            # product's own operand, never an array in HBM.
+            with jax.named_scope("walk.set"):
+                rows = jnp.arange(setB.shape[0])[None, :]
+                code = _cat_code(Xt[:, cats[-1][0]], cats[-1][1])[:, None]
+                for (c, k), end in zip(cats[-2::-1], ends[-2::-1]):
+                    code = jnp.where(rows < end,
+                                     _cat_code(Xt[:, c], k)[:, None], code)
+                hot = (code == level[None, :]).astype(jnp.bfloat16)
+                in_set = jnp.dot(hot, setB,
+                                 preferred_element_type=jnp.float32) > 0.5
 
         def step(acc, tables):
-            s2, th, na, lv, ws = tables
+            s2, th, na, lv, ws, *of_sets = tables
             with jax.named_scope("walk.level"):
                 lo, hi = (jnp.dot(h, s2, preferred_element_type=jnp.float32)
                           .astype(jnp.int32) for h in halves)
                 x = jax.lax.bitcast_convert_type((hi << 16) | lo,
                                                  jnp.float32)
-                right = jnp.where(jnp.isnan(x), ~na[None, :],
-                                  x > th[None, :])
+                over = x > th[None, :]
+                if cats:
+                    splits_set, u = of_sets
+                    over = jnp.where(
+                        splits_set[None, :], jax.lax.dynamic_slice_in_dim(
+                            in_set, u * S, S, axis=1), over)
+                right = jnp.where(jnp.isnan(x), ~na[None, :], over)
                 # the path-matched levels at once, a block at a time: the
                 # row's ±1 decisions match the path to exactly one
                 # position of level `top` of each tree in all of them
@@ -627,17 +756,19 @@ def _walk_dense_xla(X, sel, thr1, nal1, leafv, tws, paths, *, levels):
         # choose from when it contracts the add into a multiply-add (CPU)
         zero = jax.lax.optimization_barrier(jnp.zeros(t, jnp.float32))
         with jax.named_scope("walk.tree"):
-            acc, _ = jax.lax.scan(step, zero, (sel2, thr1, nal1, leafv, tws))
+            acc, _ = jax.lax.scan(step, zero, tables)
         return jax.lax.dynamic_update_slice_in_dim(out, acc, s, axis=0)
 
     return jax.lax.fori_loop(0, -(-n // t), tile, jnp.zeros(n, jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("depth", "has_cat"))
-def _walk_gather(X, col, thr, nal, val, tw, catbits, iscat, *, depth,
-                 has_cat):
+def _walk_gather(X, col, thr, nal, val, tw, catbits, iscat, cat_rows=None,
+                 *, depth, has_cat):
     """Σ_t tw[t] · value[t, leaf_t(row)] by a fixed-depth chain of gathers
-    per tree: categorical SET splits and deep trees."""
+    per tree: deep trees, and the dense body's oracle. `cat_rows` (C,): the
+    levels a categorical column's code is held to (`_cat_layout`); None:
+    the 32 W bits of a set."""
     n = X.shape[0]
     if has_cat:
         nb = catbits.shape[-1] * 32
@@ -656,8 +787,8 @@ def _walk_gather(X, col, thr, nal, val, tw, catbits, iscat, *, depth,
                 isna = jnp.isnan(x)
                 right = x > thr[t][node]
                 if has_cat:
-                    code = jnp.clip(jnp.nan_to_num(x).astype(jnp.int32),
-                                    0, nb - 1)
+                    code = _cat_code(x, nb if cat_rows is None
+                                     else jnp.maximum(cat_rows[cc], 1))
                     word = catbits[t][node, code // 32]
                     bit = (word >> (code % 32).astype(jnp.uint32)) & 1
                     right = jnp.where(iscat[cc], bit == 1, right)
@@ -689,55 +820,90 @@ def _rows_mesh(X):
 
 
 @_compat.guard_collective
-@functools.partial(jax.jit, static_argnames=("depth", "has_cat", "mesh"))
+@functools.partial(jax.jit,
+                   static_argnames=("depth", "has_cat", "mesh", "cats"))
 def _ensemble_walk(X, col, thr, nal, val, tw, catbits, iscat, *, depth,
-                   has_cat, mesh=None):
+                   has_cat, mesh=None, cats=()):
     """Module-level jitted scoring walk: cached per (shapes, depth, has_cat)
     signature. Defining this as a closure inside predict_ensemble gave the
     jit a fresh function identity per call — every single ensemble predict
     retraced AND recompiled, which dominated serving latency. The shape
     picks the body (`_walk_path`); the XLA module is `jit__ensemble_walk`
-    either way. `mesh`: where X's rows are sharded (`_rows_mesh`)."""
-    if _walk_path(depth, X.shape[1], has_cat) == "gather":
+    either way. `mesh`: where X's rows are sharded (`_rows_mesh`); `cats`:
+    the categorical columns' level rows (`_cat_layout`)."""
+    if _walk_path(depth, X.shape[1], sum(k for _, k in cats)) == "gather":
+        rows = None
+        if cats:
+            rows = np.zeros(iscat.shape[0], np.int32)
+            rows[[c for c, _ in cats]] = [k for _, k in cats]
+            rows = jnp.asarray(rows)
         return _walk_gather(X, col, thr, nal, val, tw, catbits, iscat,
-                            depth=depth, has_cat=has_cat)
-    dense = functools.partial(_walk_dense, depth=depth)
+                            rows, depth=depth, has_cat=has_cat)
+    dense = functools.partial(_walk_dense, depth=depth, cats=cats)
+    tables = (col, thr, nal, val, tw) + ((catbits,) if cats else ())
     if mesh is not None:
         P = jax.sharding.PartitionSpec
         dense = jax.shard_map(dense, mesh=mesh, out_specs=P(_mesh.ROWS),
-                              in_specs=(P(_mesh.ROWS),) + (P(),) * 5,
+                              in_specs=(P(_mesh.ROWS),) + (P(),) * len(tables),
                               check_vma=False)
-    return dense(X, col, thr, nal, val, tw)
+    return dense(X, *tables)
+
+
+_NO_SETS = (np.zeros((1, 1, 1), np.uint32), np.zeros(1, bool))
+
+
+def _walk_tables(trees: TreeArrays, n_cols: int):
+    """(the walk's arguments after X with unit weights, the level layout):
+    the ensemble's tables on the device and `_cat_layout`, made ONCE an
+    ensemble and kept beside it. A host array handed to the jitted walk —
+    `col_is_cat`, the unit weights, the numeric program's two unused
+    arguments, a MOJO's tables — is a transfer of its own in EVERY call,
+    and the walk is not enqueued before the last of them is done: two
+    thread hops a transfer on the host, ahead of every frame's walk. The
+    entry holds the arrays it was made from and is made anew when the
+    ensemble's are others; tables made under a trace keep none."""
+    src = (trees.col, trees.thr, trees.na_left, trees.value, trees.catbits,
+           trees.col_is_cat, trees.cat_levels)
+    hit = trees.__dict__.get("_tables")
+    same = hit is not None and hit[1] == n_cols \
+        and all(a is b for a, b in zip(hit[0], src))
+    if same:  # h2o3-ok: R025 object identity of the ensemble's arrays, never a value: a tracer is only ever itself
+        return hit[2]
+    cats = _cat_layout(trees, n_cols)  # h2o3-ok: R025 col_is_cat / cat_levels are host numpy model metadata excluded from the serving params pytree — static per artifact (covers the if below)
+    if cats:
+        sets = (trees.catbits, np.asarray(trees.col_is_cat))
+    else:
+        # fixed dummy shapes so the no-cat program signature is stable
+        sets = _NO_SETS
+    got = tuple(jnp.asarray(a) for a in (
+        trees.col, trees.thr, trees.na_left, trees.value,
+        np.ones(trees.ntrees, np.float32), *sets)), cats
+    if not any(isinstance(a, jax.core.Tracer) for a in got[0]):  # h2o3-ok: R025 asks what the arrays ARE, not what they hold: made of traced arguments, or inside a trace (there a host constant is staged as a tracer too), the tables belong to that trace and no entry is kept
+        trees.__dict__["_tables"] = (src, n_cols, got)
+    return got
 
 
 def predict_ensemble(X, trees: TreeArrays, weights=None):
-    """Σ_t value[t, leaf_t(row)]. Numeric-only ensembles of moderate depth
-    are scored densely, every node of a tree for a tile of rows
-    (`_walk_dense`); categorical SET splits — a node routes by bitset
-    membership of the level id (hex/genmodel GenModel.bitSetContains
-    analog) — and deep trees take a fixed-depth gather walk per tree
+    """Σ_t value[t, leaf_t(row)]. Ensembles of moderate depth are scored
+    densely, every node of a tree for a tile of rows (`_walk_dense`) —
+    categorical SET splits too: a node routes by bitset membership of the
+    level id (hex/genmodel GenModel.bitSetContains analog), matched on the
+    MXU — and deep trees take a fixed-depth gather walk per tree
     (`_walk_gather`). The two agree bit for bit; `_walk_path` picks from
-    (depth, columns, has-categoricals) and h2o3_tree_walk_total counts it."""
-    col = jnp.asarray(trees.col)
-    thr = jnp.asarray(trees.thr)
-    nal = jnp.asarray(trees.na_left)
-    val = jnp.asarray(trees.value)
-    tw = (jnp.asarray(weights, jnp.float32) if weights is not None
-          else jnp.ones(trees.ntrees, jnp.float32))
-    has_cat = trees.catbits is not None and trees.col_is_cat is not None \
-        and bool(np.any(np.asarray(trees.col_is_cat)))  # h2o3-ok: R025 col_is_cat is host numpy model metadata excluded from the serving params pytree — static per artifact; the export PR hoists has_cat into artifact metadata (covers the if below)
-    if has_cat:
-        catbits = jnp.asarray(trees.catbits)
-        iscat = jnp.asarray(np.asarray(trees.col_is_cat))
-    else:
-        # fixed dummy shapes so the no-cat program signature is stable
-        catbits = jnp.zeros((1, 1, 1), jnp.uint32)
-        iscat = jnp.zeros(1, bool)
-    path = _walk_path(trees.depth, X.shape[1], has_cat)
+    (depth, columns, level rows) and h2o3_tree_walk_total counts it."""
+    # the host's share before the walk is enqueued: the ensemble's tables
+    # onto the device (inside `predict.dispatch` on the large-frame path);
+    # after an ensemble's first call a look-up (`_walk_tables`)
+    with _span("predict.tables"):
+        (col, thr, nal, val, tw, catbits, iscat), cats = \
+            _walk_tables(trees, X.shape[1])
+        if weights is not None:
+            tw = jnp.asarray(weights, jnp.float32)
+    path = _walk_path(trees.depth, X.shape[1], sum(k for _, k in cats))
     WALKS.inc(path=path,
               block=_block_label(trees.depth) if path == "dense" else "")
     return _ensemble_walk(X, col, thr, nal, val, tw, catbits, iscat,
-                          depth=trees.depth, has_cat=has_cat,
+                          depth=trees.depth, has_cat=cats != (), cats=cats,
                           mesh=_rows_mesh(X) if path == "dense" else None)
 
 

@@ -18,6 +18,7 @@ back from the router (val[heap]), so F-updates are gathers, not tree walks.
 
 from __future__ import annotations
 
+import contextlib
 import math
 
 import jax
@@ -27,7 +28,13 @@ import numpy as np
 from h2o3_tpu.core.frame import Frame
 from h2o3_tpu.models.model import ModelBase
 from h2o3_tpu.models.tree import engine as E
+from h2o3_tpu.obs import metrics as _om
 from h2o3_tpu.obs.timeline import span as _span
+
+
+SET_NODES = _om.counter(
+    "h2o3_tree_set_split_nodes_total",
+    "categorical SET-split nodes of the tree models published, by algo")
 
 
 class SharedTreeEstimator(ModelBase):
@@ -66,6 +73,26 @@ class SharedTreeEstimator(ModelBase):
 
     def _cat_mode(self):
         return "label"  # trees bin label-encoded categoricals natively
+
+    def _note_published(self):
+        """Count the published ensembles' categorical SET-split nodes
+        (h2o3_tree_set_split_nodes_total{algo}) and note what the `predict`
+        root span says of the walk: `cat_levels`, the level rows the dense
+        walk matches (engine._cat_layout), and `set_nodes`."""
+        trees = [t for t in [getattr(self, "_trees", None)]
+                 + list(getattr(self, "_trees_k", None) or []) if t is not None]
+        sets = rows = 0
+        for ta in trees:
+            cats = E._cat_layout(ta, len(self._dinfo.predictors))
+            if cats:
+                rows = sum(k for _, k in cats)
+                col = np.asarray(ta.col)
+                flags = np.asarray(ta.col_is_cat, bool)
+                sets += int(((col >= 0) & flags[np.clip(
+                    col, 0, flags.size - 1)]).sum())
+        if sets:
+            SET_NODES.inc(sets, algo=self.algo)
+        self._predict_attrs = {"cat_levels": rows, "set_nodes": sets}
 
     def _validate_early_stopping(self):
         """Fail fast on an unusable stopping_metric (H2O validates at
@@ -147,9 +174,11 @@ class SharedTreeEstimator(ModelBase):
 
     # ---- binned-engine shared setup (GBM + DRF + IF share the histogram
     # machinery, SharedTree.java:507 buildLayer) --------------------------
-    def _binned_setup(self, frame: Frame):
+    def _binned_setup(self, frame: Frame, job=None):
         """Quantize the frame ONCE, form the mesh wiring and the grower.
-        Returns a context dict used by the per-algo binned drivers."""
+        Returns a context dict used by the per-algo binned drivers. `job`:
+        where a categorical column past a code byte books its binning
+        (phase `setup.cats`, inside the caller's `setup`)."""
         from h2o3_tpu.models.tree import binned as BN
         from h2o3_tpu.parallel import mesh as MESH
         p = self.params
@@ -171,8 +200,22 @@ class SharedTreeEstimator(ModelBase):
         stride = max(1, n >> 18)
         from h2o3_tpu.parallel import mrtask as _mr
         Xs = _mr.host_fetch(X[::stride][: 1 << 18])
+        levels = np.array([di.cardinalities[c] if c in di.cat_cols else 0
+                           for c in di.predictors], np.int64)
         with _span("gbm.bin.spec", rows=int(Xs.shape[0]), bins=b_val):
-            spec = BN.make_bins(Xs, is_cat, b_val)
+            # every level of a categorical column its own bin up to
+            # nbins_cats (DHistogram); a column past a code byte takes
+            # several byte planes, and the numeric columns keep b_val
+            spec = BN.make_bins(Xs, is_cat, b_val, cat_levels=levels,
+                                nbins_cats=nbins_cats)
+        grouped = {} if spec.planes is None else {
+            di.predictors[c]: {"levels": k, "bins": b}
+            for c, (k, b) in spec.planes.grouped.items()}
+        if grouped:
+            from h2o3_tpu.utils import log as _log
+            _log.warn(f"{self.algo}: more levels than nbins_cats="
+                      f"{nbins_cats}, consecutive levels share a bin: "
+                      f"{grouped}")
 
         cl = MESH.cloud()
         shards = cl.n_rows_shards
@@ -203,8 +246,19 @@ class SharedTreeEstimator(ModelBase):
         # the DISPATCH of the binning programs (a retrace or an executable
         # load shows here); the device's share ends the caller's `setup`
         with _span("gbm.bin.codes", rows=n, cols=C):
-            codes = BN.prepare_codes(BN.quantize(X, spec, n_pad=n_pad,
-                                                 sharding=codes_sh))
+            if spec.planes is None:
+                codes = BN.prepare_codes(BN.quantize(X, spec, n_pad=n_pad,
+                                                     sharding=codes_sh))
+            else:
+                with (job.phase("setup.cats") if job is not None
+                      else contextlib.nullcontext()), \
+                        _span("gbm.bin.cats", columns=int(is_cat.sum()),
+                              levels=int(max(cards)), bins=spec.b_val,
+                              planes=int(spec.planes.cp_pad)):
+                    codes = BN.prepare_codes(BN.quantize(
+                        X, spec, n_pad=n_pad, sharding=codes_sh))
+                    # h2o3-ok: R002 the span ends when the byte planes exist; the caller's `setup` phase waits on the same array next
+                    jax.block_until_ready(codes)
             y1 = BN.pad_rows(y, n_pad)
             w1 = BN.pad_rows(w, n_pad)
             if multi:
@@ -226,7 +280,7 @@ class SharedTreeEstimator(ModelBase):
                     C=C, is_cat=is_cat, spec=spec, grower=grower,
                     n_pad=n_pad, cl=cl, multi=multi,
                     mesh=cl.mesh if multi else None,
-                    codes_chunk=codes_chunk)
+                    codes_chunk=codes_chunk, levels=levels, grouped=grouped)
 
     def _binned_tree_arrays(self, ctx, chunks, prev=None, lead=None):
         """Assemble E.TreeArrays from trainer chunk outputs (+ an optional
@@ -262,6 +316,8 @@ class SharedTreeEstimator(ModelBase):
             depth=ctx["grower"].D, cover=coverT,
             catbits=wordsT if any_cat else None,
             col_is_cat=(np.pad(ctx["is_cat"],
+                               (0, spec.c_pad - C)) if any_cat else None),
+            cat_levels=(np.pad(ctx["levels"],
                                (0, spec.c_pad - C)) if any_cat else None))
         return ta, gainsT
 
@@ -575,7 +631,7 @@ class H2OGradientBoostingEstimator(SharedTreeEstimator):
         of `_quantize` and read 1.7 % where binning cost 25 %. One sync a
         train(), where the Σw readback that follows synced anyway."""
         with job.phase("setup"):   # quantile spec + codes + device_put
-            ctx = self._binned_setup(frame)
+            ctx = self._binned_setup(frame, job)
             # h2o3-ok: R002 the phase ends when the device has binned, not when the host has enqueued it
             jax.block_until_ready(ctx["codes"])
         return ctx
@@ -691,6 +747,9 @@ class H2OGradientBoostingEstimator(SharedTreeEstimator):
                 "learn_rate": lr, "init_f": f0, "engine": "binned_pallas",
                 "nbins_effective": ctx["spec"].b_val,
             }
+            if ctx["grouped"]:      # never silently: which levels share bins
+                self._output.model_summary["categorical_levels_grouped"] = \
+                    ctx["grouped"]
 
     def _fit_binned_multinomial(self, frame: Frame, job):
         """K class trees per iteration through ONE jitted binned program

@@ -188,9 +188,14 @@ def prepare_codes(codes_u8):
 # — one definition each so the standalone kernels and the fused kernel
 # cannot drift semantically.
 def _route_math(words, heap, tbl, route, *, base, L, n_bins, any_cat,
-                na_code):
+                na_code, planes=1):
     """New heap ids for one row tile. `words` is the loaded packed-plane
-    tile (W_pad, R); `tbl`/`route` the loaded split tables."""
+    tile (W_pad, R); `tbl`/`route` the loaded split tables. `planes` > 1:
+    a split column's code lies in one of up to `planes` byte columns from
+    tbl's column on (models/tree/binned.py `Planes`), `route` holds a
+    (n_bins)-wide row for each of them, and the row's bit is the one that
+    is set in any: a byte of 255 in a plane the code is not in, and every
+    slot of a plane the leaf's column does not have, read 0."""
     R = heap.shape[0]
     leaf = heap - base
     active = (leaf >= 0) & (leaf < L)
@@ -214,23 +219,33 @@ def _route_math(words, heap, tbl, route, *, base, L, n_bins, any_cat,
     # (exact i32 sum — a one-hot f32 dot would round packed words > 2^24),
     # then a per-lane variable shift extracts the byte
     col_i = props[:, 0].astype(jnp.int32)
-    wi = col_i >> 2
-    shift = (col_i & 3) * 8
     w_pad = words.shape[0]
     iota_w = lax.broadcasted_iota(jnp.int32, (w_pad, R), 0)
-    wsel = jnp.sum(jnp.where(iota_w == wi[None, :], words, 0), axis=0)
-    code_i = (wsel >> shift) & 255                            # (R,) i32
-    code_sel = code_i.astype(jnp.float32)
+
+    def code_of(col_i):
+        wi = col_i >> 2
+        shift = (col_i & 3) * 8
+        wsel = jnp.sum(jnp.where(iota_w == wi[None, :], words, 0), axis=0)
+        return ((wsel >> shift) & 255).astype(jnp.float32)    # (R,)
+
+    code_sel = code_of(col_i)
     if any_cat:
         # goes-right bit via the full route table: route[leaf, code]
-        rowroute = lax.dot_general(
-            ohl_f.astype(jnp.bfloat16), route.astype(jnp.bfloat16),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # (R, BP)
-        iota_b = lax.broadcasted_iota(jnp.int32, (R, n_bins), 1) \
-            .astype(jnp.float32)
-        bsel = (iota_b == code_sel[:, None]).astype(jnp.float32)
-        go = jnp.sum(rowroute * bsel, axis=1) > 0.5           # (R,)
+        def bit_of(code_sel, route):
+            rowroute = lax.dot_general(
+                ohl_f.astype(jnp.bfloat16), route.astype(jnp.bfloat16),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)           # (R, BP)
+            iota_b = lax.broadcasted_iota(jnp.int32, (R, n_bins), 1) \
+                .astype(jnp.float32)
+            bsel = (iota_b == code_sel[:, None]).astype(jnp.float32)
+            return jnp.sum(rowroute * bsel, axis=1)           # (R,)
+
+        bit = bit_of(code_sel, route[:, :n_bins] if planes > 1 else route)
+        for k in range(1, planes):
+            bit = bit + bit_of(code_of(col_i + k),
+                               route[:, k * n_bins:(k + 1) * n_bins])
+        go = bit > 0.5
     else:
         # numeric-only fast path: threshold compare + NA direction from the
         # props table (rows 2 = split bin, 3 = na-goes-left). All-f32
@@ -295,23 +310,26 @@ def _dense_parts(words, A, *, n_bins):
 # ===========================================================================
 # Phase 1: route rows by the previous level's splits
 def _route_kernel(codesP_ref, heap_ref, tbl_ref, route_ref,
-                  heap_out_ref, *, base, L, n_bins, any_cat, na_code):
+                  heap_out_ref, *, base, L, n_bins, any_cat, na_code,
+                  planes=1):
     """Non-terminal route: heap update only — F is NOT streamed through
     the kernel (it is untouched between terminal passes)."""
     heap_out_ref[0, :] = _route_math(
         codesP_ref[...], heap_ref[0, :], tbl_ref[...], route_ref[...],
-        base=base, L=L, n_bins=n_bins, any_cat=any_cat, na_code=na_code)
+        base=base, L=L, n_bins=n_bins, any_cat=any_cat, na_code=na_code,
+        planes=planes)
 
 
 def _route_kernel_f(codesP_ref, heap_ref, tbl_ref, route_ref, valtab_ref,
                     f_ref, heap_out_ref, f_out_ref, *, base, L, n_bins,
-                    eta, any_cat, na_code):
+                    eta, any_cat, na_code, planes=1):
     """Terminal route: heap update + fused margin update F += eta*val[heap]
     (ComputePredAndRes's gather folded into the same stream)."""
     R = f_ref.shape[1]
     newheap = _route_math(
         codesP_ref[...], heap_ref[0, :], tbl_ref[...], route_ref[...],
-        base=base, L=L, n_bins=n_bins, any_cat=any_cat, na_code=na_code)
+        base=base, L=L, n_bins=n_bins, any_cat=any_cat, na_code=na_code,
+        planes=planes)
     heap_out_ref[0, :] = newheap
     nodes_p = valtab_ref.shape[1]
     iota_n = lax.broadcasted_iota(jnp.int32, (R, nodes_p), 1)
@@ -331,18 +349,18 @@ def _route_kernel_f(codesP_ref, heap_ref, tbl_ref, route_ref, valtab_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("base", "L", "eta", "emit_f",
-                                    "any_cat", "na_code"))
+                                    "any_cat", "na_code", "planes"))
 def sbh_route_pallas(codesP, heap, tbl, route_f, valtab=None, F=None, *,
                      base, L, eta=0.0, emit_f=False, any_cat=True,
-                     na_code=255):
+                     na_code=255, planes=1):
     """codesP (W_pad, n_pad) i32 packed plane; heap (n_pad,) i32;
     tbl (8, Lp) f32 (row 0 = split col, 1 = did, 2 = split bin,
-    3 = na-goes-left); route_f (Lp, n_bins) f32 (1.0 = code goes right);
-    valtab (8, NODES_P) f32 / F (n_pad,) f32 only with emit_f.
+    3 = na-goes-left); route_f (Lp, planes * n_bins) f32 (1.0 = code goes
+    right); valtab (8, NODES_P) f32 / F (n_pad,) f32 only with emit_f.
     Returns (newheap, newF) — newF is None when emit_f=False."""
     w_pad, n_pad = codesP.shape
     nblk = n_pad // BLOCK_ROWS
-    n_bins = route_f.shape[1]
+    n_bins = route_f.shape[1] // planes
     KERNEL_TRACES.inc(kernel="route_f" if emit_f else "route", L=str(L))
 
     def row():
@@ -359,7 +377,7 @@ def sbh_route_pallas(codesP, heap, tbl, route_f, valtab=None, F=None, *,
     if emit_f:
         kernel = functools.partial(_route_kernel_f, base=base, L=L,
                                    n_bins=n_bins, eta=eta, any_cat=any_cat,
-                                   na_code=na_code)
+                                   na_code=na_code, planes=planes)
         in_specs += [pl.BlockSpec(valtab.shape, lambda j: (0, 0)), row()]
         args += (valtab, F.reshape(1, n_pad))
         out_specs = [row(), row()]
@@ -368,7 +386,7 @@ def sbh_route_pallas(codesP, heap, tbl, route_f, valtab=None, F=None, *,
     else:
         kernel = functools.partial(_route_kernel, base=base, L=L,
                                    n_bins=n_bins, any_cat=any_cat,
-                                   na_code=na_code)
+                                   na_code=na_code, planes=planes)
         out_specs, out_shape = row(), heap_shape
     out = pl.pallas_call(
         kernel,
@@ -387,7 +405,7 @@ def sbh_route_pallas(codesP, heap, tbl, route_f, valtab=None, F=None, *,
 
 def sbh_route_xla(codesT, heap, tbl, route_f, valtab=None, F=None, *,
                   base, L, eta=0.0, emit_f=False, any_cat=True,
-                  na_code=255):
+                  na_code=255, planes=1):
     """Pure-XLA fallback: same contract (CPU scatter/gather is fast).
     codesT is the UNPACKED (C_pad, n_pad) plane — uint8 or legacy i32;
     the integer arithmetic below is dtype-agnostic and bit-identical."""
@@ -401,6 +419,12 @@ def sbh_route_xla(codesT, heap, tbl, route_f, valtab=None, F=None, *,
         axis=0)[0].astype(jnp.int32)
     n_bins = route_f.shape[1]
     go = route_f.reshape(-1)[leaf_c * n_bins + code_sel] > 0.5
+    for k in range(1, planes):      # the column's further byte planes
+        code_k = jnp.take_along_axis(
+            codesT, jnp.clip(col_r + k, 0, codesT.shape[0] - 1)[None, :],
+            axis=0)[0].astype(jnp.int32)
+        go = go | (route_f.reshape(-1)[
+            leaf_c * n_bins + k * (n_bins // planes) + code_k] > 0.5)
     splits = active & did_r
     newheap = jnp.where(splits, 2 * heap + 1 + go.astype(jnp.int32), heap)
     newF = F + eta * valtab[0, newheap] if emit_f else F
@@ -408,14 +432,15 @@ def sbh_route_xla(codesT, heap, tbl, route_f, valtab=None, F=None, *,
 
 
 def sbh_route(codes, heap, tbl, route_f, valtab=None, F=None, *, base, L,
-              eta=0.0, emit_f=False, any_cat=True, na_code=255):
+              eta=0.0, emit_f=False, any_cat=True, na_code=255, planes=1):
     if is_packed(codes):
         return sbh_route_pallas(codes, heap, tbl, route_f, valtab, F,
                                 base=base, L=L, eta=eta, emit_f=emit_f,
-                                any_cat=any_cat, na_code=na_code)
+                                any_cat=any_cat, na_code=na_code,
+                                planes=planes)
     return sbh_route_xla(codes, heap, tbl, route_f, valtab, F,
                          base=base, L=L, eta=eta, emit_f=emit_f,
-                         any_cat=any_cat, na_code=na_code)
+                         any_cat=any_cat, na_code=na_code, planes=planes)
 
 
 # ===========================================================================
@@ -544,7 +569,7 @@ def _fused_applicable(L_h: int, n_bins: int, c_pack: int) -> bool:
 
 def _fused_kernel(codesP_ref, heap_ref, tbl_ref, route_ref, stats_ref,
                   heap_out_ref, hist_ref, *, base_r, L_r, base_h, L_h,
-                  n_bins, any_cat, na_code, gwe):
+                  n_bins, any_cat, na_code, gwe, planes=1):
     j = pl.program_id(0)
 
     @pl.when(j == 0)
@@ -554,7 +579,8 @@ def _fused_kernel(codesP_ref, heap_ref, tbl_ref, route_ref, stats_ref,
     words = codesP_ref[...]                                   # (W_pad, R)
     newheap = _route_math(words, heap_ref[0, :], tbl_ref[...],
                           route_ref[...], base=base_r, L=L_r,
-                          n_bins=n_bins, any_cat=any_cat, na_code=na_code)
+                          n_bins=n_bins, any_cat=any_cat, na_code=na_code,
+                          planes=planes)
     heap_out_ref[0, :] = newheap
     # histogram over the UPDATED heap: left children of [base_h, base_h+L_h)
     A = _stats_panel(newheap, stats_ref[...], base=base_h, L=L_h, gwe=gwe,
@@ -565,10 +591,11 @@ def _fused_kernel(codesP_ref, heap_ref, tbl_ref, route_ref, stats_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("base_r", "L_r", "base_h", "L_h",
-                                    "n_bins", "any_cat", "na_code"))
+                                    "n_bins", "any_cat", "na_code",
+                                    "planes"))
 def sbh_route_hist_fused_pallas(codesP, heap, tbl, route_f, stats, *,
                                 base_r, L_r, base_h, L_h, n_bins,
-                                any_cat=True, na_code=255):
+                                any_cat=True, na_code=255, planes=1):
     """ONE kernel: route splits of [base_r, base_r+L_r), then accumulate
     the half (left-children) histogram of [base_h, base_h+L_h) over the
     updated heap. Returns (newheap, hist (l_eff, c_pack, S, n_bins))."""
@@ -579,11 +606,12 @@ def sbh_route_hist_fused_pallas(codesP, heap, tbl, route_f, stats, *,
     gwe = max(1, l_eff)
     nblk = n_pad // BLOCK_ROWS
     n_bins_rf = route_f.shape[1]
-    assert n_bins_rf == n_bins
+    assert n_bins_rf == planes * n_bins
     hist_shape = (c_pack, gwe * S_STATS, n_bins)
     kernel = functools.partial(_fused_kernel, base_r=base_r, L_r=L_r,
                                base_h=base_h, L_h=L_h, n_bins=n_bins,
-                               any_cat=any_cat, na_code=na_code, gwe=gwe)
+                               any_cat=any_cat, na_code=na_code, gwe=gwe,
+                               planes=planes)
     newheap, hist = pl.pallas_call(
         kernel,
         name="sbh_route_hist_fused",
@@ -611,7 +639,7 @@ def sbh_route_hist_fused_pallas(codesP, heap, tbl, route_f, stats, *,
 
 
 def sbh_route_hist(codes, heap, tbl, route_f, stats, *, base_r, L_r,
-                   base_h, L_h, n_bins, any_cat=True, na_code=255):
+                   base_h, L_h, n_bins, any_cat=True, na_code=255, planes=1):
     """Fused-or-sequential level pass: route the previous level's splits,
     then accumulate the new level's half (left-children) histogram over
     the updated heap. The fused Pallas program wherever the level
@@ -623,9 +651,9 @@ def sbh_route_hist(codes, heap, tbl, route_f, stats, *, base_r, L_r,
         return sbh_route_hist_fused_pallas(
             codes, heap, tbl, route_f, stats, base_r=base_r, L_r=L_r,
             base_h=base_h, L_h=L_h, n_bins=n_bins, any_cat=any_cat,
-            na_code=na_code)
+            na_code=na_code, planes=planes)
     newheap, _ = sbh_route(codes, heap, tbl, route_f, base=base_r, L=L_r,
-                           any_cat=any_cat, na_code=na_code)
+                           any_cat=any_cat, na_code=na_code, planes=planes)
     hist = sbh_hist(codes, newheap, stats, base=base_h, L=L_h,
                     n_bins=n_bins, half=True)
     return newheap, hist

@@ -190,6 +190,51 @@ def _add_fused(cases, seed, specs):
                       (u8, heap, tbl, route_f, stats), HIST_TOL))
 
 
+def _add_planes(cases, seed, planes=2):
+    """A split column whose code lies in one of `planes` byte columns
+    (models/tree/binned.py `Planes`: a categorical column past a code
+    byte): the terminal route and the fused route+hist with a route row a
+    plane, random bits in every plane's row — the kernels sum the planes'
+    bits, the twin ors them."""
+    L_h = 16
+    L_r = L_h >> 1
+    base_r, base_h, l_eff = L_r - 1, L_h - 1, L_h >> 1
+    u8, packed, heap, stats, _ = _rand_inputs(seed + 50, L_r)
+    rng = np.random.default_rng(seed + 51)
+    tbl, _, _ = _route_tables(rng, L_r)
+    tbl = tbl.at[0].set(jnp.minimum(tbl[0], C_PAD - planes))
+    route = jnp.asarray((rng.random((max(8, L_r), planes * N_BINS)) < 0.4)
+                        .astype(np.float32))
+    nodes_p = -(-(2 * (base_r + L_r) + 1) // 128) * 128
+    valtab = jnp.asarray(np.concatenate(
+        [rng.normal(0, 1, (1, nodes_p)),
+         np.zeros((7, nodes_p))]).astype(np.float32))
+    F = jnp.asarray(rng.normal(0, 1, N_PAD).astype(np.float32))
+    kw = dict(base=base_r, L=L_r, any_cat=True, na_code=B_VAL, eta=0.1,
+              emit_f=True, planes=planes)
+    cases.append((
+        f"route_planes={planes}_terminal",
+        lambda c, h, t, r, v, f: HP.sbh_route_pallas(c, h, t, r, v, f, **kw),
+        lambda c, h, t, r, v, f: HP.sbh_route_xla(c, h, t, r, v, f, **kw),
+        (packed, heap, tbl, route, valtab, F),
+        (u8, heap, tbl, route, valtab, F), 1e-5))
+
+    def fused(c, h, t, r, s):
+        nh, hist = HP.sbh_route_hist_fused_pallas(
+            c, h, t, r, s, base_r=base_r, L_r=L_r, base_h=base_h, L_h=L_h,
+            n_bins=N_BINS, any_cat=True, na_code=B_VAL, planes=planes)
+        return nh, hist[:l_eff, :C_PAD]
+
+    def pair(c, h, t, r, s):
+        nh, _ = HP.sbh_route_xla(c, h, t, r, base=base_r, L=L_r,
+                                 any_cat=True, na_code=B_VAL, planes=planes)
+        return nh, HP.sbh_hist_xla(c, nh, s, base=base_h, L=L_h,
+                                   n_bins=N_BINS, half=True)[:l_eff]
+    cases.append((f"fused_planes={planes}_L={L_h}", fused, pair,
+                  (packed, heap, tbl, route, stats),
+                  (u8, heap, tbl, route, stats), HIST_TOL))
+
+
 def fusable_levels(c_pack=C_PAD, n_bins=N_BINS):
     """Every L_h of a depth<=10 tree the fused shape rule admits."""
     return [1 << d for d in range(1, 11)
@@ -199,14 +244,15 @@ def fusable_levels(c_pack=C_PAD, n_bins=N_BINS):
 def kernel_parity_check(seed=0):
     """Assert pallas == xla at HIGGS width for everything the DEFAULT
     rules select: hist (full + half), route (with and without the F
-    stream, numeric and categorical) and the level-fused route+hist at
-    every level its rule admits. Returns a dict of max deviations."""
+    stream, numeric and categorical), the level-fused route+hist at
+    every level its rule admits, and both routes over a column of two
+    byte planes. Returns a dict of max deviations."""
     devs = {}
     # one program pair per family: a refusal names its family, and each
     # family's Mosaic kernels still compile in parallel
     for add in (_add_hist, _add_route, functools.partial(
             _add_fused, specs=[(L_h, False) for L_h in fusable_levels()]
-            + [(LEVELS[1], True)])):
+            + [(LEVELS[1], True)]), _add_planes):
         cases = []
         add(cases, seed)
         devs.update(_run_cases(cases))

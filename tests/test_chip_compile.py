@@ -308,3 +308,80 @@ def test_prediction_planes_compile_for_v5e_at_frame_size(chips, topo, sds,
         for sh in jax.tree_util.tree_leaves(compiled.output_shardings):
             assert sh.is_equivalent_to(out_sh, 1), sh
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+# ---- the airline configuration (benchmark/configs/gbm_airline.json): eight
+# columns, six categorical, Origin and Dest past a code byte -------------------
+AIR_ROWS = 7_720_935
+AIR_LEVELS = (12, 31, 7, 0, 29, 340, 340, 0)
+
+
+def _airline_spec():
+    levels = np.array(AIR_LEVELS)
+    spec = BN.make_bins(np.zeros((64, 8), np.float32), levels > 0, 255,
+                        cat_levels=levels)
+    return spec, spec.planes
+
+
+@pytest.mark.parametrize("name", ["route_planes", "fused_planes",
+                                  "quantize_planes", "walk_sets"])
+def test_airline_programs_compile_for_v5e(name, sds, no_persistent_cache):
+    """What a frame with a column past a code byte adds to the programs
+    (models/tree/binned.py `Planes`): the route kernels reading a split
+    column's code from one of two byte planes, at 16 plane columns of 256
+    bins and 7,720,935 rows; the program that makes the planes; and the
+    scoring walk with the set match beside the feature select, which must
+    stay `jit__ensemble_walk`, hold no gather and keep the level one-hot
+    out of HBM (7,720,936 x 768 bf16 would be 11.9 GB)."""
+    spec, pl = _airline_spec()
+    assert (pl.cp_pad, pl.per, pl.n_search, spec.n_bins) == (16, 2, 384, 256)
+    n_pad = -(-(AIR_ROWS + 1) // HP.BLOCK_ROWS) * HP.BLOCK_ROWS
+    w_pad = HP.packed_words(pl.cp_pad)
+    codes, heap = sds((w_pad, n_pad), jnp.int32), sds((n_pad,), jnp.int32)
+    stats = sds((HP.S_STATS, n_pad), jnp.float32)
+
+    def tables(L):
+        Lp = max(8, L)
+        return sds((8, Lp), jnp.float32), \
+            sds((Lp, pl.per * spec.n_bins), jnp.float32)
+    if name == "route_planes":
+        nodes_p = -(-(2 ** 6) // 128) * 128
+        # depth 5 routes its first four levels in the fused kernel: the
+        # terminal route is the one route kernel its trainer holds
+        lowered = jax.jit(lambda a: HP.sbh_route_pallas(
+            *a, base=15, L=16, eta=0.1, emit_f=True, any_cat=True,
+            na_code=spec.b_val, planes=pl.per)).lower(
+            (codes, heap, *tables(16), sds((8, nodes_p), jnp.float32),
+             sds((n_pad,), jnp.float32)))
+    elif name == "fused_planes":
+        assert HP._fused_applicable(16, spec.n_bins, w_pad * HP.PACK)
+        lowered = jax.jit(lambda a: HP.sbh_route_hist_fused_pallas(
+            *a, base_r=7, L_r=8, base_h=15, L_h=16, n_bins=spec.n_bins,
+            any_cat=True, na_code=spec.b_val, planes=pl.per)).lower(
+            (codes, heap, *tables(8), stats))
+    elif name == "quantize_planes":
+        lowered = BN._quantize_planes.lower(
+            sds((AIR_ROWS, 8), jnp.float32),
+            sds(spec.edges.shape, jnp.float32), sds((8,), jnp.int32),
+            *(sds((pl.cp_pad,), d) for d in (jnp.int32, jnp.int32,
+                                             jnp.bool_)), n_pad=n_pad)
+    else:
+        ntrees, depth, words = 20, 5, 12
+        nodes = 2 ** (depth + 1) - 1
+        cats = tuple((c, k) for c, k in enumerate(AIR_LEVELS) if k)
+        assert E._walk_path(depth, 8, sum(AIR_LEVELS)) == "dense"
+        tbl = [sds((ntrees, nodes), d) for d in
+               (jnp.int32, jnp.float32, jnp.bool_, jnp.float32)]
+        lowered = E._ensemble_walk.__wrapped__.lower(
+            sds((AIR_ROWS + 1, 8), jnp.float32), *tbl,
+            sds((ntrees,), jnp.float32),
+            sds((ntrees, nodes, words), jnp.uint32), sds((8,), jnp.bool_),
+            depth=depth, has_cat=True, cats=cats)
+    compiled = lowered.compile()    # raises what the chip's compiler would
+    text = compiled.as_text()
+    if name == "walk_sets":
+        assert text.startswith("HloModule jit__ensemble_walk")
+        assert not re.search(r" gather\(", text)
+        assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+    elif name != "quantize_planes":
+        assert "tpu_custom_call" in text

@@ -60,6 +60,14 @@ def test_walk_phase():
     assert rec["pallas_kernels_traced"] == []   # the XLA twin ran here
 
 
+def test_walk_sets_phase():
+    rec = cs.phase_walk_sets(6_000, cs.WALK_SET_LEVELS, (5, 5), SEED)
+    assert rec["level_rows"] == sum(cs.WALK_SET_LEVELS) == 759
+    assert rec["set_words"] == 12 and rec["rows_past_a_byte"] > 0
+    assert 0 < rec["set_nodes"] < rec["split_nodes"]
+    assert rec["nodes_reached"] > 15 and rec["nonfinite_cells"] > 0
+
+
 def test_train_phase(trained):
     rec = trained[0]
     assert rec["train_auc"] > cs.AUC_MIN
@@ -110,9 +118,9 @@ def test_parity_harness_with_the_twins_standing_in(monkeypatch):
         return h, (f if kw.get("emit_f") else None)
 
     def fused(cp, heap, tbl, rf, stats, *, base_r, L_r, base_h, L_h,
-              n_bins, any_cat, na_code):
+              n_bins, any_cat, na_code, planes=1):
         nh, _ = route(cp, heap, tbl, rf, base=base_r, L=L_r,
-                      any_cat=any_cat, na_code=na_code)
+                      any_cat=any_cat, na_code=na_code, planes=planes)
         return nh, hist(cp, nh, stats, base=base_h, L=L_h, n_bins=n_bins,
                         half=True)
 
@@ -120,7 +128,7 @@ def test_parity_harness_with_the_twins_standing_in(monkeypatch):
     monkeypatch.setattr(HP, "sbh_route_pallas", route)
     monkeypatch.setattr(HP, "sbh_route_hist_fused_pallas", fused)
     devs = parity.kernel_parity_check(SEED)
-    assert len(devs) >= 13 and max(devs.values()) == 0
+    assert len(devs) >= 17 and max(devs.values()) == 0
 
 
 def _run(args, cwd=REPO, **env):

@@ -392,8 +392,11 @@ def test_predict_leaves_one_root_span_with_its_stages(
     assert len(roots) == 1
     root = roots[0]
     assert root["parent"] == 0
+    # a tree model's root also says what the walk matches: the level rows
+    # of its categorical columns and its SET-split nodes (none here)
     assert root["attrs"] == {"model": m.key, "algo": "gbm", "frame": f.key,
-                             "rows": f.nrows, "cols": f.ncols, "path": path}
+                             "rows": f.nrows, "cols": f.ncols, "path": path,
+                             "cat_levels": 0, "set_nodes": 0}
     kids = [s for s in spans if s["parent"] == root["id"]
             and s["name"].startswith("predict.")]
     assert [s["name"] for s in kids] == children

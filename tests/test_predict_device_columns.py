@@ -187,8 +187,10 @@ def test_score_host_still_fetches_for_the_host_callers(models, monkeypatch):
     out = m._score_host(f)
     names = [s["name"] for s in SPANS.snapshot()]
     assert isinstance(out, np.ndarray) and out.shape == (f.padded_len, 2)
-    assert names == ["predict.matrix", "predict.dispatch", "predict.wait",
-                     "predict.fetch"]
+    # (spans are listed as they END: the tables' placement lies inside
+    # the dispatch)
+    assert names == ["predict.matrix", "predict.tables", "predict.dispatch",
+                     "predict.wait", "predict.fetch"]
     fetch = SPANS.snapshot()[-1]
     assert fetch["attrs"]["bytes"] == out.nbytes
     monkeypatch.delenv("H2O3_SCORE_FASTPATH_MAX_ROWS")

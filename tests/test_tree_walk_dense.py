@@ -217,7 +217,9 @@ def test_the_shape_picks_the_body_and_the_counter_says_which():
             (trees(8), "dense", "0.5x256"), (trees(12), "dense", "0.5x256"),
             (trees(5), "dense", "4x32"), (trees(7), "dense", "1x128"),
             (trees(2), "dense", "16x8"), (trees(16), "gather", ""),
-            (trees(5, cat=True), "gather", "")):
+            # a categorical SET split no longer means gather: its 32 level
+            # rows count as columns (tests/test_tree_walk_sets.py)
+            (trees(5, cat=True), "dense", "4x32")):
         before = _walks(block)
         out = E.predict_ensemble(X28, ta)
         assert out.shape == (64,)
@@ -226,7 +228,7 @@ def test_the_shape_picks_the_body_and_the_counter_says_which():
         assert after[path] == before[path] + 1, (ta.depth, path)
         assert after[other] == before[other]
     assert E._walk_path(8, 28, False) == E._walk_path(5, 28, False) == "dense"
-    assert E._walk_path(8, 28, True) == "gather"
+    assert E._walk_path(8, 28, 32) == "dense"
     # depth 14 at HIGGS width is the deepest shape measured on the chip
     # (dense 5.9 x the faster); wider or deeper than that walks by gathers
     assert E._walk_path(14, 28, False) == "dense"
