@@ -10,9 +10,11 @@ depth 8, bernoulli; only the tree count is cut):
     kernels -> Pallas kernels == their XLA twins at HIGGS width, on chip
     walk    -> the dense scoring walk == the gather walk, leaf for leaf,
                at both cells' shapes (10 x depth 8, 20 x depth 5)
-    walk_sets -> the same with categorical SET splits, at the airline
-               cell's shape (8 columns, 6 categorical, 2 past a code
-               byte; 20 x depth 5)
+    walk_sets -> the same with categorical SET splits matched INSIDE the
+               fused kernel, at the airline cell's shape (8 columns, 6
+               categorical, 2 past a code byte; 20 x depth 5) and with
+               two blocks a tree's top and a level under them (10 x
+               depth 8, 3 x depth 9)
     ingest  -> seeded CSV through h2o3_tpu.import_file (native tokenizer)
     train   -> 11M x 28 Frame -> H2OGradientBoostingEstimator.train
     predict -> large-frame sharded path AND compiled-scorer fast path,
@@ -65,6 +67,7 @@ WALK_SHAPES = ((10, 8), (20, 5))   # (ntrees, depth) of the benchmark's two cell
 # the airline cell's shape: levels a column (0: numeric), 20 trees x depth 5
 WALK_SET_ROWS = 400_000
 WALK_SET_LEVELS = (12, 31, 7, 0, 29, 340, 340, 0)
+WALK_SET_SHAPES = (WALK_SHAPES[1], WALK_SHAPES[0], (3, 9))
 PARITY_ROWS = 1_100_003      # device-built frame == host-built: over the fast
                              # path's 2^20 rows, not a multiple of the padding
 ONE_ROW_MS_PR28 = (7.41, 7.66)   # PERF.md's 1-row medians, to read "1" against
@@ -246,9 +249,10 @@ def phase_walk_exact(rows: int, shapes, seed: int,
             "pallas_kernels_traced": picked, "shapes": recs}
 
 
-def phase_walk_sets(rows: int, levels, shape, seed: int) -> dict:
+def phase_walk_sets(rows: int, levels, shape, seed: int,
+                    on_chip: bool = True) -> dict:
     """The dense body with categorical SET splits against the gather body
-    on this device, at the airline cell's shape: `levels` a column (0:
+    on this device, at the airline cell's columns: `levels` a column (0:
     numeric; two columns past a code byte), `shape` = (ntrees, depth).
     Random trees that mix numeric and SET splits, level ids drawn over
     every level, with NaN, ids past the column's levels, negative and
@@ -256,9 +260,13 @@ def phase_walk_sets(rows: int, levels, shape, seed: int) -> dict:
     NODE's own number, so `==` is leaf for leaf; then random values and
     weights, the ensemble's sum bit for bit. The set match is a bfloat16
     product of {0, 1} summed in f32: only the chip can show that its MXU
-    keeps it exact."""
+    keeps it exact, that Mosaic's float -> int conversion reads a level id
+    as `_cat_code` does, and that the kernel's set variant
+    (`walk_dense_tile_sets`) is what ran."""
     import jax.numpy as jnp
     from h2o3_tpu.models.tree import engine as E
+    from h2o3_tpu.ops import hist_pallas as HP
+    traces0 = HP.kernel_traces()
     rng = np.random.default_rng(seed + 5)
     levels = np.asarray(levels)
     C, (ntrees, depth) = levels.size, shape
@@ -309,7 +317,13 @@ def phase_walk_sets(rows: int, levels, shape, seed: int) -> dict:
                          (rng.random(ntrees) + 0.5).astype(np.float32))
     assert np.array_equal(dense, gather), int((dense != gather).sum())
     is_set = (col >= 0) & (levels > 0)[np.maximum(col, 0)]
+    picked = sorted(k for k, v in HP.kernel_traces().items()
+                    if v > traces0.get(k, 0))
+    # on the chip the dense body IS the fused kernel, sets and all
+    assert picked == ([("walk_dense_tile_sets", 1 << depth)] if on_chip
+                      else []), picked
     return {"rows": rows, "cols": C, "ntrees": ntrees, "depth": depth,
+            "block": E._block_label(depth), "pallas_kernels_traced": picked,
             "level_rows": K, "set_words": W, "set_nodes": int(is_set.sum()),
             "split_nodes": int((col >= 0).sum()),
             "rows_past_a_byte": int((X[:, levels > 255] >= 256).sum()),
@@ -749,9 +763,10 @@ def main(argv=None) -> int:
         _emit("kernels", t0, phase_kernels(args.seed))
         t0 = time.perf_counter()
         _emit("walk", t0, phase_walk_exact(WALK_ROWS, WALK_SHAPES, args.seed))
-        t0 = time.perf_counter()
-        _emit("walk_sets", t0, phase_walk_sets(
-            WALK_SET_ROWS, WALK_SET_LEVELS, WALK_SHAPES[1], args.seed))
+        for shape in WALK_SET_SHAPES:
+            t0 = time.perf_counter()
+            _emit("walk_sets", t0, phase_walk_sets(
+                WALK_SET_ROWS, WALK_SET_LEVELS, shape, args.seed))
         t0 = time.perf_counter()
         _emit("ingest", t0, phase_ingest(INGEST_ROWS, args.seed, OUT_DIR))
         t0 = time.perf_counter()

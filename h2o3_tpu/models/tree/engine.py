@@ -403,7 +403,8 @@ jax.tree_util.register_pytree_node(TreeArrays, _trees_flatten,
 
 
 # ---- the scoring walk -------------------------------------------------------
-# Two bodies of one algorithm; `_walk_path` picks by shape, at trace time.
+# Two bodies of one algorithm; `_walk_path` picks by shape, at trace time
+# (and `_dense_body` the dense body's form: the TPU's kernel, or XLA).
 #
 # dense: no per-row index anywhere. A tile of rows meets EVERY node of a tree
 # with dense arithmetic, a BLOCK of 128 node slots at a time (one MXU tile
@@ -425,12 +426,16 @@ jax.tree_util.register_pytree_node(TreeArrays, _trees_flatten,
 # at 28 columns, the deepest shape measured, is the last to take the dense
 # body.
 #
-# A categorical SET split is decided densely too: a row tile's level
-# one-hots (one segment a categorical column, K rows in all) times the
-# blocks' bit matrices, on the MXU, gives every slot's bit (a row has one 1
-# in a column's segment and a slot is non-zero in one segment only: exact),
-# and the decision is where(slot splits a set, bit, x > thr) under the same
-# NaN rule. The K level rows count as columns in the bound.
+# A categorical SET split is decided densely too: the rows' level one-hots
+# (one segment a categorical column, K rows in all) times the blocks' bit
+# matrices, on the MXU, give every slot's bit (a row has one 1 in a
+# column's segment and a slot is non-zero in one segment only: exact), and
+# the decision is where(slot splits a set, bit, x > thr) under the same
+# NaN rule. The K level rows count as columns in the bound. On the TPU the
+# match is INSIDE the fused kernel (the one-hot a VMEM tile, one int8
+# product more a node block: 7,720,935 x 8 with 759 level rows at 20 x
+# depth 5 walks in 49.8 ms where the XLA body takes 105.7: PERF.md §6, PR
+# 35); the XLA body matches once a row tile, beside the feature select.
 _DENSE_MAX_CELLS = 1 << 19
 # rows x (steps x slots) of the set match of one row tile, kept as booleans
 _SET_TILE_CELLS = 1 << 26
@@ -455,6 +460,17 @@ def _walk_path(depth: int, n_cols: int, cat_rows: int = 0) -> str:
     dense = depth >= 1 and \
         ((1 << depth) - 1) * (n_cols + cat_rows) <= _DENSE_MAX_CELLS
     return "dense" if dense else "gather"
+
+
+def _dense_body(cats=()) -> str:
+    """Which of the dense body's two forms: "kernel" on the TPU, "xla"
+    elsewhere — and on the TPU for an ensemble whose level rows
+    (`_cat_layout`) leave the kernel's one-hot scratch not one whole chunk
+    of rows (`walk_pallas.hot_rows`: K' over 4,096 — a MOJO without
+    `cat_levels` matches all 32 W bits a column, and at depth 1-3
+    `_DENSE_MAX_CELLS` admits K in the tens of thousands)."""
+    fits = not cats or _wp.hot_rows(_wp.level_rows(cats)) > 0
+    return "kernel" if _wp.use_pallas() and fits else "xla"
 
 
 def _cat_layout(trees, n_cols: int) -> tuple:
@@ -600,11 +616,12 @@ def _perfect_tree(col, thr, nal, val, tw, n_cols, depth):
 
 def _perfect_sets(col, catbits, cats, depth):
     """The nodes' go-right sets in `_perfect_tree`'s layout, by the same
-    moves that place `col`: B (K', U * S) bf16 in {0, 1} — row off_c + l of
+    moves that place `col`: B (U, K', S) int8 in {0, 1} — row off_c + l of
     slot s of step u is bit l of the node in that slot when it splits
     categorical column c, else 0 (`cats` = `_cat_layout`: the segments in
-    order, K' = their sum filled up to a multiple of 128) — and which slots
-    split a set, (U, S) bool."""
+    order, K' = `walk_pallas.level_rows`: their sum filled up to whole MXU
+    tiles) — and which slots split a set, (U, S) bool. ONE layout for both
+    bodies: the XLA twin multiplies by it a row tile, the kernel a block."""
     levels, G, _ = _block_regime(depth)
     T, nodes, W = catbits.shape
     real, inner, L = (1 << depth) - 1, (1 << levels) - 1, 1 << levels
@@ -623,11 +640,10 @@ def _perfect_sets(col, catbits, cats, depth):
         .transpose(0, 2, 1)                                  # (U, 32 W, S)
     segs = [jnp.where((col1 == c)[:, None, :], bits[:, :k, :], 0)
             for c, k in cats]
-    K = sum(k for _, k in cats)
-    B = jnp.concatenate(segs, axis=1).astype(jnp.bfloat16)
-    B = jnp.pad(B, ((0, 0), (0, -K % 128), (0, 0)))
+    B = jnp.concatenate(segs, axis=1)
+    B = jnp.pad(B, ((0, 0), (0, _wp.level_rows(cats) - B.shape[1]), (0, 0)))
     is_set = functools.reduce(jnp.logical_or, [col1 == c for c, _ in cats])
-    return B.transpose(1, 0, 2).reshape(B.shape[1], -1), is_set
+    return B, is_set
 
 
 # the two bodies are jitted for the tests that set one against the other;
@@ -635,19 +651,17 @@ def _perfect_sets(col, catbits, cats, depth):
 @functools.partial(jax.jit, static_argnames=("depth", "cats"))
 def _walk_dense(X, col, thr, nal, val, tw, catbits=None, *, depth, cats=()):
     """Σ_t tw[t] · value[t, leaf_t(row)] with no per-row index: bit for bit
-    what `_walk_gather` returns. A numeric ensemble runs on the TPU as ONE
-    fused kernel a row tile (ops/walk_pallas.py), `_walk_dense_xla` its twin
-    everywhere else; with categorical SET splits (`cats`, `_cat_layout`)
-    the XLA body on every backend, the set match beside the feature
-    select."""
+    what `_walk_gather` returns. On the TPU ONE fused kernel a row tile
+    (ops/walk_pallas.py), categorical SET splits (`cats`, `_cat_layout`)
+    matched inside it; `_walk_dense_xla` is its twin everywhere else
+    (`_dense_body`)."""
     tables = _perfect_tree(col, thr, nal, val, tw, X.shape[1], depth)
-    levels = _block_regime(depth)[0]
-    if cats:
-        return _walk_dense_xla(
-            X, *tables, _block_paths(depth), levels=levels, cats=cats,
-            sets=_perfect_sets(col, catbits, cats, depth))
-    body = _wp.walk_dense_tile if _wp.use_pallas() else _walk_dense_xla
-    return body(X, *tables, _block_paths(depth), levels=levels)
+    body = _wp.walk_dense_tile if _dense_body(cats) == "kernel" \
+        else _walk_dense_xla
+    of_sets = dict(cats=cats, sets=_perfect_sets(col, catbits, cats, depth)) \
+        if cats else {}
+    return body(X, *tables, _block_paths(depth),
+                levels=_block_regime(depth)[0], **of_sets)
 
 
 def _cat_code(x, rows):
@@ -674,6 +688,9 @@ def _walk_dense_xla(X, sel, thr1, nal1, leafv, tws, paths, *, levels,
     tables = (sel2, thr1, nal1, leafv, tws)
     if cats:
         setB, is_set = sets
+        # (U, K', S) -> (K', U * S): every step's slots in one product
+        setB = setB.transpose(1, 0, 2).reshape(setB.shape[1], -1) \
+            .astype(jnp.bfloat16)
         t = max(1, min(t, _SET_TILE_CELLS // setB.shape[1]))
         tables += (is_set, jnp.arange(thr1.shape[0]))
         # the level rows' own tables: where each categorical column's

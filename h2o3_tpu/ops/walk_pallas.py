@@ -24,6 +24,17 @@ feature -> the f32 itself -> the decision (NaN: ~na_left, else x > thr) as
 which child] -> the tree's leaf value -> acc + w * v, tree by tree in tree
 order.
 
+Categorical SET splits (`cats`: the set variant, counted and named
+`walk_dense_tile_sets`; a numeric ensemble's program holds none of this):
+at a tile's first step the rows' level one-hots go to a VMEM scratch too
+(`_level_one_hot`: a segment a categorical column, K' level rows in all,
+int8), and a block's decision takes ONE more product — the block's bit
+matrix (128 x K', `engine._perfect_sets`) times the one-hot, int8 with an
+int32 sum of one term = the slot's bit of the row's level, exact — OR-ed
+into x > thr (a slot that splits a set holds thr = +inf). The row tile
+keeps the one-hot to HOT_BYTES (`hot_rows`); nothing of (rows x level
+rows) size is ever an array in HBM.
+
 `engine._walk_dense_xla` is the twin (the CPU's body and the test oracle);
 the two agree with `engine._walk_gather` bit for bit.
 """
@@ -51,10 +62,79 @@ PATH_LEVELS = 8
 # 9.7 / 33.6 at 8192 (PERF.md §6, PR 33); the tile's size moves nothing
 TILE_ROWS = 16384
 CHUNK = 8192
+# the level one-hot of a row tile (level rows x rows, int8) keeps to this
+# much VMEM: 768 level rows (the airline table's) x 16384 rows is 12.6 MB
+HOT_BYTES = 32 << 20
+# lanes of one pass of the one-hot's compares: a column's 340 levels x 256
+# lanes are 88 vregs (128 / 256 / 512 / 1024 lanes moved a frame of the
+# airline cell by +2.3 / 0 / +0.4 / -0.3 ms: PERF.md §6, PR 35)
+HOT_LANES = 256
+# level rows are filled up to whole MXU tiles (the product's depth); a
+# segment starts where the one before ends: ONE layout,
+# `engine._perfect_sets`' too
+LEVEL_TILE = 128
+# sublanes of an int8 tile: what a store into the one-hot is aligned to
+_I8_ROWS = 32
 
 
-def _walk_kernel(tw_ref, xt_ref, sel_ref, tbl_ref, paths_ref, out_ref,
-                 planes, acc, *, levels, trees, chunk):
+def level_rows(cats) -> int:
+    """K' of `cats` (`engine._cat_layout`): the segments one after the
+    other, filled up to a multiple of LEVEL_TILE. 0 for no segment."""
+    return -(-sum(k for _, k in cats) // LEVEL_TILE) * LEVEL_TILE
+
+
+def hot_rows(Kp: int, chunk: int = CHUNK) -> int:
+    """The rows of a row tile whose level one-hot (Kp, rows) keeps to
+    HOT_BYTES, in whole chunks: 0 where not one fits (the XLA twin's)."""
+    return HOT_BYTES // Kp // chunk * chunk
+
+
+def _level_one_hot(xt_ref, hot, cats):
+    """hot (K', rows) int8 <- the tile's level one-hots: row off_c + l is
+    (column c's code == l), the code by `engine._cat_code`'s rule (NaN -> 0,
+    truncated toward zero, held to [0, k - 1]: held first, as a float, and
+    floored, so that the conversion neither overflows nor rounds — the
+    same whole number). Made FOUR LEVEL ROWS A 32-BIT WORD, as
+    `pltpu.bitcast` lays int8 rows (row 4 i + j is byte j of word row i):
+    one compare and one select a word, not a level. A segment starts
+    wherever the one before ends, and stores go in whole int8 tiles of 32
+    sublanes: a segment is compared over the tiles it touches (a code
+    inside [0, k) matches no row outside the segment) and ADDED to the
+    tile it shares with the segments before."""
+    Kp, rows = hot.shape
+
+    def lanes(j, carry):
+        at = pl.ds(pl.multiple_of(j * HOT_LANES, HOT_LANES), HOT_LANES)
+        off = done = 0
+        for c, k in cats:
+            lo = off // _I8_ROWS * _I8_ROWS
+            hi = -(-(off + k) // _I8_ROWS) * _I8_ROWS
+            x = xt_ref[c:c + 1, at]
+            x = jnp.clip(jnp.where(x != x, 0.0, x), 0.0, k - 1.0)
+            code = jnp.floor(x).astype(jnp.int32) + (off - lo)
+            word = jax.lax.broadcasted_iota(
+                jnp.int32, ((hi - lo) // 4, HOT_LANES), 0)
+            one = jnp.where(word == code >> 2, 1 << 8 * (code & 3), 0)
+            if lo < done:
+                shared = one[:(done - lo) // 4] \
+                    + pltpu.bitcast(hot[lo:done, at], jnp.int32)
+                one = shared if hi == done else \
+                    jnp.concatenate([shared, one[(done - lo) // 4:]])
+            hot[lo:hi, at] = pltpu.bitcast(one, jnp.int8)
+            off, done = off + k, hi
+        if done < Kp:
+            hot[done:, at] = jnp.zeros((Kp - done, HOT_LANES), jnp.int8)
+        return carry
+
+    jax.lax.fori_loop(0, rows // HOT_LANES, lanes, 0)
+
+
+def _walk_kernel(tw_ref, xt_ref, sel_ref, tbl_ref, paths_ref, *rest,
+                 levels, trees, chunk, cats):
+    if cats:
+        set_ref, out_ref, planes, acc, hot = rest
+    else:
+        out_ref, planes, acc = rest
     step = pl.program_id(1)
     Cp = xt_ref.shape[0]
     top = min(levels, PATH_LEVELS)
@@ -68,6 +148,8 @@ def _walk_kernel(tw_ref, xt_ref, sel_ref, tbl_ref, paths_ref, out_ref,
         for k in range(4):
             byte = ((bits >> (8 * k)) & 0xFF).astype(jnp.float32)
             planes[k * Cp:(k + 1) * Cp, :] = byte.astype(jnp.bfloat16)
+        if cats:
+            _level_one_hot(xt_ref, hot, cats)
         acc[...] = jnp.zeros_like(acc)
 
     def one_chunk(j, carry):
@@ -81,8 +163,16 @@ def _walk_kernel(tw_ref, xt_ref, sel_ref, tbl_ref, paths_ref, out_ref,
                               preferred_element_type=jnp.float32)
                       .astype(jnp.int32) for h in (0, 1))
             x = pltpu.bitcast((hi << 16) | lo, jnp.float32)
-            return jnp.where(x != x, tbl[:, 1:2],
-                             jnp.where(x > tbl[:, 0:1], 1.0, -1.0))
+            nan, nan_turn = x != x, tbl[:, 1:2]
+            right = x > tbl[:, 0:1]
+            if cats:
+                # the slot's bit of the row's level: {0, 1} x {0, 1} in
+                # int8 (twice bf16's rate on this MXU), one non-zero term a
+                # slot and row. A slot that splits a set holds thr = +inf,
+                # every other slot an empty set
+                right |= jnp.dot(set_ref[0, b], hot[:, at_rows],
+                                 preferred_element_type=jnp.int32) > 0
+            return jnp.where(nan, nan_turn, jnp.where(right, 1.0, -1.0))
 
         def reached(b, tbl):
             """(128, chunk): the position of level `top` each row reaches,
@@ -140,19 +230,38 @@ def _walk_kernel(tw_ref, xt_ref, sel_ref, tbl_ref, paths_ref, out_ref,
         out_ref[...] = acc[...]
 
 
-def walk_dense_tile(X, sel, thr, nal, leafv, tws, paths, *, levels):
+def walk_dense_tile(X, sel, thr, nal, leafv, tws, paths, *, levels, cats=(),
+                    sets=None):
     """The steps of `engine._perfect_tree` over X (n, C): (n,) f32 =
-    Σ_t w[t] · value[t, leaf_t(row)], tree by tree."""
+    Σ_t w[t] · value[t, leaf_t(row)], tree by tree. `cats`, `sets`
+    (`engine._cat_layout`, `_perfect_sets`): the categorical columns' level
+    rows and the nodes' go-right sets (U, K', S) with the slots that split
+    one (U, S) — int8 {0, 1}; the caller sees that `hot_rows(K')` is not 0."""
     n, C = X.shape
     U, G = tws.shape
     blocks = thr.shape[1] // BLOCK
-    KERNEL_TRACES.inc(kernel="walk_dense_tile", L=str(1 << levels))
+    name = "walk_dense_tile_sets" if cats else "walk_dense_tile"
+    KERNEL_TRACES.inc(kernel=name, L=str(1 << levels))
     Cp = -(-C // 16) * 16       # a bf16 tile's 16 sublanes
     # (U, C, S) one-hot -> (U, blocks, 128, 2 Cp): [low byte | high byte]
     # of a 16-bit half -> low + 256 * high
     sel = jnp.pad(sel, ((0, 0), (0, Cp - C), (0, 0)))
     sel = jnp.concatenate([sel, 256 * sel], axis=1) \
         .reshape(U, 2 * Cp, blocks, BLOCK).transpose(0, 2, 3, 1)
+    chunk = min(CHUNK, TILE_ROWS, -(-n // 256) * 256)
+    rows = min(TILE_ROWS, -(-n // chunk) * chunk)
+    operands, specs, scratch = (), [], []
+    if cats:
+        bits, is_set = sets
+        Kp = bits.shape[1]
+        # no finite or infinite x is over +inf: a set's slot turns by its bit
+        thr = jnp.where(is_set, jnp.inf, thr)
+        # (U, K', S) -> (U, blocks, 128, K'), as sel
+        operands = (bits.reshape(U, Kp, blocks, BLOCK).transpose(0, 2, 3, 1),)
+        specs = [pl.BlockSpec((1, blocks, BLOCK, Kp),
+                              lambda i, u: (u, 0, 0, 0))]
+        rows = min(rows, hot_rows(Kp, chunk))
+        scratch = [pltpu.VMEM((Kp, rows), jnp.int8)]
     # a block's per-slot constants as columns: thr, a NaN's turn (±1), the
     # leaf value (of the bottom level's positions, laid as the slots are)
     tbl = jnp.stack([a.reshape(U, blocks, BLOCK) for a in
@@ -160,14 +269,13 @@ def walk_dense_tile(X, sel, thr, nal, leafv, tws, paths, *, levels):
                      + [jnp.zeros_like(thr)] * 5], axis=2)
     # the path matrix transposed: positions on the sublanes too
     pathsT = jnp.asarray(paths.transpose(0, 2, 1), jnp.bfloat16)
-    chunk = min(CHUNK, TILE_ROWS, -(-n // 256) * 256)
-    rows = min(TILE_ROWS, -(-n // chunk) * chunk)
     XT = X.T
     if n < rows:                # a frame shorter than a tile: one padded tile
         XT = jnp.pad(XT, ((0, 0), (0, rows - n)))
     out = pl.pallas_call(
-        functools.partial(_walk_kernel, levels=levels, trees=G, chunk=chunk),
-        name="walk_dense_tile",
+        functools.partial(_walk_kernel, levels=levels, trees=G, chunk=chunk,
+                          cats=cats),
+        name=name,
         grid=(-(-n // rows), U),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -176,13 +284,13 @@ def walk_dense_tile(X, sel, thr, nal, leafv, tws, paths, *, levels):
                          lambda i, u: (u, 0, 0, 0)),
             pl.BlockSpec((1, blocks, 8, BLOCK), lambda i, u: (u, 0, 0, 0)),
             pl.BlockSpec(pathsT.shape, lambda i, u: (0, 0, 0)),
-        ],
+        ] + specs,
         out_specs=pl.BlockSpec((1, rows), lambda i, u: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, XT.shape[1]), jnp.float32),
         scratch_shapes=[pltpu.VMEM((4 * Cp, rows), jnp.bfloat16),
-                        pltpu.VMEM((1, rows), jnp.float32)],
+                        pltpu.VMEM((1, rows), jnp.float32)] + scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=96 << 20),
-    )(tws, XT, sel, tbl, pathsT)
+    )(tws, XT, sel, tbl, pathsT, *operands)
     return out[0, :n]

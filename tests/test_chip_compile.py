@@ -323,16 +323,29 @@ def _airline_spec():
     return spec, spec.planes
 
 
+# the scoring walk's set shapes: (ntrees, depth, chips, the columns' levels
+# known) — the cell's own, two blocks a tree's top, a position level under
+# them, the cell's over the host's four chips, and a MOJO of the same table
+# (384 bits a column: K' = 2,304, half a tile of rows)
+WALK_SETS = {"walk_sets": (20, 5, 1, True), "walk_sets_d8": (10, 8, 1, True),
+             "walk_sets_d9": (3, 9, 1, True),
+             "walk_sets_4chips": (20, 5, 4, True),
+             "walk_sets_mojo": (20, 5, 1, False)}
+
+
 @pytest.mark.parametrize("name", ["route_planes", "fused_planes",
-                                  "quantize_planes", "walk_sets"])
-def test_airline_programs_compile_for_v5e(name, sds, no_persistent_cache):
+                                  "quantize_planes", *WALK_SETS])
+def test_airline_programs_compile_for_v5e(name, topo, sds,
+                                          no_persistent_cache, monkeypatch):
     """What a frame with a column past a code byte adds to the programs
     (models/tree/binned.py `Planes`): the route kernels reading a split
     column's code from one of two byte planes, at 16 plane columns of 256
     bins and 7,720,935 rows; the program that makes the planes; and the
-    scoring walk with the set match beside the feature select, which must
-    stay `jit__ensemble_walk`, hold no gather and keep the level one-hot
-    out of HBM (7,720,936 x 768 bf16 would be 11.9 GB)."""
+    scoring walk with the set match INSIDE the fused kernel
+    (`walk_dense_tile_sets`, which Mosaic must accept at these shapes),
+    which must stay `jit__ensemble_walk`, hold no gather and no collective,
+    and leave nothing of (rows x level rows) size outside VMEM (7,720,936 x
+    768 int8 would be 5.9 GB; the program's temp is under 16 MB)."""
     spec, pl = _airline_spec()
     assert (pl.cp_pad, pl.per, pl.n_search, spec.n_bins) == (16, 2, 384, 256)
     n_pad = -(-(AIR_ROWS + 1) // HP.BLOCK_ROWS) * HP.BLOCK_ROWS
@@ -366,22 +379,44 @@ def test_airline_programs_compile_for_v5e(name, sds, no_persistent_cache):
             *(sds((pl.cp_pad,), d) for d in (jnp.int32, jnp.int32,
                                              jnp.bool_)), n_pad=n_pad)
     else:
-        ntrees, depth, words = 20, 5, 12
-        nodes = 2 ** (depth + 1) - 1
-        cats = tuple((c, k) for c, k in enumerate(AIR_LEVELS) if k)
-        assert E._walk_path(depth, 8, sum(AIR_LEVELS)) == "dense"
-        tbl = [sds((ntrees, nodes), d) for d in
+        ntrees, depth, chips, known = WALK_SETS[name]
+        nodes, words = 2 ** (depth + 1) - 1, 12
+        cats = tuple((c, k if known else 32 * words)
+                     for c, k in enumerate(AIR_LEVELS) if k)
+        assert E._walk_path(depth, 8, sum(k for _, k in cats)) == "dense"
+        # the dispatcher asks the default backend, which is the CPU here
+        monkeypatch.setattr(WP, "use_pallas", lambda: True)
+        assert E._dense_body(cats) == "kernel"
+        mesh, rows = None, AIR_ROWS + 1
+        if chips == 4:
+            mesh = Mesh(np.array(topo.devices).reshape(-1, 1),
+                        (MESH.ROWS, MESH.MODEL))
+            rows = MESH.Cloud(mesh).padded_rows(AIR_ROWS)
+
+        def of(shape, dt, spec):
+            return sds(shape, dt) if mesh is None else jax.ShapeDtypeStruct(
+                shape, dt, sharding=NamedSharding(mesh, spec))
+        tbl = [of((ntrees, nodes), d, P()) for d in
                (jnp.int32, jnp.float32, jnp.bool_, jnp.float32)]
+        before = HP.kernel_traces()
         lowered = E._ensemble_walk.__wrapped__.lower(
-            sds((AIR_ROWS + 1, 8), jnp.float32), *tbl,
-            sds((ntrees,), jnp.float32),
-            sds((ntrees, nodes, words), jnp.uint32), sds((8,), jnp.bool_),
-            depth=depth, has_cat=True, cats=cats)
+            of((rows, 8), jnp.float32, P(MESH.ROWS)), *tbl,
+            of((ntrees,), jnp.float32, P()),
+            of((ntrees, nodes, words), jnp.uint32, P()),
+            of((8,), jnp.bool_, P()),
+            depth=depth, has_cat=True, cats=cats, mesh=mesh)
+        picked = {k for k, v in HP.kernel_traces().items()
+                  if v > before.get(k, 0)}
+        assert picked == {("walk_dense_tile_sets", 1 << depth)}
     compiled = lowered.compile()    # raises what the chip's compiler would
     text = compiled.as_text()
-    if name == "walk_sets":
+    if name in WALK_SETS:
         assert text.startswith("HloModule jit__ensemble_walk")
+        assert "tpu_custom_call" in text
         assert not re.search(r" gather\(", text)
-        assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+        for op in ("all-gather", "all-reduce", "all-to-all",
+                   "collective-permute"):
+            assert op not in text, op       # each chip walks its own rows
+        assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
     elif name != "quantize_planes":
         assert "tpu_custom_call" in text

@@ -60,12 +60,18 @@ def test_walk_phase():
     assert rec["pallas_kernels_traced"] == []   # the XLA twin ran here
 
 
-def test_walk_sets_phase():
-    rec = cs.phase_walk_sets(6_000, cs.WALK_SET_LEVELS, (5, 5), SEED)
+@pytest.mark.parametrize("shape,rows", [((5, 5), 6_000), ((2, 8), 20_000),
+                                        ((1, 9), 30_000)])
+def test_walk_sets_phase(shape, rows):
+    rec = cs.phase_walk_sets(rows, cs.WALK_SET_LEVELS, shape, SEED,
+                             on_chip=False)
     assert rec["level_rows"] == sum(cs.WALK_SET_LEVELS) == 759
     assert rec["set_words"] == 12 and rec["rows_past_a_byte"] > 0
     assert 0 < rec["set_nodes"] < rec["split_nodes"]
     assert rec["nodes_reached"] > 15 and rec["nonfinite_cells"] > 0
+    assert rec["block"] == ("4x32" if shape[1] == 5 else "0.5x256")
+    assert rec["pallas_kernels_traced"] == []   # the XLA twin ran here
+    assert {d for _, d in cs.WALK_SET_SHAPES} == {5, 8, 9}
 
 
 def test_train_phase(trained):

@@ -4,10 +4,13 @@ rows' level one-hots times the blocks' bit matrices — against
 `_walk_gather`'s per-row bit look-up, bit for bit (`==`, never allclose),
 on ensembles that mix numeric and SET splits, in every block regime, with
 the columns' levels known (`TreeArrays.cat_levels`) and not (a MOJO: all
-32 W bits of a set); and the one rule that picks the body.
+32 W bits of a set); the same for the TPU kernel's set variant
+(ops/walk_pallas.py: the level one-hot a VMEM tile, one int8 product more a
+node block), interpreted here; and the rules that pick the body and its
+form.
 
-The CPU's matmul is exact whatever its operands; that the chip's bfloat16
-product of {0, 1} is, is chip_smoke.py's `walk_sets` phase.
+The CPU's matmul is exact whatever its operands; that the chip's int8 and
+bfloat16 products of {0, 1} are, is chip_smoke.py's `walk_sets` phase.
 """
 
 import numpy as np
@@ -15,8 +18,11 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
 from h2o3_tpu.models.tree import engine as E
+from h2o3_tpu.ops import walk_pallas as WP
+from test_tree_walk_dense import _take_the_kernel
 
 C = 6
 IS_CAT = np.array([True, False, True, False, False, True])
@@ -46,7 +52,7 @@ def _rows(rng, n, levels):
     """Level ids over every level and past them (past the bitset too),
     negative and fractional ones, NaN and ±inf, beside numeric values."""
     X = rng.standard_normal((n, C)).astype(np.float32)
-    for c in np.flatnonzero(IS_CAT):
+    for c in np.flatnonzero(levels):
         X[:, c] = rng.integers(-3, levels[c] + 70, size=n) \
             + rng.choice([0.0, 0.5, 0.99], size=n)
     for v in (np.nan, np.inf, -np.inf, 1e12, -0.0):
@@ -54,23 +60,32 @@ def _rows(rng, n, levels):
     return X
 
 
-def _both(depth, ntrees, n, widest, seed, known=True):
+def _both(depth, ntrees, n, widest, seed, known=True, body="xla",
+          levels=None):
+    """`levels`: a column's levels, 0 for a numeric one (by default three
+    categorical columns of `widest`, 7 and 31); `body` "kernel": the TPU
+    kernel, interpreted — the program has no such option."""
     rng = np.random.default_rng(seed)
-    levels = np.array([widest, 0, 7, 0, 0, 31])
+    levels = np.array([widest, 0, 7, 0, 0, 31] if levels is None else levels)
     col, thr, nal, val, tw, sets = _ensemble(rng, ntrees, depth, levels)
     X = _rows(rng, n, levels)
     ta = E.TreeArrays(col=col, thr=thr, na_left=nal, value=val, depth=depth,
-                      catbits=sets, col_is_cat=IS_CAT,
+                      catbits=sets, col_is_cat=levels > 0,
                       cat_levels=levels if known else None)
     cats = E._cat_layout(ta, C)
     hold = np.zeros(C, np.int32)
     hold[[c for c, _ in cats]] = [k for _, k in cats]
     args = [jnp.asarray(a) for a in (X, col, thr, nal, val, tw)]
     want = np.asarray(E._walk_gather(
-        *args, jnp.asarray(sets), jnp.asarray(IS_CAT), jnp.asarray(hold),
+        *args, jnp.asarray(sets), jnp.asarray(levels > 0), jnp.asarray(hold),
         depth=depth, has_cat=True))
-    got = np.asarray(E._walk_dense(*args, jnp.asarray(sets), depth=depth,
-                                   cats=cats))
+    if body == "xla":
+        got = np.asarray(E._walk_dense(*args, jnp.asarray(sets), depth=depth,
+                                       cats=cats))
+    else:
+        with pltpu.force_tpu_interpret_mode():
+            got = np.asarray(E._walk_dense.__wrapped__(
+                *args, jnp.asarray(sets), depth=depth, cats=cats))
     assert got.dtype == want.dtype == np.float32 and got.shape == (n,)
     assert np.array_equal(got, want), \
         f"{(got != want).sum()} of {n} rows differ"
@@ -128,6 +143,86 @@ def test_a_row_sharded_frame_with_sets_stays_sharded(cloud8, monkeypatch):
     got = E.predict_ensemble(X, ta, weights=tw)
     assert got.sharding.is_equivalent_to(cloud8.rows_sharding(1), 1)
     assert np.array_equal(np.asarray(got), want)
+
+
+def _set_traces(depth):
+    return WP.KERNEL_TRACES.value(kernel="walk_dense_tile_sets",
+                                  L=str(1 << max(depth, 3)))
+
+
+# the kernel's set variant in every block regime (16 / 4 trees a block, one
+# tree a block, two blocks a tree's top, a position level under them, its
+# block index dynamic) x level rows that fill no int8 tile of 32 nor an MXU
+# tile of 128 (43, 78, 338, 738); 1,037 rows in tiles of 512: two whole
+# tiles and a tail; NaN, ids past a column's levels, negative and
+# fractional ids among the rows (`_rows`)
+@pytest.mark.parametrize("widest", [5, 40, 300, 700])
+@pytest.mark.parametrize("depth", [1, 3, 5, 7, 8, 9])
+def test_the_set_kernel_is_the_gather_walk_bit_for_bit(depth, widest,
+                                                       monkeypatch):
+    _take_the_kernel(monkeypatch, 512)
+    before = _set_traces(depth)
+    numeric = WP.KERNEL_TRACES.value(kernel="walk_dense_tile",
+                                     L=str(1 << max(depth, 3)))
+    _, _, _, want, cats = _both(depth, 7, 1037, widest,
+                                2000 * depth + widest, body="kernel")
+    assert sum(k for _, k in cats) % 32 and np.unique(want).size > 1
+    assert _set_traces(depth) == before + 1
+    assert WP.KERNEL_TRACES.value(
+        kernel="walk_dense_tile", L=str(1 << max(depth, 3))) == numeric
+
+
+# one categorical column (a segment alone, off a tile's edge); six of them,
+# short ones among them (a tile of 32 level rows shared by three and four
+# segments; a segment that ends where its tile does); the levels not known
+# (a MOJO: all 32 W bits a column); one row; a frame shorter than a tile
+@pytest.mark.parametrize("depth,n,levels,known", [
+    (5, 1037, [0, 0, 300, 0, 0, 0], True),
+    (3, 700, [0, 0, 0, 0, 0, 5], True),
+    (5, 1037, [3, 2, 7, 20, 300, 31], True),
+    (8, 517, [12, 31, 7, 29, 340, 340], True),
+    (9, 300, [3, 2, 7, 20, 40, 31], True),
+    (5, 517, [40, 0, 7, 0, 0, 31], False),
+    (8, 300, [300, 0, 7, 0, 0, 31], False),
+    (5, 1, [300, 0, 7, 0, 0, 31], True),
+    (7, 100, [3, 29, 0, 32, 0, 64], True)])
+def test_the_set_kernel_over_segment_layouts(depth, n, levels, known,
+                                             monkeypatch):
+    _take_the_kernel(monkeypatch, 256)
+    before = _set_traces(depth)
+    ta, _, _, _, cats = _both(depth, 5, n, max(levels), depth + n,
+                              known=known, body="kernel", levels=levels)
+    bits = 32 * ta.catbits.shape[-1]
+    assert [k for _, k in cats] == [k if known else bits for k in levels if k]
+    assert _set_traces(depth) == before + 1
+
+
+def test_the_level_one_hots_budget_picks_the_kernel_or_the_twin(monkeypatch):
+    """`_dense_body`: on the TPU a dense ensemble takes the kernel, with or
+    without sets, while its level rows leave the one-hot scratch a whole
+    chunk of rows; past that the XLA twin — from the shape alone."""
+    # this backend is not a TPU: the twin, whatever the shape
+    assert E._dense_body() == E._dense_body(((0, 759),)) == "xla"
+    monkeypatch.setattr(WP, "use_pallas", lambda: True)
+    airline = tuple((c, k) for c, k in enumerate(
+        (12, 31, 7, 0, 29, 340, 340, 0)) if k)
+    assert WP.level_rows(airline) == 768 and WP.level_rows(()) == 0
+    assert WP.hot_rows(768) == 5 * WP.CHUNK     # the tile is TILE_ROWS
+    assert E._dense_body() == E._dense_body(airline) == "kernel"
+    # a MOJO of the same table: six columns x 384 bits
+    assert E._dense_body(tuple((c, 384) for c in range(6))) == "kernel"
+    assert WP.hot_rows(4096) == WP.CHUNK and WP.hot_rows(4224) == 0
+    assert E._dense_body(((0, 4096),)) == "kernel"
+    assert E._dense_body(((0, 4097),)) == "xla"
+    assert E._dense_body(((0, 4000), (3, 97))) == "xla"
+    # depth 3 admits it densely (7 x (6 + 5000) <= 2^19), the twin walks it
+    assert E._walk_path(3, C, 5000) == "dense"
+    monkeypatch.setattr(WP, "TILE_ROWS", 256)
+    before = _set_traces(3)
+    _both(3, 4, 300, 5000, 11, body="kernel", levels=[5000, 0, 7, 0, 0, 0])
+    assert _set_traces(3) == before              # no kernel was traced
+    _both(3, 4, 300, 4000, 12, body="kernel", levels=[4000, 0, 7, 0, 0, 0])
+    assert _set_traces(3) == before + 1
 
 
 def test_the_level_rows_count_as_columns_in_the_rule():
