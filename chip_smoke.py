@@ -15,6 +15,11 @@ depth 8, bernoulli; only the tree count is cut):
                categorical, 2 past a code byte; 20 x depth 5) and with
                two blocks a tree's top and a level under them (10 x
                depth 8, 3 x depth 9)
+    walk_classes -> a 23-class ensemble through ONE walk (the kernel's
+               accumulator a row a class) == 23 gather walks of the
+               classes' own trees, bit for bit, at the KDD Cup 1999
+               cell's shape (41 columns, 3 categorical; 10 x 23 trees of
+               depth 5) and at depths 8 and 9
     ingest  -> seeded CSV through h2o3_tpu.import_file (native tokenizer)
     train   -> 11M x 28 Frame -> H2OGradientBoostingEstimator.train
     predict -> large-frame sharded path AND compiled-scorer fast path,
@@ -68,6 +73,11 @@ WALK_SHAPES = ((10, 8), (20, 5))   # (ntrees, depth) of the benchmark's two cell
 WALK_SET_ROWS = 400_000
 WALK_SET_LEVELS = (12, 31, 7, 0, 29, 340, 340, 0)
 WALK_SET_SHAPES = (WALK_SHAPES[1], WALK_SHAPES[0], (3, 9))
+# the KDD Cup 1999 cell's shape: 41 columns, three categorical, 23 classes,
+# (iterations, depth): the cell's own 10 x 23 trees of depth 5, then 8 and 9
+WALK_CLASS_LEVELS = (0, 3, 70, 11) + (0,) * 37
+WALK_CLASSES = 23
+WALK_CLASS_SHAPES = ((10, 5), (2, 8), (1, 9))
 PARITY_ROWS = 1_100_003      # device-built frame == host-built: over the fast
                              # path's 2^20 rows, not a multiple of the padding
 ONE_ROW_MS_PR28 = (7.41, 7.66)   # PERF.md's 1-row medians, to read "1" against
@@ -249,6 +259,34 @@ def phase_walk_exact(rows: int, shapes, seed: int,
             "pallas_kernels_traced": picked, "shapes": recs}
 
 
+def _set_model(rng, rows: int, levels, ntrees: int, depth: int):
+    """(X, col, thr, na_left, sets): rows over `levels` a column (0:
+    numeric) — level ids over every level, with NaN, ±inf, ids past the
+    column's levels, negative and fractional values among them — and
+    random trees that mix numeric and SET splits, early leaves among
+    them, thresholds drawn from the rows."""
+    C = levels.size
+    X = rng.standard_normal((rows, C)).astype(np.float32) * 700 + 1200
+    for c in np.flatnonzero(levels):
+        X[:, c] = rng.integers(0, levels[c], size=rows)
+        odd = rng.random(rows) < 0.02
+        X[odd, c] = rng.choice([-3.0, -0.5, 0.5, levels[c] - 0.25,
+                                levels[c], levels[c] + 77.0, 1e9],
+                               size=int(odd.sum()))
+    for v, p in ((np.nan, 0.01), (np.inf, 0.001), (-np.inf, 0.001)):
+        X[rng.random(X.shape) < p] = v
+    nodes, inner = 2 ** (depth + 1) - 1, 2 ** depth - 1
+    W = -(-int(levels.max()) // 128) * 4
+    col = rng.integers(0, C, size=(ntrees, nodes)).astype(np.int32)
+    col[:, inner:] = -1
+    col[:, 1:inner][rng.random((ntrees, inner - 1)) < 0.1] = -1
+    thr = X[rng.integers(0, rows, size=col.shape), np.maximum(col, 0)]
+    nal = rng.random(col.shape) < 0.5
+    bits = rng.integers(0, 2 ** 32, size=col.shape + (W,),
+                        dtype=np.uint64).astype(np.uint32)
+    return X, col, thr, nal, bits
+
+
 def phase_walk_sets(rows: int, levels, shape, seed: int,
                     on_chip: bool = True) -> dict:
     """The dense body with categorical SET splits against the gather body
@@ -270,24 +308,8 @@ def phase_walk_sets(rows: int, levels, shape, seed: int,
     rng = np.random.default_rng(seed + 5)
     levels = np.asarray(levels)
     C, (ntrees, depth) = levels.size, shape
-    X = rng.standard_normal((rows, C)).astype(np.float32) * 700 + 1200
-    for c in np.flatnonzero(levels):
-        X[:, c] = rng.integers(0, levels[c], size=rows)
-        odd = rng.random(rows) < 0.02
-        X[odd, c] = rng.choice([-3.0, -0.5, 0.5, levels[c] - 0.25,
-                                levels[c], levels[c] + 77.0, 1e9],
-                               size=int(odd.sum()))
-    for v, p in ((np.nan, 0.01), (np.inf, 0.001), (-np.inf, 0.001)):
-        X[rng.random(X.shape) < p] = v
-    nodes, inner = 2 ** (depth + 1) - 1, 2 ** depth - 1
-    W = -(-int(levels.max()) // 128) * 4
-    col = rng.integers(0, C, size=(ntrees, nodes)).astype(np.int32)
-    col[:, inner:] = -1
-    col[:, 1:inner][rng.random((ntrees, inner - 1)) < 0.1] = -1
-    thr = X[rng.integers(0, rows, size=col.shape), np.maximum(col, 0)]
-    nal = rng.random(col.shape) < 0.5
-    bits = rng.integers(0, 2 ** 32, size=col.shape + (W,),
-                        dtype=np.uint64).astype(np.uint32)
+    X, col, thr, nal, bits = _set_model(rng, rows, levels, ntrees, depth)
+    nodes, inner, W = col.shape[1], 2 ** depth - 1, bits.shape[-1]
     ta = E.TreeArrays(col=col, thr=thr, na_left=nal, value=thr, depth=depth,
                       catbits=bits, col_is_cat=levels > 0, cat_levels=levels)
     cats = E._cat_layout(ta, C)
@@ -329,6 +351,66 @@ def phase_walk_sets(rows: int, levels, shape, seed: int,
             "rows_past_a_byte": int((X[:, levels > 255] >= 256).sum()),
             "nodes_reached": len(reached),
             "nonfinite_cells": int((~np.isfinite(X)).sum())}
+
+
+def phase_walk_classes(rows: int, levels, shape, classes: int, seed: int,
+                       on_chip: bool = True) -> dict:
+    """A K-class ensemble through ONE walk (`TreeArrays.tree_class`: the
+    kernel's accumulator a row a class, each tree's w * v added to its
+    class's row) against K separate gather walks of the classes' own trees
+    on this device, bit for bit — at the KDD Cup 1999 cell's columns
+    (`levels`) and `shape` = (iterations, depth), iteration-major trees of
+    random values and weights (a few of weight 0), rows as `_set_model`
+    makes them. Only the chip can show that the kernel's dynamic-sublane
+    update sums a class's trees in that class's own order, and that the
+    class variant (`walk_dense_tile_sets_classes`) is what ran."""
+    import jax.numpy as jnp
+    from h2o3_tpu.models.tree import engine as E
+    from h2o3_tpu.ops import hist_pallas as HP
+    traces0 = HP.kernel_traces()
+    rng = np.random.default_rng(seed + 6)
+    levels = np.asarray(levels)
+    C, (iters, depth) = levels.size, shape
+    T = iters * classes
+    X, col, thr, nal, bits = _set_model(rng, rows, levels, T, depth)
+    val = rng.standard_normal(col.shape).astype(np.float32)
+    tw = (rng.random(T) + 0.5).astype(np.float32)
+    tw[3::7] = 0.0
+    cls = np.tile(np.arange(classes, dtype=np.int32), iters)
+    ta = E.TreeArrays(col=col, thr=thr, na_left=nal, value=val, depth=depth,
+                      catbits=bits, col_is_cat=levels > 0, cat_levels=levels,
+                      tree_class=cls)
+    cats = E._cat_layout(ta, C)
+    assert E._walk_path(depth, C, sum(k for _, k in cats)) == "dense"
+    hold = np.zeros(C, np.int32)
+    hold[[c for c, _ in cats]] = [k for _, k in cats]
+    Xd = jnp.asarray(X)
+    t0 = time.perf_counter()
+    one = np.asarray(E.predict_ensemble(Xd, ta, weights=tw))
+    first_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    again = np.asarray(E.predict_ensemble(Xd, ta, weights=tw))
+    warm_s = time.perf_counter() - t0
+    assert one.shape == (rows, classes) and np.array_equal(one, again)
+    for c in range(classes):
+        own = [jnp.asarray(a[cls == c]) for a in (col, thr, nal, val, tw,
+                                                  bits)]
+        want = np.asarray(E._walk_gather(
+            Xd, *own, jnp.asarray(levels > 0), jnp.asarray(hold),
+            depth=depth, has_cat=True))
+        assert np.array_equal(one[:, c], want), \
+            (depth, c, int((one[:, c] != want).sum()))
+    picked = sorted(k for k, v in HP.kernel_traces().items()
+                    if v > traces0.get(k, 0))
+    assert picked == ([("walk_dense_tile_sets_classes", 1 << depth)]
+                      if on_chip else []), picked
+    return {"rows": rows, "cols": C, "classes": classes, "ntrees": T,
+            "depth": depth, "block": E._block_label(depth),
+            "pallas_kernels_traced": picked,
+            "level_rows": sum(k for _, k in cats),
+            "distinct_sums": int(np.unique(one).size),
+            "nonfinite_cells": int((~np.isfinite(X)).sum()),
+            "first_call_s": round(first_s, 3), "warm_call_s": round(warm_s, 3)}
 
 
 def write_csv(path: str, X, y):
@@ -767,6 +849,11 @@ def main(argv=None) -> int:
             t0 = time.perf_counter()
             _emit("walk_sets", t0, phase_walk_sets(
                 WALK_SET_ROWS, WALK_SET_LEVELS, shape, args.seed))
+        for shape in WALK_CLASS_SHAPES:
+            t0 = time.perf_counter()
+            _emit("walk_classes", t0, phase_walk_classes(
+                WALK_SET_ROWS, WALK_CLASS_LEVELS, shape, WALK_CLASSES,
+                args.seed))
         t0 = time.perf_counter()
         _emit("ingest", t0, phase_ingest(INGEST_ROWS, args.seed, OUT_DIR))
         t0 = time.perf_counter()

@@ -603,7 +603,9 @@ def _h_tree(h):
     tn = int(p.get("tree_number") or 0)
     cls_name = p.get("tree_class")
     ta = getattr(m, "_trees", None)
-    if ta is None and getattr(m, "_trees_k", None) is not None:
+    # a K-class model: the named class's trees (of GBM's one ensemble a
+    # host view, `_trees_k`), tree_number counted within the class
+    if getattr(m, "_trees_k", None) is not None:
         dom = m._dinfo.response_domain or []
         ci = dom.index(cls_name) if cls_name in dom else 0
         ta = m._trees_k[ci]

@@ -344,10 +344,22 @@ class TreeArrays:
     # col_is_cat. A categorical column's code is clipped to its levels, and
     # the dense walk matches that many bits of a node's set, not all 32 W
     cat_levels: object = None   # (C,) int or None
+    # a K-class ensemble (multinomial): the class each tree adds to, HOST
+    # metadata like col_is_cat — trees stored iteration-major (iteration i
+    # holds classes 0..K-1 in order), every class with a tree. None: one
+    # output. The walk then returns a margin a class, (n, K)
+    tree_class: object = None   # (T,) int32 or None
 
     @property
     def ntrees(self):
         return self.col.shape[0]
+
+    @property
+    def n_classes(self) -> int:
+        """K of a K-class ensemble; 0 for an ensemble of one output."""
+        if self.tree_class is None:
+            return 0
+        return int(np.max(self.tree_class)) + 1
 
     def __getstate__(self):
         # the walk's placed tables (`_walk_tables`) are derived state, made
@@ -377,25 +389,48 @@ def stack_trees(tree_list, depth) -> TreeArrays:
 # of baking them in as closure constants. Children are the per-node
 # arrays; `depth` is static trace structure, and `col_is_cat` and
 # `cat_levels` stay HOST data (aux) because predict_ensemble lays out the
-# categorical columns' level rows (`_cat_layout`) at trace time.
+# categorical columns' level rows (`_cat_layout`) at trace time; so does
+# `tree_class`, whose largest entry is the width of the walk's output.
 def _trees_flatten(t: TreeArrays):
     aux = (t.depth,
            None if t.col_is_cat is None
            else tuple(bool(b) for b in np.asarray(t.col_is_cat)),
            None if t.cat_levels is None
-           else tuple(int(k) for k in np.asarray(t.cat_levels)))
+           else tuple(int(k) for k in np.asarray(t.cat_levels)),
+           None if t.tree_class is None
+           else tuple(int(k) for k in np.asarray(t.tree_class)))
     return (t.col, t.thr, t.na_left, t.value, t.cover, t.catbits), aux
 
 
 def _trees_unflatten(aux, children):
-    depth, cat, levels = aux
+    depth, cat, levels, cls = aux
     col, thr, nal, val, cover, catbits = children
     return TreeArrays(col=col, thr=thr, na_left=nal, value=val,
                       depth=depth, cover=cover, catbits=catbits,
                       col_is_cat=None if cat is None
                       else np.asarray(cat, bool),
                       cat_levels=None if levels is None
-                      else np.asarray(levels, np.int64))
+                      else np.asarray(levels, np.int64),
+                      tree_class=None if cls is None
+                      else np.asarray(cls, np.int32))
+
+
+def class_ensembles(trees: TreeArrays) -> list:
+    """A K-class ensemble as K ensembles of one output each, on the HOST
+    (NumPy): class c's trees in their order. What the artifact writers and
+    the tree route read of a multinomial model; nothing here goes to the
+    device."""
+    cls = np.asarray(trees.tree_class)
+    # every table fetched once, then cut a class at a time
+    host = {k: None if a is None else np.asarray(a) for k, a in (
+        ("col", trees.col), ("thr", trees.thr), ("na_left", trees.na_left),
+        ("value", trees.value), ("cover", trees.cover),
+        ("catbits", trees.catbits))}
+    return [TreeArrays(depth=trees.depth, col_is_cat=trees.col_is_cat,
+                       cat_levels=trees.cat_levels,
+                       **{k: None if a is None else a[cls == c]
+                          for k, a in host.items()})
+            for c in range(trees.n_classes)]
 
 
 jax.tree_util.register_pytree_node(TreeArrays, _trees_flatten,
@@ -648,20 +683,25 @@ def _perfect_sets(col, catbits, cats, depth):
 
 # the two bodies are jitted for the tests that set one against the other;
 # inside `_ensemble_walk` a nested jit inlines
-@functools.partial(jax.jit, static_argnames=("depth", "cats"))
-def _walk_dense(X, col, thr, nal, val, tw, catbits=None, *, depth, cats=()):
+@functools.partial(jax.jit, static_argnames=("depth", "cats", "classes"))
+def _walk_dense(X, col, thr, nal, val, tw, catbits=None, cls=None, *, depth,
+                cats=(), classes=0):
     """Σ_t tw[t] · value[t, leaf_t(row)] with no per-row index: bit for bit
     what `_walk_gather` returns. On the TPU ONE fused kernel a row tile
     (ops/walk_pallas.py), categorical SET splits (`cats`, `_cat_layout`)
     matched inside it; `_walk_dense_xla` is its twin everywhere else
-    (`_dense_body`)."""
+    (`_dense_body`). `classes` = K > 0, `cls` (T,): each tree is summed
+    into its class's row, (n, K) out of the one walk."""
     tables = _perfect_tree(col, thr, nal, val, tw, X.shape[1], depth)
     body = _wp.walk_dense_tile if _dense_body(cats) == "kernel" \
         else _walk_dense_xla
-    of_sets = dict(cats=cats, sets=_perfect_sets(col, catbits, cats, depth)) \
+    more = dict(cats=cats, sets=_perfect_sets(col, catbits, cats, depth)) \
         if cats else {}
+    if classes:
+        U, G = tables[4].shape
+        more["classes"] = (_steps(cls[:, None], U, G, 0), classes)
     return body(X, *tables, _block_paths(depth),
-                levels=_block_regime(depth)[0], **of_sets)
+                levels=_block_regime(depth)[0], **more)
 
 
 def _cat_code(x, rows):
@@ -670,12 +710,22 @@ def _cat_code(x, rows):
     return jnp.clip(jnp.nan_to_num(x).astype(jnp.int32), 0, rows - 1)
 
 
+def _class_add(acc, c, term):
+    """acc (rows, K) with `term` (rows,) added into column c (traced): the
+    column read, added to and put back, so that a class's sum has the terms
+    and the order of that class's own walk."""
+    at = jax.lax.dynamic_slice_in_dim(acc, c, 1, axis=1)
+    return jax.lax.dynamic_update_slice_in_dim(acc, at + term[:, None], c,
+                                               axis=1)
+
+
 def _walk_dense_xla(X, sel, thr1, nal1, leafv, tws, paths, *, levels,
-                    cats=(), sets=None):
+                    cats=(), sets=None, classes=None):
     """The dense body in plain XLA: row tiles in a `fori_loop` (the last
     tile overlaps the one before), the steps of `_perfect_tree` in a
     `scan`. `cats`, `sets` (`_perfect_sets`): the ensemble's categorical
-    columns and its nodes' go-right sets."""
+    columns and its nodes' go-right sets. `classes`: (each step's trees'
+    classes (U, G), K) of a K-class ensemble — (n, K) out."""
     n = X.shape[0]
     L = 1 << levels
     top = min(levels, _PATH_LEVELS)
@@ -698,6 +748,8 @@ def _walk_dense_xla(X, sel, thr1, nal1, leafv, tws, paths, *, levels,
         ends = np.cumsum([k for _, k in cats])
         level = np.full(setB.shape[0], -1, np.int32)
         level[:ends[-1]] = np.concatenate([np.arange(k) for _, k in cats])
+    if classes:
+        tables += (classes[0],)
 
     def tile(i, out):
         s = jnp.minimum(i * t, n - t)   # the last tile overlaps the one before
@@ -730,6 +782,8 @@ def _walk_dense_xla(X, sel, thr1, nal1, leafv, tws, paths, *, levels,
 
         def step(acc, tables):
             s2, th, na, lv, ws, *of_sets = tables
+            if classes:
+                *of_sets, cs = of_sets
             with jax.named_scope("walk.level"):
                 lo, hi = (jnp.dot(h, s2, preferred_element_type=jnp.float32)
                           .astype(jnp.int32) for h in halves)
@@ -765,27 +819,34 @@ def _walk_dense_xla(X, sel, thr1, nal1, leafv, tws, paths, *, levels,
                 v = jnp.sum(jnp.where(at, lv[None, :], 0.0)
                             .reshape(t, G, -1), axis=2)
                 for g in range(G):      # tree by tree, in tree order
-                    acc = acc + ws[g] * v[:, g]
+                    if classes:
+                        acc = _class_add(acc, cs[g], ws[g] * v[:, g])
+                    else:
+                        acc = acc + ws[g] * v[:, g]
                 return acc, None
 
         # the sum starts from a zero the compiler cannot fold away: with one
         # step unrolled, 0 + w0 v0 + w1 v1 would leave it two products to
         # choose from when it contracts the add into a multiply-add (CPU)
-        zero = jax.lax.optimization_barrier(jnp.zeros(t, jnp.float32))
+        zero = jax.lax.optimization_barrier(
+            jnp.zeros((t, classes[1]) if classes else t, jnp.float32))
         with jax.named_scope("walk.tree"):
             acc, _ = jax.lax.scan(step, zero, tables)
         return jax.lax.dynamic_update_slice_in_dim(out, acc, s, axis=0)
 
-    return jax.lax.fori_loop(0, -(-n // t), tile, jnp.zeros(n, jnp.float32))
+    return jax.lax.fori_loop(
+        0, -(-n // t), tile,
+        jnp.zeros((n, classes[1]) if classes else n, jnp.float32))
 
 
-@functools.partial(jax.jit, static_argnames=("depth", "has_cat"))
+@functools.partial(jax.jit, static_argnames=("depth", "has_cat", "classes"))
 def _walk_gather(X, col, thr, nal, val, tw, catbits, iscat, cat_rows=None,
-                 *, depth, has_cat):
+                 cls=None, *, depth, has_cat, classes=0):
     """Σ_t tw[t] · value[t, leaf_t(row)] by a fixed-depth chain of gathers
     per tree: deep trees, and the dense body's oracle. `cat_rows` (C,): the
     levels a categorical column's code is held to (`_cat_layout`); None:
-    the 32 W bits of a set."""
+    the 32 W bits of a set. `classes` = K > 0, `cls` (T,): tree t is summed
+    into column cls[t] of (n, K)."""
     n = X.shape[0]
     if has_cat:
         nb = catbits.shape[-1] * 32
@@ -815,11 +876,15 @@ def _walk_gather(X, col, thr, nal, val, tw, catbits, iscat, cat_rows=None,
 
         node = jax.lax.fori_loop(0, depth, step, node)
         with jax.named_scope("walk.leaf"):
+            if classes:
+                return _class_add(acc, cls[t], tw[t] * val[t][node]), None
             return acc + tw[t] * val[t][node], None
 
     with jax.named_scope("walk.tree"):
-        out, _ = jax.lax.scan(per_tree, jnp.zeros(n, jnp.float32),
-                              jnp.arange(col.shape[0]))
+        out, _ = jax.lax.scan(
+            per_tree,
+            jnp.zeros((n, classes) if classes else n, jnp.float32),
+            jnp.arange(col.shape[0]))
     return out
 
 
@@ -837,17 +902,19 @@ def _rows_mesh(X):
 
 
 @_compat.guard_collective
-@functools.partial(jax.jit,
-                   static_argnames=("depth", "has_cat", "mesh", "cats"))
-def _ensemble_walk(X, col, thr, nal, val, tw, catbits, iscat, *, depth,
-                   has_cat, mesh=None, cats=()):
+@functools.partial(jax.jit, static_argnames=("depth", "has_cat", "mesh",
+                                             "cats", "classes"))
+def _ensemble_walk(X, col, thr, nal, val, tw, catbits, iscat, cls=None, *,
+                   depth, has_cat, mesh=None, cats=(), classes=0):
     """Module-level jitted scoring walk: cached per (shapes, depth, has_cat)
     signature. Defining this as a closure inside predict_ensemble gave the
     jit a fresh function identity per call — every single ensemble predict
     retraced AND recompiled, which dominated serving latency. The shape
     picks the body (`_walk_path`); the XLA module is `jit__ensemble_walk`
     either way. `mesh`: where X's rows are sharded (`_rows_mesh`); `cats`:
-    the categorical columns' level rows (`_cat_layout`)."""
+    the categorical columns' level rows (`_cat_layout`); `classes` = K > 0
+    and `cls` (T,): a K-class ensemble, (n, K) margins out of the ONE walk
+    (`TreeArrays.tree_class`)."""
     if _walk_path(depth, X.shape[1], sum(k for _, k in cats)) == "gather":
         rows = None
         if cats:
@@ -855,12 +922,21 @@ def _ensemble_walk(X, col, thr, nal, val, tw, catbits, iscat, *, depth,
             rows[[c for c, _ in cats]] = [k for _, k in cats]
             rows = jnp.asarray(rows)
         return _walk_gather(X, col, thr, nal, val, tw, catbits, iscat,
-                            rows, depth=depth, has_cat=has_cat)
+                            rows, cls, depth=depth, has_cat=has_cat,
+                            classes=classes)
     dense = functools.partial(_walk_dense, depth=depth, cats=cats)
     tables = (col, thr, nal, val, tw) + ((catbits,) if cats else ())
+    out_spec = (_mesh.ROWS,)
+    if classes:
+        # the classes go last; the sets, where there are any, stay sixth
+        def dense(X, *tables):
+            return _walk_dense(X, *tables[:-1], cls=tables[-1], depth=depth,
+                               cats=cats, classes=classes)
+        tables += (cls,)
+        out_spec += (None,)
     if mesh is not None:
         P = jax.sharding.PartitionSpec
-        dense = jax.shard_map(dense, mesh=mesh, out_specs=P(_mesh.ROWS),
+        dense = jax.shard_map(dense, mesh=mesh, out_specs=P(*out_spec),
                               in_specs=(P(_mesh.ROWS),) + (P(),) * len(tables),
                               check_vma=False)
     return dense(X, *tables)
@@ -870,7 +946,8 @@ _NO_SETS = (np.zeros((1, 1, 1), np.uint32), np.zeros(1, bool))
 
 
 def _walk_tables(trees: TreeArrays, n_cols: int):
-    """(the walk's arguments after X with unit weights, the level layout):
+    """(the walk's arguments after X with unit weights, a K-class ensemble's
+    followed by its trees' classes; the level layout):
     the ensemble's tables on the device and `_cat_layout`, made ONCE an
     ensemble and kept beside it. A host array handed to the jitted walk —
     `col_is_cat`, the unit weights, the numeric program's two unused
@@ -880,7 +957,7 @@ def _walk_tables(trees: TreeArrays, n_cols: int):
     entry holds the arrays it was made from and is made anew when the
     ensemble's are others; tables made under a trace keep none."""
     src = (trees.col, trees.thr, trees.na_left, trees.value, trees.catbits,
-           trees.col_is_cat, trees.cat_levels)
+           trees.col_is_cat, trees.cat_levels, trees.tree_class)
     hit = trees.__dict__.get("_tables")
     same = hit is not None and hit[1] == n_cols \
         and all(a is b for a, b in zip(hit[0], src))
@@ -894,14 +971,18 @@ def _walk_tables(trees: TreeArrays, n_cols: int):
         sets = _NO_SETS
     got = tuple(jnp.asarray(a) for a in (
         trees.col, trees.thr, trees.na_left, trees.value,
-        np.ones(trees.ntrees, np.float32), *sets)), cats
+        np.ones(trees.ntrees, np.float32), *sets)
+        + (() if trees.tree_class is None
+           else (np.asarray(trees.tree_class, np.int32),))), cats
     if not any(isinstance(a, jax.core.Tracer) for a in got[0]):  # h2o3-ok: R025 asks what the arrays ARE, not what they hold: made of traced arguments, or inside a trace (there a host constant is staged as a tracer too), the tables belong to that trace and no entry is kept
         trees.__dict__["_tables"] = (src, n_cols, got)
     return got
 
 
 def predict_ensemble(X, trees: TreeArrays, weights=None):
-    """Σ_t value[t, leaf_t(row)]. Ensembles of moderate depth are scored
+    """Σ_t value[t, leaf_t(row)], (n,) — of a K-class ensemble
+    (`tree_class`) the sum of each class's trees, (n, K), from the same ONE
+    dispatch. Ensembles of moderate depth are scored
     densely, every node of a tree for a tile of rows (`_walk_dense`) —
     categorical SET splits too: a node routes by bitset membership of the
     level id (hex/genmodel GenModel.bitSetContains analog), matched on the
@@ -912,16 +993,19 @@ def predict_ensemble(X, trees: TreeArrays, weights=None):
     # onto the device (inside `predict.dispatch` on the large-frame path);
     # after an ensemble's first call a look-up (`_walk_tables`)
     with _span("predict.tables"):
-        (col, thr, nal, val, tw, catbits, iscat), cats = \
+        (col, thr, nal, val, tw, catbits, iscat, *cls), cats = \
             _walk_tables(trees, X.shape[1])
         if weights is not None:
             tw = jnp.asarray(weights, jnp.float32)
     path = _walk_path(trees.depth, X.shape[1], sum(k for _, k in cats))
     WALKS.inc(path=path,
               block=_block_label(trees.depth) if path == "dense" else "")
-    return _ensemble_walk(X, col, thr, nal, val, tw, catbits, iscat,
+    # h2o3-ok: R025 tree_class is host numpy model metadata (the pytree's aux, never a tracer)
+    of_classes = dict(classes=trees.n_classes) if cls else {}
+    return _ensemble_walk(X, col, thr, nal, val, tw, catbits, iscat, *cls,
                           depth=trees.depth, has_cat=cats != (), cats=cats,
-                          mesh=_rows_mesh(X) if path == "dense" else None)
+                          mesh=_rows_mesh(X) if path == "dense" else None,
+                          **of_classes)
 
 
 @_compat.guard_collective

@@ -19,6 +19,7 @@ back from the router (val[heap]), so F-updates are gathers, not tree walks.
 from __future__ import annotations
 
 import contextlib
+import functools
 import math
 
 import jax
@@ -40,14 +41,14 @@ SET_NODES = _om.counter(
 class SharedTreeEstimator(ModelBase):
     """Common driver for GBM / DRF (and the histogram machinery IF shares)."""
 
-    # mesh-sharded serving: ensembles (TreeArrays pytrees — `_trees` for
-    # the single-output distributions, `_trees_k` per class for
-    # multinomial) enter the scorer as shared device args. The per-node
+    # mesh-sharded serving: ensembles (TreeArrays pytrees — `_trees`: GBM's
+    # ONE ensemble, a K-class one too (`tree_class`); `_trees_k` per class
+    # where a family keeps its classes apart: DRF, the XGBoost-style
+    # booster) enter the scorer as shared device args. The per-node
     # arrays shard their TREE axis over the optional "model" mesh axis
     # (each model shard walks its tree slice; XLA inserts the cross-shard
     # sum); on the default rows-only mesh that spec degenerates to one
-    # replicated copy. `_f0` stays a baked constant — the multinomial
-    # scorer concretizes it (float(self._f0[c])) at trace time.
+    # replicated copy. `_f0` stays a baked constant of the scorer.
     _serving_param_attrs = ("_trees", "_trees_k")
     _partition_rules = (
         (r"^_trees", jax.sharding.PartitionSpec("model")),
@@ -74,13 +75,36 @@ class SharedTreeEstimator(ModelBase):
     def _cat_mode(self):
         return "label"  # trees bin label-encoded categoricals natively
 
+    @property
+    def _trees_k(self):
+        """The classes' ensembles apart. A family that keeps them so (DRF,
+        the XGBoost-style booster) stores the list; of a model whose ONE
+        ensemble holds every class's trees (`_trees.tree_class`) this is a
+        HOST view derived on demand for the readers that want a class at a
+        time (genmodel/, the tree route) — never a second device copy."""
+        own = self.__dict__.get("_trees_k")
+        trees = self.__dict__.get("_trees")
+        if own is None and getattr(trees, "tree_class", None) is not None:
+            return E.class_ensembles(trees)
+        return own
+
+    @_trees_k.setter
+    def _trees_k(self, value):
+        self.__dict__["_trees_k"] = value
+
+    def _serving_params(self):
+        # the ensembles the instance itself holds: the derived view above
+        # is no parameter
+        p = {a: self.__dict__.get(a) for a in self._serving_param_attrs}
+        return {a: v for a, v in p.items() if v is not None} or None
+
     def _note_published(self):
         """Count the published ensembles' categorical SET-split nodes
         (h2o3_tree_set_split_nodes_total{algo}) and note what the `predict`
         root span says of the walk: `cat_levels`, the level rows the dense
         walk matches (engine._cat_layout), and `set_nodes`."""
-        trees = [t for t in [getattr(self, "_trees", None)]
-                 + list(getattr(self, "_trees_k", None) or []) if t is not None]
+        trees = [t for t in [self.__dict__.get("_trees")]
+                 + list(self.__dict__.get("_trees_k") or []) if t is not None]
         sets = rows = 0
         for ta in trees:
             cats = E._cat_layout(ta, len(self._dinfo.predictors))
@@ -285,7 +309,7 @@ class SharedTreeEstimator(ModelBase):
     def _binned_tree_arrays(self, ctx, chunks, prev=None, lead=None):
         """Assemble E.TreeArrays from trainer chunk outputs (+ an optional
         checkpoint model's arrays prepended). `lead` flattens extra leading
-        scan dims (the multinomial (iters, K) case picks class k)."""
+        scan dims (the multinomial (iters, K) case: iteration-major)."""
         spec, C = ctx["spec"], ctx["C"]
         sel = (lambda a: a) if lead is None else lead
         colT = jnp.concatenate([sel(c[0]) for c in chunks])
@@ -773,17 +797,16 @@ class H2OGradientBoostingEstimator(SharedTreeEstimator):
             pc = (wn * (yin == c)).sum() / max(wn.sum(), 1e-30)
             f0[c] = math.log(max(pc, 1e-10))
 
-        prevs = None
+        prev = None
         ckpt = p.get("checkpoint")
         if ckpt:
             prev_model = self._resolve_checkpoint(ckpt)
-            prevs = prev_model._trees_k
-            assert prevs[0].depth == grower.D, \
+            prev = prev_model._trees
+            assert prev.depth == grower.D, \
                 "checkpoint restart requires identical max_depth"
             f0 = prev_model._f0
-            Fc = jnp.stack(
-                [f0[c] + lr * E.predict_ensemble(ctx["X"], prevs[c])
-                 for c in range(K)], axis=1).astype(jnp.float32)
+            Fc = jnp.asarray(f0)[None, :] \
+                + lr * E.predict_ensemble(ctx["X"], prev)
             F = jnp.zeros((n_pad, K), jnp.float32).at[:n].set(Fc)
         else:
             F = jnp.where((jnp.arange(n_pad) < n)[:, None],
@@ -800,8 +823,8 @@ class H2OGradientBoostingEstimator(SharedTreeEstimator):
         sample_rate = float(p["sample_rate"])
         col_rate_tree = float(p.get("col_sample_rate_per_tree") or 1.0)
         chunks = []
-        done = prevs[0].ntrees if prevs is not None else 0
-        if prevs is not None and done >= ntrees:
+        done = prev.ntrees // K if prev is not None else 0
+        if prev is not None and done >= ntrees:
             raise ValueError(
                 f"checkpoint model already has {done} trees per class; "
                 f"ntrees ({ntrees}) must exceed it to continue training")
@@ -827,23 +850,24 @@ class H2OGradientBoostingEstimator(SharedTreeEstimator):
             if self._should_stop() or job.budget_exhausted:
                 break
 
-        # chunks hold (iters, K, ...) arrays; split into per-class ensembles
+        # chunks hold (iters, K, ...) arrays: iteration-major as they come,
+        # ONE ensemble with each tree's class beside it
         with job.phase("finish"):
-            self._trees_k = []
-            gains_tot = None
-            for c in range(K):
-                sel = (lambda a, c=c: a[:, c])
-                ta, g = self._binned_tree_arrays(
-                    ctx, chunks,
-                    prev=prevs[c] if prevs is not None else None, lead=sel)
-                self._trees_k.append(ta)
-                gains_tot = g if gains_tot is None else gains_tot + g
-            self._varimp_from_gains(np.asarray(gains_tot[:C], np.float64))
+            self._trees, gains = self._binned_tree_arrays(
+                ctx, chunks, prev=prev,
+                lead=lambda a: a.reshape((-1,) + a.shape[2:]))
+            self._trees.tree_class = np.tile(
+                np.arange(K, dtype=np.int32), self._trees.ntrees // K)
+            self._varimp_from_gains(np.asarray(gains[:C], np.float64))
             self._output.model_summary = {
-                "number_of_trees": sum(t.ntrees for t in self._trees_k),
+                "number_of_trees": int(self._trees.ntrees),
                 "max_depth": grower.D, "distribution": "multinomial",
                 "learn_rate": lr, "engine": "binned_pallas",
+                "nbins_effective": ctx["spec"].b_val,
             }
+            if ctx["grouped"]:      # never silently: which levels share bins
+                self._output.model_summary["categorical_levels_grouped"] = \
+                    ctx["grouped"]
 
     def _fit_multinomial(self, X, y, w, job):
         self._vstate = None   # no multinomial validation series (yet)
@@ -863,7 +887,7 @@ class H2OGradientBoostingEstimator(SharedTreeEstimator):
             f0[c] = math.log(max(pc, 1e-10))
         self._f0 = f0
         F = jnp.tile(jnp.asarray(f0)[None, :], (X.shape[0], 1))
-        trees_k = [[] for _ in range(K)]
+        trees = []              # iteration-major: iteration t, class c
         gains_tot = jnp.zeros(X.shape[1], jnp.float32)
         interval = max(1, int(self.params.get("score_tree_interval") or 5))
         onehot = jax.nn.one_hot(yi, K)
@@ -887,7 +911,7 @@ class H2OGradientBoostingEstimator(SharedTreeEstimator):
                                    nodes=grower.nodes, scale=(K - 1) / K)
                 cover = E.node_covers(heap, wt, nodes=grower.nodes,
                                       D=grower.D)
-                trees_k[c].append((col, thr, nal, val, cover))
+                trees.append((col, thr, nal, val, cover))
                 newF.append(F[:, c] + lr * val[heap])
             F = jnp.stack(newF, axis=1)
             if (t + 1) % interval == 0 or t == ntrees - 1:
@@ -895,10 +919,12 @@ class H2OGradientBoostingEstimator(SharedTreeEstimator):
                 if self._should_stop():
                     break
             job.update(0.1 + 0.8 * (t + 1) / ntrees, f"iter {t+1}")
-        self._trees_k = [E.stack_trees(tl, grower.D) for tl in trees_k]
+        self._trees = E.stack_trees(trees, grower.D)
+        self._trees.tree_class = np.tile(np.arange(K, dtype=np.int32),
+                                         len(trees) // K)
         self._varimp_from_gains(np.asarray(gains_tot, np.float64))
         self._output.model_summary = {
-            "number_of_trees": sum(t.ntrees for t in self._trees_k),
+            "number_of_trees": int(self._trees.ntrees),
             "max_depth": grower.D, "distribution": "multinomial",
         }
 
@@ -906,13 +932,26 @@ class H2OGradientBoostingEstimator(SharedTreeEstimator):
     def _score_matrix(self, X):
         lr = float(self.params["learn_rate"])
         if self._dist == "multinomial":
-            Fs = [jnp.full(X.shape[0], float(self._f0[c]), jnp.float32)
-                  + lr * E.predict_ensemble(X, ta)
-                  for c, ta in enumerate(self._trees_k)]
-            return jax.nn.softmax(jnp.stack(Fs, axis=1), axis=1)
+            # ONE walk sums every tree into its class's row, one link after
+            margins = E.predict_ensemble(X, self._trees)
+            with _span("predict.link"):
+                return _class_link(margins, self._link_f0(), lr=lr)
         F = self._f0 + lr * E.predict_ensemble(X, self._trees)
         return _link_inv_dist(self._dist, F,
                               udf=getattr(self, "_udf_dist", None))
+
+    def _link_f0(self):
+        """The K initial margins on the device, placed ONCE a model: a host
+        array handed to the jitted link is a transfer of its own ahead of
+        every frame (as `engine._walk_tables` has it of the trees). Under a
+        trace the placed copy is a constant of that trace and is not kept."""
+        hit = self.__dict__.get("_f0_placed")
+        if hit is not None and hit[0] is self._f0:
+            return hit[1]
+        f0 = jnp.asarray(self._f0, jnp.float32)
+        if not isinstance(f0, jax.core.Tracer):
+            self._f0_placed = (self._f0, f0)
+        return f0
 
     def _contrib_scale_bias(self):
         return float(self.params["learn_rate"]), float(self._f0)
@@ -947,6 +986,13 @@ def _grad_hess(dist, F, y, udf=None):
     if dist == "laplace":
         return jnp.sign(y - F), jnp.ones_like(F)
     raise NotImplementedError(f"GBM distribution {dist}")
+
+
+@functools.partial(jax.jit, static_argnames=("lr",))
+def _class_link(sums, f0, *, lr):
+    """A K-class model's probabilities from the walk's (n, K) sums: the
+    margins and their softmax in ONE program."""
+    return jax.nn.softmax(f0[None, :] + lr * sums, axis=1)
 
 
 def _link_inv_dist(dist, F, udf=None):

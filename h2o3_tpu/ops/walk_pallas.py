@@ -35,6 +35,16 @@ into x > thr (a slot that splits a set holds thr = +inf). The row tile
 keeps the one-hot to HOT_BYTES (`hot_rows`); nothing of (rows x level
 rows) size is ever an array in HBM.
 
+A K-class ensemble (`classes`: the class variant, counted and named
+`walk_dense_tile_classes`, with sets `walk_dense_tile_sets_classes`; an
+ensemble of one output gets no operand, scratch or instruction of it): the
+accumulator is K rows deep (filled up to whole sublane tiles), each step's
+trees' classes ride in SMEM beside their weights, and a tree's w * v is
+added to ITS class's row, tree by tree in tree order — a class's sum has
+the terms and the order of that class's own walk, while the tile's byte
+planes and level one-hot are made once for all K classes and the classes'
+trees fill the node blocks together.
+
 `engine._walk_dense_xla` is the twin (the CPU's body and the test oracle);
 the two agree with `engine._walk_gather` bit for bit.
 """
@@ -130,11 +140,12 @@ def _level_one_hot(xt_ref, hot, cats):
 
 
 def _walk_kernel(tw_ref, xt_ref, sel_ref, tbl_ref, paths_ref, *rest,
-                 levels, trees, chunk, cats):
-    if cats:
-        set_ref, out_ref, planes, acc, hot = rest
-    else:
-        out_ref, planes, acc = rest
+                 levels, trees, chunk, cats, classes):
+    rest = list(rest)
+    set_ref = rest.pop(0) if cats else None
+    cls_ref = rest.pop(0) if classes else None
+    out_ref, planes, acc, *hot = rest
+    hot = hot[0] if cats else None
     step = pl.program_id(1)
     Cp = xt_ref.shape[0]
     top = min(levels, PATH_LEVELS)
@@ -217,6 +228,15 @@ def _walk_kernel(tw_ref, xt_ref, sel_ref, tbl_ref, paths_ref, *rest,
 
             v = [jax.lax.fori_loop(0, (1 << levels) // BLOCK, leaf,
                                    jnp.zeros((1, chunk), jnp.float32))]
+        if classes:
+            # tree by tree, each into its class's row: a dynamic-sublane
+            # read-add-write (a select over the K rows instead read the same
+            # 107.0 ms a frame of 2,449,215 x 41 at 230 trees: PERF.md §6, PR 36)
+            for g in range(trees):
+                row = pl.ds(cls_ref[step, g], 1)
+                acc[row, at_rows] = acc[row, at_rows] \
+                    + tw_ref[step, g] * v[g]
+            return carry
         a = acc[:, at_rows]
         for g in range(trees):      # tree by tree, in tree order
             a = a + tw_ref[step, g] * v[g]
@@ -231,16 +251,19 @@ def _walk_kernel(tw_ref, xt_ref, sel_ref, tbl_ref, paths_ref, *rest,
 
 
 def walk_dense_tile(X, sel, thr, nal, leafv, tws, paths, *, levels, cats=(),
-                    sets=None):
+                    sets=None, classes=None):
     """The steps of `engine._perfect_tree` over X (n, C): (n,) f32 =
     Σ_t w[t] · value[t, leaf_t(row)], tree by tree. `cats`, `sets`
     (`engine._cat_layout`, `_perfect_sets`): the categorical columns' level
     rows and the nodes' go-right sets (U, K', S) with the slots that split
-    one (U, S) — int8 {0, 1}; the caller sees that `hot_rows(K')` is not 0."""
+    one (U, S) — int8 {0, 1}; the caller sees that `hot_rows(K')` is not 0.
+    `classes`: (each step's trees' classes (U, G) int32, K) of a K-class
+    ensemble — (n, K) out, column c the sum of class c's trees."""
     n, C = X.shape
     U, G = tws.shape
     blocks = thr.shape[1] // BLOCK
-    name = "walk_dense_tile_sets" if cats else "walk_dense_tile"
+    name = "walk_dense_tile" + ("_sets" if cats else "") \
+        + ("_classes" if classes else "")
     KERNEL_TRACES.inc(kernel=name, L=str(1 << levels))
     Cp = -(-C // 16) * 16       # a bf16 tile's 16 sublanes
     # (U, C, S) one-hot -> (U, blocks, 128, 2 Cp): [low byte | high byte]
@@ -262,6 +285,11 @@ def walk_dense_tile(X, sel, thr, nal, leafv, tws, paths, *, levels, cats=(),
                               lambda i, u: (u, 0, 0, 0))]
         rows = min(rows, hot_rows(Kp, chunk))
         scratch = [pltpu.VMEM((Kp, rows), jnp.int8)]
+    deep = 1
+    if classes:
+        operands += (classes[0],)
+        specs = specs + [pl.BlockSpec(memory_space=pltpu.SMEM)]
+        deep = -(-classes[1] // 8) * 8      # an f32 tile's 8 sublanes
     # a block's per-slot constants as columns: thr, a NaN's turn (±1), the
     # leaf value (of the bottom level's positions, laid as the slots are)
     tbl = jnp.stack([a.reshape(U, blocks, BLOCK) for a in
@@ -274,7 +302,7 @@ def walk_dense_tile(X, sel, thr, nal, leafv, tws, paths, *, levels, cats=(),
         XT = jnp.pad(XT, ((0, 0), (0, rows - n)))
     out = pl.pallas_call(
         functools.partial(_walk_kernel, levels=levels, trees=G, chunk=chunk,
-                          cats=cats),
+                          cats=cats, classes=bool(classes)),
         name=name,
         grid=(-(-n // rows), U),
         in_specs=[
@@ -285,12 +313,14 @@ def walk_dense_tile(X, sel, thr, nal, leafv, tws, paths, *, levels, cats=(),
             pl.BlockSpec((1, blocks, 8, BLOCK), lambda i, u: (u, 0, 0, 0)),
             pl.BlockSpec(pathsT.shape, lambda i, u: (0, 0, 0)),
         ] + specs,
-        out_specs=pl.BlockSpec((1, rows), lambda i, u: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, XT.shape[1]), jnp.float32),
+        out_specs=pl.BlockSpec((deep, rows), lambda i, u: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((deep, XT.shape[1]), jnp.float32),
         scratch_shapes=[pltpu.VMEM((4 * Cp, rows), jnp.bfloat16),
-                        pltpu.VMEM((1, rows), jnp.float32)] + scratch,
+                        pltpu.VMEM((deep, rows), jnp.float32)] + scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=96 << 20),
     )(tws, XT, sel, tbl, pathsT, *operands)
+    if classes:
+        return out[:classes[1], :n].T
     return out[0, :n]

@@ -19,6 +19,11 @@ Also here: the scoring walk's dense body (engine._ensemble_walk) at the
 benchmark's frame, 2,750,000 x 28, for its two ensembles — the fused kernel
 `walk_dense_tile` (ops/walk_pallas.py) — on one chip and row-sharded over
 the host's four.
+
+And the KDD Cup 1999 configuration (benchmark/configs/gbm_kddcup99.json):
+a 23-class ensemble through the kernel's class variant
+(`walk_dense_tile_sets_classes`), the K-tree multinomial trainer, and the
+prediction planes of a 23-class frame.
 """
 
 import functools
@@ -420,3 +425,100 @@ def test_airline_programs_compile_for_v5e(name, topo, sds,
         assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
     elif name != "quantize_planes":
         assert "tpu_custom_call" in text
+
+
+# ---- the KDD Cup 1999 configuration (benchmark/configs/gbm_kddcup99.json):
+# 41 columns, three categorical (K' = 128 level rows), 23 classes ------------
+KDD_ROWS, KDD_COLS, KDD_CLASSES = 2_449_215, 41, 23
+KDD_LEVELS = (0, 3, 70, 11) + (0,) * 37
+
+# the class walk's shapes: (ntrees, depth, chips) — the cell's own (10
+# iterations x 23 classes: 58 blocks of four trees), two blocks a tree's
+# top, the cell's over the host's four chips
+WALK_CLASSES = {"walk_classes": (230, 5, 1), "walk_classes_d8": (46, 8, 1),
+                "walk_classes_4chips": (230, 5, 4)}
+
+
+@pytest.mark.parametrize("name", [*WALK_CLASSES, "gbm_multi_trainer",
+                                  "planes_24"])
+def test_kddcup99_programs_compile_for_v5e(name, topo, sds,
+                                           no_persistent_cache, monkeypatch):
+    """What a response of 23 classes adds to the programs: the scoring walk
+    with the accumulator a row a class (`walk_dense_tile_sets_classes`,
+    whose dynamic-sublane update Mosaic must accept), still
+    `jit__ensemble_walk`, no gather, no collective, nothing of (rows x
+    slots) size outside the kernel — its (rows, 23) output is all it
+    leaves; the K-tree trainer `gbm_multi_chunk_trainer` as
+    `_fit_binned_multinomial` builds it (23 trees an iteration in one
+    program, the Pallas histogram kernels inside); and the one program
+    that makes a frame's 24 prediction planes."""
+    cats = tuple((c, k) for c, k in enumerate(KDD_LEVELS) if k)
+    if name == "planes_24":
+        from h2o3_tpu.models.model import _prediction_planes
+        compiled = jax.jit(_prediction_planes(KDD_ROWS, "i8")).lower(
+            sds((KDD_ROWS + 1, KDD_CLASSES), jnp.float32)).compile()
+        planes = compiled.out_info
+        assert [[str(p.dtype) for p in col] for col in planes] == \
+            [["int8", "uint8"]] + [["float32", "uint8"]] * KDD_CLASSES
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+        return
+    if name == "gbm_multi_trainer":
+        monkeypatch.setattr(HP, "use_pallas", lambda: True)
+        levels = np.array(KDD_LEVELS)
+        spec = BN.make_bins(
+            np.random.default_rng(0).normal(size=(4096, KDD_COLS))
+            .astype(np.float32), levels > 0, 70, cat_levels=levels)
+        assert (spec.c_pad, spec.n_bins, spec.planes) == (48, 128, None)
+        grower = BN.BinnedGrower(spec, max_depth=5, min_rows=10.0,
+                                 min_split_improvement=1e-5)
+        n_pad = grower.layout(KDD_ROWS)
+        trainer = BN.gbm_multi_chunk_trainer(
+            grower, KDD_ROWS, n_classes=KDD_CLASSES, eta=0.1,
+            sample_rate=1.0, mtries=0, k_iters=5)
+        row = sds((n_pad,), jnp.float32)
+        compiled = trainer.lower(
+            sds((HP.packed_words(spec.c_pad), n_pad), jnp.int32), row, row,
+            sds((n_pad, KDD_CLASSES), jnp.float32),
+            sds((2,), jnp.uint32)).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        # the margins, residuals and one-hot labels of 23 classes
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+        return
+    ntrees, depth, chips = WALK_CLASSES[name]
+    nodes = 2 ** (depth + 1) - 1
+    assert E._walk_path(depth, KDD_COLS, sum(k for _, k in cats)) == "dense"
+    assert WP.level_rows(cats) == 128
+    # the dispatcher asks the default backend, which is the CPU here
+    monkeypatch.setattr(WP, "use_pallas", lambda: True)
+    mesh, rows = None, KDD_ROWS + 1
+    if chips == 4:
+        mesh = Mesh(np.array(topo.devices).reshape(-1, 1),
+                    (MESH.ROWS, MESH.MODEL))
+        rows = MESH.Cloud(mesh).padded_rows(KDD_ROWS)
+
+    def of(shape, dt, spec):
+        return sds(shape, dt) if mesh is None else jax.ShapeDtypeStruct(
+            shape, dt, sharding=NamedSharding(mesh, spec))
+    tbl = [of((ntrees, nodes), d, P()) for d in
+           (jnp.int32, jnp.float32, jnp.bool_, jnp.float32)]
+    before = HP.kernel_traces()
+    compiled = E._ensemble_walk.__wrapped__.lower(
+        of((rows, KDD_COLS), jnp.float32, P(MESH.ROWS)), *tbl,
+        of((ntrees,), jnp.float32, P()),
+        of((ntrees, nodes, 3), jnp.uint32, P()),
+        of((KDD_COLS,), jnp.bool_, P()), of((ntrees,), jnp.int32, P()),
+        depth=depth, has_cat=True, cats=cats, mesh=mesh,
+        classes=KDD_CLASSES).compile()
+    picked = {k for k, v in HP.kernel_traces().items()
+              if v > before.get(k, 0)}
+    assert picked == {("walk_dense_tile_sets_classes", 1 << depth)}
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit__ensemble_walk")
+    assert "tpu_custom_call" in text
+    assert not re.search(r" gather\(", text)
+    for op in ("all-gather", "all-reduce", "all-to-all",
+               "collective-permute"):
+        assert op not in text, op       # each chip walks its own rows
+    assert compiled.out_info.shape == (rows, KDD_CLASSES)
+    # the kernel's (24, rows) output and its transpose: 2 x 235 MB a chip
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20

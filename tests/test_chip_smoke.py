@@ -74,6 +74,19 @@ def test_walk_sets_phase(shape, rows):
     assert {d for _, d in cs.WALK_SET_SHAPES} == {5, 8, 9}
 
 
+@pytest.mark.parametrize("shape,rows", [((2, 5), 6_000), ((1, 8), 8_000),
+                                        ((1, 9), 8_000)])
+def test_walk_classes_phase(shape, rows):
+    rec = cs.phase_walk_classes(rows, cs.WALK_CLASS_LEVELS, shape, 5, SEED,
+                                on_chip=False)
+    assert rec["level_rows"] == sum(cs.WALK_CLASS_LEVELS) == 84
+    assert (rec["cols"], rec["classes"], rec["ntrees"]) == (41, 5, 5 * shape[0])
+    assert rec["distinct_sums"] > 100 and rec["nonfinite_cells"] > 0
+    assert rec["pallas_kernels_traced"] == []   # the XLA twin ran here
+    assert cs.WALK_CLASSES == 23 and cs.WALK_CLASS_SHAPES[0] == (10, 5)
+    assert {d for _, d in cs.WALK_CLASS_SHAPES} == {5, 8, 9}
+
+
 def test_train_phase(trained):
     rec = trained[0]
     assert rec["train_auc"] > cs.AUC_MIN

@@ -153,6 +153,31 @@ def test_tree_and_artifact_routes(server, small_model):
     assert b"class" in pojo and b"score0" in pojo
 
 
+def test_a_three_class_models_trees_by_class_and_its_artifacts(server):
+    """A K-class GBM holds ONE ensemble; the tree route and the artifact
+    writers read a class at a time through its host view (`_trees_k`)."""
+    rng = np.random.default_rng(1)
+    X = rng.normal(0, 1, (300, 3))
+    y = np.digitize(X[:, 0] + 0.3 * X[:, 1], [-0.5, 0.5])
+    cols = {f"x{j}": X[:, j] for j in range(3)}
+    cols["y"] = np.array(["a", "b", "c"], object)[y]
+    Frame.from_dict(cols, key="ext_train3")
+    r = _post(server, "/3/ModelBuilders/gbm", training_frame="ext_train3",
+              response_column="y", ntrees="2", max_depth="3",
+              model_id="ext_gbm3", seed="7")
+    assert _wait(server, r["job"]["key"])["status"] == "DONE"
+    from h2o3_tpu.core.kvstore import DKV
+    m = DKV.get("ext_gbm3")
+    assert m._trees.ntrees == 6 and list(m._trees.tree_class) == [0, 1, 2] * 2
+    for ci, name in enumerate("abc"):
+        t = _get(server, f"/3/Tree?model=ext_gbm3&tree_number=1"
+                         f"&tree_class={name}")
+        want = np.asarray(m._trees.value)[3 + ci]     # iteration 1, class ci
+        assert np.allclose(t["predictions"], want)
+    assert _get_raw(server, "/3/Models/ext_gbm3/mojo")[:2] == b"PK"
+    assert b"score0" in _get_raw(server, "/3/Models.java/ext_gbm3")
+
+
 def test_typeahead_sessions_dkv(server, tmp_path):
     (tmp_path / "data_a.csv").write_text("x\n1\n")
     (tmp_path / "data_b.csv").write_text("x\n2\n")
