@@ -11,11 +11,13 @@ cardinality in the bounded ring.
 R011 therefore enforces, package-wide:
   * every `timeline.span("...")` name is DECLARED at exactly one call
     site (pass-through wrappers that forward a name parameter are
-    exempt, like R005's registry helpers);
+    exempt, like R005's registry helpers); so is every name given to
+    `SPANS.add_finished("...", ...)`, the ring's way in for a span that
+    ended elsewhere (obs/metrics' jax build spans);
   * declarations use literal names — a plain string, or a conditional
-    expression whose arms are both literals (the scorer's
-    `"scorer.warm_hit" if warm else "scorer.compile"` shape, censused as
-    two names);
+    expression whose arms are literals or such expressions again (the
+    scorer's `"scorer.warm_hit" if warm else "scorer.compile"` shape,
+    censused as two names);
   * the census of what passed is committed as `h2o3_tpu/obs/SPANS.md`
     (`python -m h2o3_tpu.analysis --write-census`) so a span rename
     shows up in review as a census diff, not as a silently broken trace
@@ -56,6 +58,8 @@ def _is_span_call(node: ast.Call, local_aliases: set) -> bool:
     fn = node.func
     if isinstance(fn, ast.Name):
         return fn.id in local_aliases
+    if isinstance(fn, ast.Attribute) and fn.attr == "add_finished":
+        return True
     if isinstance(fn, ast.Attribute) and fn.attr == "span" \
             and isinstance(fn.value, ast.Name):
         return fn.value.id in _RECEIVER_ALIASES
@@ -64,16 +68,14 @@ def _is_span_call(node: ast.Call, local_aliases: set) -> bool:
 
 def _literal_names(first: ast.AST):
     """The span name(s) a literal first argument declares: a constant
-    string, or an IfExp whose two arms are both constant strings.
+    string, or an IfExp whose two arms are each literal in this sense.
     Returns None when the argument is not literal."""
     if isinstance(first, ast.Constant) and isinstance(first.value, str):
         return [first.value]
-    if isinstance(first, ast.IfExp) \
-            and isinstance(first.body, ast.Constant) \
-            and isinstance(first.body.value, str) \
-            and isinstance(first.orelse, ast.Constant) \
-            and isinstance(first.orelse.value, str):
-        return [first.body.value, first.orelse.value]
+    if isinstance(first, ast.IfExp):
+        body, orelse = _literal_names(first.body), _literal_names(first.orelse)
+        if body is not None and orelse is not None:
+            return body + orelse
     return None
 
 

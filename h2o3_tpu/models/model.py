@@ -51,6 +51,11 @@ _PREDICT_ROWS = _om.counter(
     "h2o3_predict_rows_total",
     "rows scored by completed Model.predict() calls, by algorithm and "
     "scoring path")
+_PUBLISH_SECONDS = _om.counter(
+    "h2o3_model_publish_seconds_total",
+    "seconds of train()'s model.publish step, after the job: the drift "
+    "baseline (span modelmon.baseline), the DKV put and the optional "
+    "serving pre-warm")
 # how often the prediction frame is built without a host round trip
 _FRAME_COLUMNS = _om.counter(
     "h2o3_predict_frame_columns_total",
@@ -525,15 +530,17 @@ class ModelBase:
         # profile must never fail the train)
         from h2o3_tpu.obs import modelmon as _modelmon
         from h2o3_tpu import serving
-        with _span("model.publish", model=self.key):
+        with _span("model.publish", model=self.key) as sp:
             self._note_published()
-            _modelmon.install_baseline(self, frame)
+            with _span("modelmon.baseline", model=self.key):
+                _modelmon.install_baseline(self, frame)
             DKV.put(self.key, self)
             # optional serving pre-warm on publish (H2O3_SCORER_PREWARM=1):
             # compile the most common row bucket in the background so the
             # first real request warm-hits instead of paying the compile
             if serving.prewarm_enabled():
                 serving.prewarm(self)
+        _PUBLISH_SECONDS.inc(sp.t_end - sp.t_start, algo=self.algo)
         return self
 
     def _note_published(self):

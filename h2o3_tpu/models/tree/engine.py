@@ -49,13 +49,6 @@ from h2o3_tpu.ops import walk_pallas as _wp
 # and /metrics read the same counter (per-ensemble rate = Δcounter/Δt)
 ROW_TREES = _om.counter("h2o3_gbm_row_trees_total",
                         "rows x trees processed by the tree engines")
-# which body of the scoring walk a predict_ensemble call took (a call made
-# under an outer jit counts once, when that program is traced)
-WALKS = _om.counter(
-    "h2o3_tree_walk_total",
-    "predict_ensemble calls by the scoring walk's body: path=dense (every "
-    "node of a level, no per-row index; block = {trees a 128-slot node "
-    "block}x{slots a tree}) or gather (deep trees, or too many level rows)")
 
 # Dense-matmul histogram path is used while (leaves × 3 stats) stays MXU-sized.
 # Measured on v5e: the one-hot matmul beats segment-sum scatter ~3× even at
@@ -541,7 +534,7 @@ def _block_regime(depth: int):
 
 
 def _block_label(depth: int) -> str:
-    """`h2o3_tree_walk_total`'s `block`: "{trees a block}x{slots a tree}" of
+    """The `predict.tables` span's `block`: "{trees a block}x{slots a tree}" of
     the path-matched levels — 4x32, 1x128, 0.5x256."""
     levels, trees, blocks = _block_regime(depth)
     per = trees if blocks == 1 else 1 / blocks
@@ -1022,18 +1015,21 @@ def predict_ensemble(X, trees: TreeArrays, weights=None):
     level id (hex/genmodel GenModel.bitSetContains analog), matched on the
     MXU — and deep trees take a fixed-depth gather walk per tree
     (`_walk_gather`). The two agree bit for bit; `_walk_path` picks from
-    (depth, columns, level rows) and h2o3_tree_walk_total counts it."""
+    (depth, columns, level rows) and the `predict.tables` span names it."""
     # the host's share before the walk is enqueued: the ensemble's tables
     # onto the device (inside `predict.dispatch` on the large-frame path);
     # after an ensemble's first call a look-up (`_walk_tables`)
-    with _span("predict.tables"):
+    # which body the walk takes rides on the span: `path` dense | gather,
+    # `block` {trees a 128-slot node block}x{slots a tree} of a dense walk
+    # (a call made under an outer jit is traced once, so spanned once)
+    with _span("predict.tables") as sp:
         (col, thr, nal, val, tw, catbits, iscat, *cls), cats = \
             _walk_tables(trees, X.shape[1])
         if weights is not None:
             tw = jnp.asarray(weights, jnp.float32)
-    path = _walk_path(trees.depth, X.shape[1], sum(k for _, k in cats))
-    WALKS.inc(path=path,
-              block=_block_label(trees.depth) if path == "dense" else "")
+        path = _walk_path(trees.depth, X.shape[1], sum(k for _, k in cats))
+        sp.attrs.update(path=path, block=_block_label(trees.depth)
+                        if path == "dense" else "")
     # h2o3-ok: R025 tree_class is host numpy model metadata (the pytree's aux, never a tracer)
     of_classes = dict(classes=trees.n_classes) if cls else {}
     return _ensemble_walk(X, col, thr, nal, val, tw, catbits, iscat, *cls,

@@ -75,6 +75,11 @@ class Counter(_Metric):
         with self._lock:
             return self._series.get(_label_key(labels), 0.0)
 
+    def series(self) -> list:
+        """[(labels_dict, value)] for every live series."""
+        with self._lock:
+            return [(dict(k), v) for k, v in sorted(self._series.items())]
+
     def remove(self, **labels):
         """Drop one label series — the per-entity hygiene discipline
         (see Gauge.remove): a deleted model's counters must leave
@@ -539,19 +544,35 @@ def _dkv_series():
 
 _JAX_LISTENERS_INSTALLED = False
 
+# jax's three stages of building a program (jax/_src/dispatch.py
+# log_elapsed_time): a scalar event as one starts, a time span as it ends
+_BUILD_STAGES = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+                 "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+                 "/jax/core/compile/backend_compile_duration": "compile"}
+# what a build is booked to (`within`): the nearest of these open spans
+_BUILD_OWNERS = ("job.run", "model.publish", "predict")
+
 
 def _install_jax_listeners():
-    """Count XLA compile-cache traffic via jax.monitoring events. Safe to
-    call before the backend initializes (listener registration imports jax
-    but touches no devices)."""
+    """Count XLA compile-cache traffic via jax.monitoring events, and book
+    every OUTERMOST program build of a thread (a jaxpr trace, a lowering,
+    a backend compile or cache load) as one `xla.*` span in the timeline
+    ring, parented to the span open at its start, plus its seconds in
+    h2o3_xla_build_seconds_total{stage, within}. A build inside another
+    (the jnp ops traced inside a jit's trace, the traces a lowering rule
+    makes) is part of the outer one, so no second is counted twice. Safe
+    to call before the backend initializes (listener registration imports
+    jax but touches no devices)."""
     global _JAX_LISTENERS_INSTALLED
     if _JAX_LISTENERS_INSTALLED:
         return
     _JAX_LISTENERS_INSTALLED = True
     try:
+        import jax as _jax
         import jax.monitoring as _mon
     except Exception:   # noqa: BLE001 — no jax, no compile metrics
         return
+    from h2o3_tpu.obs.timeline import SPANS
     hits = counter("h2o3_xla_compile_cache_hits_total",
                    "persistent XLA compilation cache hits")
     misses = counter("h2o3_xla_compile_cache_misses_total",
@@ -562,10 +583,26 @@ def _install_jax_listeners():
     compile_secs = counter("h2o3_xla_compile_seconds_total",
                            "cumulative wall time spent in XLA backend "
                            "compilation")
+    build_secs = counter("h2o3_xla_build_seconds_total",
+                         "seconds of outermost program builds (nested ones "
+                         "are part of theirs) by stage (trace, lower or "
+                         "compile, the last a load from the persistent "
+                         "cache too) and by what paid for them: within = "
+                         "the nearest open job.run, model.publish or "
+                         "predict span, else other")
+    tls = threading.local()     # depth, and the outermost build's owner
 
     def _on_event(event: str, **kw):
-        if event == "/jax/compilation_cache/cache_hits":
+        # an xla.compile span's `cache`: hit (loaded), miss (looked up in a
+        # cache directory and compiled; jax writes it back only past its min
+        # compile time and entry size, and only such writes count as
+        # cache_misses), off (no directory)
+        if event == "/jax/compilation_cache/compile_requests_use_cache":
+            tls.cache = "miss" if _jax.config.jax_compilation_cache_dir \
+                else "off"
+        elif event == "/jax/compilation_cache/cache_hits":
             hits.inc()
+            tls.cache = "hit"
         elif event == "/jax/compilation_cache/cache_misses":
             misses.inc()
 
@@ -573,10 +610,44 @@ def _install_jax_listeners():
         if event == "/jax/core/compile/backend_compile_duration":
             compiles.inc()
             compile_secs.inc(max(duration, 0.0))
+        elif event == "/jax/compilation_cache/cache_retrieval_time_sec":
+            tls.cache_s = duration
+
+    def _on_start(event: str, value, **kw):
+        if event not in _BUILD_STAGES:
+            return
+        depth = getattr(tls, "depth", 0)
+        tls.depth = depth + 1
+        if depth == 0:
+            open_ = SPANS.open_spans()
+            tls.parent = open_[-1].span_id if open_ else 0
+            tls.within = next((sp.name for sp in reversed(open_)
+                               if sp.name in _BUILD_OWNERS), "other")
+            tls.cache, tls.cache_s = "off", None
+
+    def _on_end(event: str, start: float, end: float, **kw):
+        stage = _BUILD_STAGES.get(event)
+        depth = getattr(tls, "depth", 0)
+        if stage is None or depth == 0:   # not a build, or begun unseen
+            return
+        tls.depth = depth - 1
+        if depth > 1:
+            return
+        build_secs.inc(max(end - start, 0.0), stage=stage, within=tls.within)
+        attrs = {"fun": str(kw.get("fun_name", ""))}
+        if stage == "compile":
+            attrs["cache"] = tls.cache
+            if tls.cache_s is not None:
+                attrs["cache_s"] = tls.cache_s
+        SPANS.add_finished("xla.trace" if stage == "trace" else
+                           "xla.lower" if stage == "lower" else
+                           "xla.compile", start, end, tls.parent, **attrs)
 
     try:
         _mon.register_event_listener(_on_event)
         _mon.register_event_duration_secs_listener(_on_duration)
+        _mon.register_scalar_listener(_on_start)
+        _mon.register_event_time_span_listener(_on_end)
     except Exception:   # noqa: BLE001
         pass
 

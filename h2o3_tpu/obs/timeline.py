@@ -140,6 +140,21 @@ class SpanTimeline:
         elif sp in st:           # mis-nested exit: unwind through it
             while st and st.pop() is not sp:
                 pass
+        self._keep(sp)
+
+    def add_finished(self, name: str, t_start: float, t_end: float,
+                     parent_id: int = 0, **attrs) -> Span:
+        """Keep a span that ended elsewhere, stamped on this ring's clock
+        (time.time()): obs/metrics books each outermost jax build so, from
+        jax's own start and end. It goes through the ring, drop counter
+        and recorder as end() does; it never enters the open stack."""
+        sp = Span(name=name, t_start=t_start, span_id=next(self._ids),
+                  parent_id=parent_id, t_end=t_end, host=host_id(),
+                  attrs=attrs, trace=_tracing.current())
+        self._keep(sp)
+        return sp
+
+    def _keep(self, sp: Span):
         with self._lock:
             # deque(maxlen) overflow is SILENT — count the span the
             # append is about to push out, so ring data loss is a signal
@@ -161,6 +176,10 @@ class SpanTimeline:
     def current(self) -> Span | None:
         st = self._stack()
         return st[-1] if st else None
+
+    def open_spans(self) -> tuple:
+        """This thread's open spans, outermost first."""
+        return tuple(self._stack())
 
     # ---- views ----------------------------------------------------------
     def snapshot(self, limit: int = 0) -> list:

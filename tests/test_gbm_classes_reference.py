@@ -85,13 +85,15 @@ def test_train_publishes_one_ensemble_iteration_major(trained):
 def test_predict_is_one_walk_and_one_link(trained):
     m, fr, X = trained
     Xd = m._dinfo.matrix(fr)
-    walks = E.WALKS.value(path="dense", block=E._block_label(4))
     SPANS.clear()
     P = np.asarray(m._score_matrix(Xd))[:N]
-    assert E.WALKS.value(path="dense", block=E._block_label(4)) == walks + 1
-    names = [s["name"] for s in SPANS.snapshot()]
+    spans = SPANS.snapshot()
+    names = [s["name"] for s in spans]
     assert names.count("predict.link") == 1
     assert names.count("predict.tables") == 1
+    t = next(s for s in spans if s["name"] == "predict.tables")
+    assert (t["attrs"]["path"], t["attrs"]["block"]) == \
+        ("dense", E._block_label(4))
     want = ref.predict_proba(X, check.read_model(m))
     assert np.abs(P - want).max() <= 2e-6
     # the initial margins are placed once a model

@@ -358,8 +358,9 @@ def test_r011_detects_nonliteral_span_name():
 
 
 def test_r011_clean_shapes():
-    """Pass-through wrappers, conditional literals, and receiver-style
-    calls are all legitimate; wrapper call sites are censused."""
+    """Pass-through wrappers, conditional literals (nested too), receiver-
+    style calls and the ring's add_finished are all legitimate; wrapper
+    call sites are censused."""
     from h2o3_tpu.analysis import rules_spans
     src = (
         "from h2o3_tpu.obs import timeline\n"
@@ -372,14 +373,18 @@ def test_r011_clean_shapes():
         "        pass\n"
         "    with timeline.span('fixture.receiver'):\n"
         "        pass\n"
-        "    return wrapper('fixture.wrapped', fn)\n")
+        "    return wrapper('fixture.wrapped', fn)\n"
+        "def b(SPANS, st):\n"
+        "    SPANS.add_finished('fixture.t' if st == 't' else 'fixture.l'\n"
+        "                       if st == 'l' else 'fixture.c', 0.0, 1.0)\n")
     mods = [engine.Module("h2o3_tpu/fx.py", "h2o3_tpu/fx.py", src,
                           __import__('ast').parse(src))]
     mods[0].lines = src.splitlines()
     decls, findings = rules_spans.collect(mods)
     assert not findings and not rules_spans.check(mods)
     assert set(decls) == {"fixture.warm", "fixture.cold",
-                          "fixture.receiver", "fixture.wrapped"}
+                          "fixture.receiver", "fixture.wrapped",
+                          "fixture.t", "fixture.l", "fixture.c"}
 
 
 def test_r011_relaxed_for_tests():

@@ -22,7 +22,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from h2o3_tpu.models.tree import engine as E
 from h2o3_tpu.ops import walk_pallas as WP
-from test_tree_walk_dense import NO_BITS, _take_the_kernel
+from test_tree_walk_dense import NO_BITS, _take_the_kernel, walked
 from test_tree_walk_dense import _ensemble as _numeric, _rows as _numeric_rows
 from test_tree_walk_sets import _ensemble as _with_sets, _rows as _set_rows
 
@@ -148,9 +148,8 @@ def test_predict_ensemble_scores_the_classes_in_one_dispatch(sets):
     """The entry point: (n, K) from ONE walk, counted once; given weights
     go beside the placed tables; the classes' table is placed with them."""
     ta, X, tw, want = _same(5, 3, 5, 517, 11, sets, "xla")
-    before = E.WALKS.value(path="dense", block=E._block_label(5))
-    got = E.predict_ensemble(jnp.asarray(X), ta, weights=tw)
-    assert E.WALKS.value(path="dense", block=E._block_label(5)) == before + 1
+    got, walks = walked(jnp.asarray(X), ta, weights=tw)
+    assert walks == [("dense", E._block_label(5))]
     assert np.array_equal(np.asarray(got), want)
     tables, _ = E._walk_tables(ta, C)
     assert len(tables) == 8 and isinstance(tables[7], jax.Array)
@@ -163,9 +162,9 @@ def test_predict_ensemble_scores_the_classes_in_one_dispatch(sets):
 def test_a_deep_class_ensemble_takes_the_gather_body():
     ta, X, tw = _model(17, 1, 3, 64, 5, False)
     assert E._walk_path(17, C) == "gather"
-    before = E.WALKS.value(path="gather", block="")
-    got = np.asarray(E.predict_ensemble(jnp.asarray(X), ta, weights=tw))
-    assert E.WALKS.value(path="gather", block="") == before + 1
+    got, walks = walked(jnp.asarray(X), ta, weights=tw)
+    got = np.asarray(got)
+    assert walks == [("gather", "")]
     assert np.array_equal(got, _k_walks(ta, X, tw))
 
 

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
 from h2o3_tpu.models.tree import engine as E
+from h2o3_tpu.obs.timeline import SPANS
 from h2o3_tpu.ops import walk_pallas as WP
 
 C = 6
@@ -193,12 +194,16 @@ def test_a_row_sharded_frame_stays_sharded(cloud8, monkeypatch):
     assert np.array_equal(np.asarray(got), want)
 
 
-def _walks(block):
-    return {"dense": E.WALKS.value(path="dense", block=block),
-            "gather": E.WALKS.value(path="gather", block="")}
+def walked(X, ta, **kw):
+    """predict_ensemble's answer and the walks it took: (path, block) of
+    each `predict.tables` span the call left in the ring."""
+    SPANS.clear()
+    out = E.predict_ensemble(X, ta, **kw)
+    return out, [(s["attrs"]["path"], s["attrs"]["block"])
+                 for s in SPANS.snapshot() if s["name"] == "predict.tables"]
 
 
-def test_the_shape_picks_the_body_and_the_counter_says_which():
+def test_the_shape_picks_the_body_and_the_span_says_which():
     rng = np.random.default_rng(5)
     X28 = jnp.asarray(rng.standard_normal((64, 28)).astype(np.float32))
 
@@ -220,13 +225,9 @@ def test_the_shape_picks_the_body_and_the_counter_says_which():
             # a categorical SET split no longer means gather: its 32 level
             # rows count as columns (tests/test_tree_walk_sets.py)
             (trees(5, cat=True), "dense", "4x32")):
-        before = _walks(block)
-        out = E.predict_ensemble(X28, ta)
+        out, walks = walked(X28, ta)
         assert out.shape == (64,)
-        after = _walks(block)
-        other = "gather" if path == "dense" else "dense"
-        assert after[path] == before[path] + 1, (ta.depth, path)
-        assert after[other] == before[other]
+        assert walks == [(path, block)], (ta.depth, path)
     assert E._walk_path(8, 28, False) == E._walk_path(5, 28, False) == "dense"
     assert E._walk_path(8, 28, 32) == "dense"
     # depth 14 at HIGGS width is the deepest shape measured on the chip

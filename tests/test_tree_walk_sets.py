@@ -25,7 +25,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from h2o3_tpu.models.tree import engine as E
 from h2o3_tpu.ops import walk_pallas as WP
-from test_tree_walk_dense import _take_the_kernel
+from test_tree_walk_dense import _take_the_kernel, walked
 
 C = 6
 IS_CAT = np.array([True, False, True, False, False, True])
@@ -122,12 +122,10 @@ def test_dense_set_walk_is_the_gather_walk_bit_for_bit(depth, widest):
                                   1000 * depth + widest)
     assert dict(cats) == {0: widest, 2: 7, 5: 31}
     assert np.unique(want).size > 1               # rows went several ways
-    # the entry point takes the dense body and counts it so
-    before = E.WALKS.value(path="dense", block=E._block_label(depth))
-    got = np.asarray(E.predict_ensemble(jnp.asarray(X), ta, weights=tw))
-    assert np.array_equal(got, want)
-    assert E.WALKS.value(path="dense", block=E._block_label(depth)) \
-        == before + 1
+    # the entry point takes the dense body and its span says so
+    got, walks = walked(jnp.asarray(X), ta, weights=tw)
+    assert np.array_equal(np.asarray(got), want)
+    assert walks == [("dense", E._block_label(depth))]
 
 
 @pytest.mark.parametrize("depth,widest", [(3, 40), (5, 300), (8, 5)])
@@ -320,9 +318,9 @@ def test_an_ensemble_over_the_bound_takes_the_gather_body():
     cats = E._cat_layout(ta, C)
     assert E._walk_path(9, C, sum(k for _, k in cats)) == "gather"
     X = _rows(rng, 300, levels)
-    before = E.WALKS.value(path="gather", block="")
-    got = np.asarray(E.predict_ensemble(jnp.asarray(X), ta, weights=tw))
-    assert E.WALKS.value(path="gather", block="") == before + 1
+    got, walks = walked(jnp.asarray(X), ta, weights=tw)
+    got = np.asarray(got)
+    assert walks == [("gather", "")]
     dense = np.asarray(E._walk_dense(
         *[jnp.asarray(a) for a in (X, col, thr, nal, val, tw, sets)],
         depth=9, cats=cats))
