@@ -23,7 +23,9 @@ depth 8, bernoulli; only the tree count is cut):
                accumulator a row a class) == 23 gather walks of the
                classes' own trees, bit for bit, at the KDD Cup 1999
                cell's shape (41 columns, 3 categorical; 10 x 23 trees of
-               depth 5) and at depths 8 and 9
+               depth 5) and at depths 8 and 9; then the frame's planes of
+               those sums out of ONE kernel, the softmax inside, == today's
+               link and planes programs, bit for bit
     ingest  -> seeded CSV through h2o3_tpu.import_file (native tokenizer)
     train   -> 11M x 28 Frame -> H2OGradientBoostingEstimator.train
     predict -> large-frame sharded path AND compiled-scorer fast path,
@@ -397,6 +399,40 @@ def phase_walk_sets(rows: int, levels, shape, seed: int,
             "time_rows": time_rows, "walk_ms": walk_ms}
 
 
+def _class_epilogue(sums, rng, on_chip: bool) -> dict:
+    """A K-class frame's planes out of ONE program, the softmax link inside
+    (`model._linked_planes`; on the chip the kernel `class_planes`), against
+    today's two programs (the link, `_prediction_planes`), bit for bit, on
+    the walk's own sums with three planted rows (all NaN, one class NaN, a
+    tie of the last two classes) and padding past the last three rows."""
+    import jax
+    import jax.numpy as jnp
+    from h2o3_tpu.models.model import (_link_probs, _linked_planes,
+                                       _prediction_planes)
+    from h2o3_tpu.ops import hist_pallas as HP
+    rows, K = sums.shape
+    n = rows - 3
+    sums = sums.at[1].set(jnp.nan).at[2, K - 1].set(jnp.nan)
+    sums = sums.at[3].set(-50.0).at[3, K - 2:].set(50.0)
+    f0 = np.log(rng.dirichlet(np.ones(K))).astype(np.float32)
+    f0[K - 1] = f0[K - 2]
+    f0 = jnp.asarray(f0)
+    traces0 = HP.kernel_traces().get(("class_planes", K), 0)
+    got = jax.jit(_linked_planes(n, "i8", LEARN_RATE, "softmax"))(sums, f0)
+    assert (HP.kernel_traces().get(("class_planes", K), 0) > traces0) \
+        == on_chip
+    want = jax.jit(_prediction_planes(n, "i8"))(
+        jax.jit(_link_probs(LEARN_RATE, "softmax"))(sums, f0))
+    for k, ((a, a_na), (b, b_na)) in enumerate(zip(got, want)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype and np.array_equal(a, b, equal_nan=True), \
+            (k, int((a != b).sum()))
+        assert np.array_equal(np.asarray(a_na), np.asarray(b_na)), k
+    lab = np.asarray(got[0][0])
+    assert (lab[1], lab[2], lab[3]) == (0, 0, K - 2), lab[:4]
+    return {"epilogue_planes": len(got)}
+
+
 def phase_walk_classes(rows: int, levels, shape, classes: int, seed: int,
                        on_chip: bool = True) -> dict:
     """A K-class ensemble through ONE walk (`TreeArrays.tree_class`: the
@@ -448,7 +484,9 @@ def phase_walk_classes(rows: int, levels, shape, classes: int, seed: int,
                     if v > traces0.get(k, 0))
     assert picked == ([("walk_dense_tile_sets_classes", 1 << depth)]
                       if on_chip else []), picked
+    epilogue = _class_epilogue(jnp.asarray(one), rng, on_chip)
     return {"rows": rows, "cols": C, "classes": classes, "ntrees": T,
+            **epilogue,
             "depth": depth, "block": E._block_label(depth),
             "pallas_kernels_traced": picked,
             "level_rows": sum(k for _, k in cats),
@@ -585,12 +623,14 @@ def _host_scores(model, X, idx, out_dir: str) -> np.ndarray:
 
 def _frame_parity(model, X, rows: int, seed: int) -> dict:
     """The large-frame predict()'s frame — planes one device program made
-    from the walk's own output, nothing fetched — against the frame
-    _prediction_frame builds on the HOST from the fetched copy of the
-    same scores: every column through Vec.to_numpy(), type and domain,
-    over rows with NaN and ±inf cells and a row count the padding does
-    not divide. Then the rows no model produces (an all-NaN score row, a
-    tie): the device's argmax must follow NumPy's rules on this chip."""
+    from the walk's own output and the link, nothing fetched — against
+    the frame _prediction_frame builds on the HOST from the fetched copy
+    of the same scores: every column through Vec.to_numpy(), type and
+    domain; and against _score_host's eager link within 1e-6 (its ulps
+    read off), over rows with NaN and ±inf cells and a row count the
+    padding does not divide. Then the rows no model produces (an all-NaN
+    score row, a tie): the device's argmax must follow NumPy's rules on
+    this chip."""
     import jax
     import jax.numpy as jnp
     import h2o3_tpu
@@ -625,12 +665,23 @@ def _frame_parity(model, X, rows: int, seed: int) -> dict:
     dev = model.predict(fr)
     t_warm = time.perf_counter() - t0
     assert made() == [made0[0] + 2, made0[1]], (made0, made())
-    scores = model._score_host(fr)
-    host = model._prediction_frame(scores, rows)
+    # the scores predict() was handed: the walk's sums with the link, whose
+    # planes were made in one program with it
+    handed = np.asarray(model._score_linkable(model._dinfo.matrix(fr)))
+    host = model._prediction_frame(handed, rows)
     assert made() == [made0[0] + 2, made0[1] + 1], (made0, made())
     same(dev, host)
     p1 = dev.vec("ps").to_numpy()
     assert np.isfinite(p1).all()        # a tree routes NaN/inf, never emits it
+    # _score_host's link is eager ops, the product rounded before the add:
+    # the same on a chip that does not contract them into a multiply-add
+    scores = model._score_host(fr)
+    ulps = 0.0
+    for k, name in enumerate(("pb", "ps")):
+        want = np.asarray(scores)[:rows, k]
+        gap = np.abs(dev.vec(name).to_numpy() - want)
+        assert gap.max() <= 1e-6, (name, float(gap.max()))
+        ulps = max(ulps, float((gap / np.spacing(want)).max()))
     planted = jnp.asarray(scores).at[1].set(jnp.nan).at[2].set(0.5) \
         .at[3, 1].set(jnp.nan)
     dev_p = model._prediction_frame(planted, rows)
@@ -644,7 +695,7 @@ def _frame_parity(model, X, rows: int, seed: int) -> dict:
     return {"rows": rows, "padded": int(fr.padded_len),
             "nonfinite_cells": int((~np.isfinite(Xp)).sum()),
             "columns_compared": 3, "planted_rows": 3,
-            "predict_warm_s": round(t_warm, 4)}
+            "ulps_vs_score_host": ulps, "predict_warm_s": round(t_warm, 4)}
 
 
 def phase_predict(model, fr, X, seed: int, out_dir: str,

@@ -62,6 +62,13 @@ _FRAME_COLUMNS = _om.counter(
     "prediction frames built, by algorithm and by where their columns were "
     "made: columns=device (planes from the device scores, one program) or "
     "host (float64 host columns, packed and put)")
+# how often the link and the planes are one program behind the walk
+_EPILOGUE = _om.counter(
+    "h2o3_predict_epilogue_total",
+    "device-built prediction frames, by algorithm and by how the scores "
+    "became planes: link=fused (the walk's sums through the link to the "
+    "planes in one program) or apart (the planes of scores a family's "
+    "_score_matrix made)")
 
 
 def _predict_root(fn):
@@ -96,18 +103,126 @@ def _prediction_planes(n: int, label: Optional[str]):
     with NaN as NA; rows >= n are NA."""
     def planes(out):
         padding = jnp.arange(out.shape[0]) >= n
-
-        def num(p):
-            p = p.astype(jnp.float32)
-            na = padding | jnp.isnan(p)
-            return jnp.where(na, 0.0, p), na.astype(jnp.uint8)
         if label is None:
-            return [num(out)]
+            return [_score_plane(out, padding)]
         lab = jnp.where(padding, 0, jnp.argmax(out, axis=1))
-        lab = lab.astype(jnp.int8 if label == "i8" else jnp.float32)
+        lab = lab.astype(_label_dtype(label))
         return [(lab, padding.astype(jnp.uint8))] \
-            + [num(out[:, k]) for k in range(out.shape[1])]
+            + [_score_plane(out[:, k], padding) for k in range(out.shape[1])]
     return planes
+
+
+def _score_plane(p, padding):
+    """A score's (data, mask): its own float32, NaN and padding NA."""
+    p = p.astype(jnp.float32)
+    na = padding | jnp.isnan(p)
+    return jnp.where(na, 0.0, p), na.astype(jnp.uint8)
+
+
+def _label_dtype(label: str):
+    return jnp.int8 if label == "i8" else jnp.float32
+
+
+class Margins:
+    """What a family that supplies its link hands the prediction frame
+    instead of probabilities: the walk's sums (rows,) or (rows, K), the
+    initial margins `f0` on the device, the learning rate and the link —
+    "logistic" (two classes), "softmax" (K), "exp" or "identity". The
+    frame's planes are then made by ONE program, `_linked_planes`.
+    `np.asarray(margins)` gives what the family's `_score_matrix` gives:
+    (rows, 2) / (rows, K) probabilities, else the (rows,) response."""
+
+    __slots__ = ("sums", "f0", "lr", "link")
+
+    def __init__(self, sums, f0, lr: float, link: str):
+        self.sums, self.f0, self.lr, self.link = sums, f0, lr, link
+
+    def probabilities(self):
+        """The scores as the family's `_score_matrix` gives them, on the
+        device: one program, the link of `_linked_planes`."""
+        from h2o3_tpu.parallel import mrtask as _mrt
+        probs = _mrt.cached_jit(_link_probs(self.lr, self.link))
+        return probs(self.sums, self.f0)
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.probabilities(), dtype)
+
+
+def link_span():
+    """Span `predict.link`, inside `predict.dispatch`: the host's dispatch
+    of a model's link program — on the large-frame path the one program
+    from the walk's sums to the planes."""
+    return _span("predict.link")
+
+
+def _inverse_link(link: str, F):
+    if link == "logistic":
+        return jax.nn.sigmoid(F)
+    if link == "softmax":
+        return jax.nn.softmax(F, axis=1)
+    if link == "exp":
+        return jnp.exp(F)
+    return F
+
+
+def probabilities(link: str, F):
+    """A margin's scores under its inverse link: (1 - p, p) of two
+    classes, the softmax of K, the response of a regression."""
+    p = _inverse_link(link, F)
+    return jnp.stack([1 - p, p], axis=1) if link == "logistic" else p
+
+
+def _link_probs(lr: float, link: str):
+    """(sums, f0) -> `Margins`' probabilities."""
+    def probs(sums, f0):
+        return probabilities(link, f0 + lr * sums)
+    return probs
+
+
+def _linked_planes(n: int, label: Optional[str], lr: float, link: str,
+                   mesh=None):
+    """The device program from a walk's sums to a prediction frame's
+    planes, what `_prediction_planes` makes of the probabilities, with the
+    link inside: F = f0 + lr x sums and its inverse link in the f32 ops of
+    the family's `_score_matrix`. Two classes never form the (rows, 2)
+    matrix: the planes are 1 - p and p, the label argmax's rule on them —
+    p > 1 - p, so a NaN or a tie gives 0. K classes on the TPU are ONE
+    kernel (`ops/planes_pallas.class_planes`) that writes every plane
+    from one read of the sums; `mesh`: where the sums' rows are sharded
+    (`engine._rows_mesh`), the kernel then runs a shard at a time."""
+    def linked_planes(sums, f0):
+        from h2o3_tpu.ops import planes_pallas
+        if link == "softmax" and planes_pallas.use_pallas():
+            lim = jnp.full(1, n, jnp.int32)
+            kw = dict(lr=lr, label_dtype=_label_dtype(label))
+            if mesh is None:
+                return planes_pallas.class_planes(sums, f0, lim, **kw)
+            return _sharded_class_planes(sums, f0, lim, mesh, **kw)
+        p = _inverse_link(link, f0 + lr * sums)
+        if link != "logistic":
+            return _prediction_planes(n, label)(p)
+        q = 1 - p
+        padding = jnp.arange(p.shape[0]) >= n
+        lab = ((p > q) & ~padding).astype(_label_dtype(label))
+        return [(lab, padding.astype(jnp.uint8)), _score_plane(q, padding),
+                _score_plane(p, padding)]
+    return linked_planes
+
+
+def _sharded_class_planes(sums, f0, lim, mesh, **kw):
+    """`class_planes` a shard at a time: each chip packs its own rows, its
+    padding counted from its first row's place in the frame."""
+    from jax.sharding import PartitionSpec as P
+    from h2o3_tpu.ops import planes_pallas
+
+    def shard(s, f0, lim):
+        start = jax.lax.axis_index(_mesh.ROWS) * s.shape[0]
+        return planes_pallas.class_planes(s, f0, lim - start, **kw)
+    K = sums.shape[1]
+    return jax.shard_map(
+        shard, mesh=mesh, in_specs=(P(_mesh.ROWS), P(), P()),
+        out_specs=[(P(_mesh.ROWS), P(_mesh.ROWS))] * (K + 1),
+        check_vma=False)(sums, f0, lim)
 
 
 # ===========================================================================
@@ -698,7 +813,9 @@ class ModelBase:
         compile cost amortizes over the batch, and come back as the device
         array, computed: `then(out)` — what the caller makes of the scores
         on the device — is enqueued behind the walk inside
-        `predict.dispatch`, and `predict.wait` blocks on its result. The
+        `predict.dispatch`, and `predict.wait` blocks on its result; `out`
+        is then what `_score_linkable` gives, the link left to `then`
+        where the family supplies it (`Margins`). The
         stages of that path are the children of the `predict` root span;
         the cache's own are scorer.warm_hit / scorer.compile."""
         from h2o3_tpu import serving
@@ -711,9 +828,10 @@ class ModelBase:
         with _span("predict.matrix"):       # adapt + stack + concat dispatch
             X = self._dinfo.matrix(test_data)
         with _span("predict.dispatch"):     # returns at enqueue
-            out = self._score_matrix(X)
-            if then is not None:
-                out = then(out)
+            if then is None:
+                out = self._score_matrix(X)
+            else:
+                out = then(self._score_linkable(X))
         with _span("predict.wait"):
             # the host blocked on the device: the caller gets results,
             # not promises (predict() returns a finished frame)
@@ -734,17 +852,25 @@ class ModelBase:
             sp.attrs["bytes"] = int(out.nbytes)
         return out
 
+    def _score_linkable(self, X):
+        """`_score_matrix` for a prediction frame made on the device: a
+        family that supplies its link returns the walk's sums with it
+        (`Margins`), and the frame's planes take the link into their one
+        program; the rest return their scores."""
+        return self._score_matrix(X)
+
     def _prediction_columns(self, out, n: int) -> list:
         """Prediction column assembly — the ONE place that maps raw
         scores to (name, values, domain-or-None) columns. Shared by
         predict(), _prediction_frame and the REST row-payload route, so
         the serving answers can never diverge. The columns are made where
-        the scores already live: a device array stays on the device
-        (`DevicePlanes` out of one program, nothing of frame size crosses
-        the host link); host scores become float64 host columns, every
-        p<level> sliced out of the ONE fetched copy."""
+        the scores already live: a device array or `Margins` stays on the
+        device (`DevicePlanes` out of one program, nothing of frame size
+        crosses the host link); host scores become float64 host columns,
+        every p<level> sliced out of the ONE fetched copy. Whatever `out`
+        is, `np.asarray(out)` gives the scores."""
         dom = self._dinfo.response_domain if self._is_classifier else None
-        if isinstance(out, jax.Array):
+        if isinstance(out, (jax.Array, Margins)):
             return self._device_columns(out, n, dom)
         if dom is not None:
             probs = np.asarray(out, np.float64)[:n]
@@ -755,18 +881,29 @@ class ModelBase:
             return cols
         return [("predict", np.asarray(out, np.float64)[:n], None)]
 
-    @staticmethod
-    def _device_columns(out: jax.Array, n: int, dom) -> list:
-        """The host columns' values as device planes, out of ONE program
-        (`_prediction_planes`). The planes come out row-sharded like every
-        Vec's; over a row-sharded `out` that takes no collective."""
+    def _device_columns(self, out, n: int, dom) -> list:
+        """The host columns' values as device planes, out of ONE program:
+        of `Margins` the link and the planes together (`_linked_planes`,
+        span `predict.link`), of scores `_prediction_planes`. The planes
+        come out row-sharded like every Vec's; over a row-sharded `out`
+        that takes no collective."""
+        from h2o3_tpu.models.tree.engine import _rows_mesh
         from h2o3_tpu.parallel import mrtask as _mrt
         c = _mesh.cloud()
         label = None if dom is None else "i8" if len(dom) <= 127 else "f32"
-        # cached_jit: the program's closure is (n, label), so every frame
-        # of one size replays one resident program
-        got = _mrt.cached_jit(_prediction_planes(n, label),
-                              out_shardings=c.rows_sharding(1))(out)
+        # cached_jit: the program's closure is (n, label[, lr, link,
+        # mesh]), so every frame of one size replays one resident program
+        if isinstance(out, Margins):
+            with link_span():
+                got = _mrt.cached_jit(
+                    _linked_planes(n, label, out.lr, out.link,
+                                   _rows_mesh(out.sums)),
+                    out_shardings=c.rows_sharding(1))(out.sums, out.f0)
+            _EPILOGUE.inc(algo=self.algo, link="fused")
+        else:
+            got = _mrt.cached_jit(_prediction_planes(n, label),
+                                  out_shardings=c.rows_sharding(1))(out)
+            _EPILOGUE.inc(algo=self.algo, link="apart")
         f32 = Codec("f32")
         if label is None:
             return [("predict", DevicePlanes(*got[0], f32, n), None)]

@@ -19,7 +19,6 @@ back from the router (val[heap]), so F-updates are gathers, not tree walks.
 from __future__ import annotations
 
 import contextlib
-import functools
 import math
 
 import jax
@@ -27,7 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from h2o3_tpu.core.frame import Frame
-from h2o3_tpu.models.model import ModelBase
+from h2o3_tpu.models.model import (Margins, ModelBase, link_span,
+                                   probabilities)
 from h2o3_tpu.models.tree import engine as E
 from h2o3_tpu.obs import metrics as _om
 from h2o3_tpu.obs.timeline import span as _span
@@ -934,19 +934,36 @@ class H2OGradientBoostingEstimator(SharedTreeEstimator):
 
     # ---- scoring ---------------------------------------------------------
     def _score_matrix(self, X):
-        lr = float(self.params["learn_rate"])
         if self._dist == "multinomial":
             # ONE walk sums every tree into its class's row, one link after
-            margins = E.predict_ensemble(X, self._trees)
-            with _span("predict.link"):
-                return _class_link(margins, self._link_f0(), lr=lr)
+            margins = self._margins(X)
+            with link_span():
+                return margins.probabilities()
+        lr = float(self.params["learn_rate"])
         F = self._f0 + lr * E.predict_ensemble(X, self._trees)
         return _link_inv_dist(self._dist, F,
                               udf=getattr(self, "_udf_dist", None))
 
+    def _margins(self, X):
+        """The walk's sums with the link (`model.Margins`)."""
+        return Margins(E.predict_ensemble(X, self._trees), self._link_f0(),
+                       float(self.params["learn_rate"]),
+                       _LINKS.get(self._dist, "identity"))
+
+    def _score_linkable(self, X):
+        """The walk's sums with the link: a prediction frame's planes take
+        the link into their one program. A user distribution's link is
+        user code, and a family that scores its own way (XGBoost's
+        `_score_matrix`) has its own: those stay `_score_matrix`'s."""
+        own = type(self)._score_matrix is \
+            H2OGradientBoostingEstimator._score_matrix
+        if not own or getattr(self, "_udf_dist", None) is not None:
+            return self._score_matrix(X)
+        return self._margins(X)
+
     def _link_f0(self):
-        """The K initial margins on the device, placed ONCE a model: a host
-        array handed to the jitted link is a transfer of its own ahead of
+        """The initial margin(s) on the device, placed ONCE a model: a host
+        value handed to the jitted link is a transfer of its own ahead of
         every frame (as `engine._walk_tables` has it of the trees). Under a
         trace the placed copy is a constant of that trace and is not kept."""
         hit = self.__dict__.get("_f0_placed")
@@ -992,19 +1009,14 @@ def _grad_hess(dist, F, y, udf=None):
     raise NotImplementedError(f"GBM distribution {dist}")
 
 
-@functools.partial(jax.jit, static_argnames=("lr",))
-def _class_link(sums, f0, *, lr):
-    """A K-class model's probabilities from the walk's (n, K) sums: the
-    margins and their softmax in ONE program."""
-    return jax.nn.softmax(f0[None, :] + lr * sums, axis=1)
+# a distribution's inverse link as `model.Margins` names it (what
+# `_link_inv_dist` applies); the others are the identity
+_LINKS = {"bernoulli": "logistic", "quasibinomial": "logistic",
+          "multinomial": "softmax", "poisson": "exp", "gamma": "exp",
+          "tweedie": "exp"}
 
 
 def _link_inv_dist(dist, F, udf=None):
     if udf is not None:
         return udf.link_inv(F)
-    if dist in ("bernoulli", "quasibinomial"):
-        p = jax.nn.sigmoid(F)
-        return jnp.stack([1 - p, p], axis=1)
-    if dist in ("poisson", "gamma", "tweedie"):
-        return jnp.exp(F)
-    return F
+    return probabilities(_LINKS.get(dist, "identity"), F)

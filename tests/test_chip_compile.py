@@ -315,6 +315,58 @@ def test_prediction_planes_compile_for_v5e_at_frame_size(chips, topo, sds,
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+@pytest.mark.parametrize("link,chips", [("logistic", 1), ("softmax", 1),
+                                        ("softmax", 4)])
+def test_linked_planes_compile_for_v5e_at_frame_size(link, chips, topo, sds,
+                                                     no_persistent_cache,
+                                                     monkeypatch):
+    """The one program from the walk's sums to a prediction frame's planes,
+    the link inside (`model._linked_planes`), at the cells' frames: the
+    HIGGS cells' (2,750,000,) sums of two classes, which never become a
+    (rows, 2) matrix, and the KDD cell's (2,449,215 + 1, 23) — on the TPU
+    ONE kernel (`class_planes`, which Mosaic must accept: a (23, rows) tile
+    of the sums' own bytes in, 48 one-dimensional planes out), and over
+    the host's four chips a kernel a chip with no collective."""
+    from h2o3_tpu.models.model import _linked_planes
+    from h2o3_tpu.ops import planes_pallas as PP
+    monkeypatch.setattr(PP, "use_pallas", lambda: True)
+    K = 2 if link == "logistic" else KDD_CLASSES
+    n = SCORE_ROWS - 5 if link == "logistic" else KDD_ROWS
+    mesh, pad, out_sh = None, n + 5 if link == "logistic" else n + 1, None
+    shape = (pad,) if link == "logistic" else (pad, K)
+    sums, f0 = sds(shape, jnp.float32), sds(shape[1:], jnp.float32)
+    if chips == 4:
+        mesh = Mesh(np.array(topo.devices).reshape(-1, 1),
+                    (MESH.ROWS, MESH.MODEL))
+        pad = MESH.Cloud(mesh).padded_rows(n)
+        out_sh = NamedSharding(mesh, P(MESH.ROWS))
+        sums = jax.ShapeDtypeStruct((pad, K), jnp.float32,
+                                    sharding=NamedSharding(mesh,
+                                                           P(MESH.ROWS, None)))
+        f0 = jax.ShapeDtypeStruct((K,), jnp.float32,
+                                  sharding=NamedSharding(mesh, P()))
+    compiled = jax.jit(_linked_planes(n, "i8", 0.1, link, mesh),
+                       out_shardings=out_sh).lower(sums, f0).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_linked_planes")
+    for op in ("all-gather", "all-reduce", "all-to-all",
+               "collective-permute"):
+        assert op not in text, op
+    planes = compiled.out_info
+    assert [[p.shape for p in col] for col in planes] == \
+        [[(pad,)] * 2] * (K + 1)
+    assert [[str(p.dtype) for p in col] for col in planes] == \
+        [["int8", "uint8"]] + [["float32", "uint8"]] * K
+    if link == "logistic":
+        assert "tpu_custom_call" not in text
+        assert f"f32[{pad},2]" not in text
+    else:
+        assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                              text)) == 1
+        assert not re.search(r" (fusion|slice|transpose)\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
 # ---- the airline configuration (benchmark/configs/gbm_airline.json): eight
 # columns, six categorical, Origin and Dest past a code byte -------------------
 AIR_ROWS = 7_720_935

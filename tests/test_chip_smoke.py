@@ -96,6 +96,7 @@ def test_walk_classes_phase(shape, rows):
     assert (rec["cols"], rec["classes"], rec["ntrees"]) == (41, 5, 5 * shape[0])
     assert rec["distinct_sums"] > 100 and rec["nonfinite_cells"] > 0
     assert rec["pallas_kernels_traced"] == []   # the XLA twin ran here
+    assert rec["epilogue_planes"] == 1 + 5      # the label and a plane a class
     assert cs.WALK_CLASSES == 23 and cs.WALK_CLASS_SHAPES[0] == (10, 5)
     assert {d for _, d in cs.WALK_CLASS_SHAPES} == {5, 8, 9}
 

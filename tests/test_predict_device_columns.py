@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from h2o3_tpu.core.frame import DevicePlanes, Frame, T_CAT, T_NUM
 from h2o3_tpu.core.kvstore import DKV
+from h2o3_tpu.models.model import Margins
 from h2o3_tpu.obs.metrics import REGISTRY
 from h2o3_tpu.obs.timeline import SPANS
 
@@ -145,22 +146,32 @@ def test_device_frame_equals_host_frame(models, kind, planted):
 @pytest.mark.parametrize("kind", ["gbm_binomial", "gbm_regression"])
 def test_predict_on_the_large_path_returns_the_host_frame_values(
         models, monkeypatch, kind):
-    """predict() end to end: the large-frame path (device columns) against
-    the frame of _score_host's fetched scores, and against the bucket path
-    of the same model."""
+    """predict() end to end: the large-frame path (device columns, the link
+    in their program) against the host frame of the scores it was handed,
+    against the frame of _score_host's fetched scores, and against the
+    bucket path of the same model. _score_host's eager ops round lr x sums
+    before the add, which XLA's CPU backend contracts into one
+    multiply-add inside the one program: an ulp
+    (tests/test_predict_epilogue.py bounds the margins)."""
     m, f = models(kind)
     bucket = m.predict(f)
     monkeypatch.setenv("H2O3_SCORE_FASTPATH_MAX_ROWS", "10")
     dev = m.predict(f)
+    same = m._prediction_frame(
+        np.asarray(m._score_linkable(m._dinfo.matrix(f))), N)
     host = m._prediction_frame(m._score_host(f), N)
     try:
-        _same_frame(dev, host)
+        _same_frame(dev, same)
+        for name in dev.names:
+            x, y = dev.vec(name).to_numpy(), host.vec(name).to_numpy()
+            assert np.array_equal(np.isnan(x), np.isnan(y)), name
+            assert (np.abs(x - y) <= np.spacing(np.abs(y))).all(), name
         for name in dev.names[1:]:
             np.testing.assert_allclose(dev.vec(name).to_numpy(),
                                        bucket.vec(name).to_numpy(),
                                        atol=1e-6, rtol=0)
     finally:
-        for fr in (bucket, dev, host):
+        for fr in (bucket, dev, same, host):
             DKV.remove(fr.key)
 
 
@@ -227,7 +238,9 @@ def test_a_patched_prediction_columns_on_numpy_yields_a_host_frame(
     pred = m.predict(f)
     spans = SPANS.snapshot()
     try:
-        assert issubclass(seen[0], jax.Array)
+        # the walk's sums with the model's link: np.array gives the
+        # probabilities
+        assert issubclass(seen[0], (jax.Array, Margins))
         frame = next(s for s in spans if s["name"] == "predict.frame")
         assert frame["attrs"]["columns"] == "host"
         assert counter.value(algo="gbm", columns="host") == host0 + 1
