@@ -1228,10 +1228,16 @@ def _walk_tail(X, col, thr, nal, val, tw, start, *, levels):
     """Σ_t tw[t] · value[t, leaf_t(row)] from each row's node `start`
     (n, T) on down `levels` more levels: the gather body's step, tree by
     tree in tree order, with a node's column, threshold and NaN turn read
-    by ONE gather of a packed (nodes, 3) row and the feature picked from
-    the row's columns by selects (no gather into X)."""
+    by ONE gather of a packed (nodes, 3) row and the feature picked in ONE
+    pass over X (no gather into X): a masked sum over the columns of X's
+    transpose as int32 bits, taken once a call. Of a row's C terms only
+    its node's column's is not 0, so the sum is that column's bits, every
+    f32 bit pattern kept (NaN payloads, -0.0, subnormals). XLA fuses it
+    into one pass a step; a select a column costs a slice and a mask a
+    column, each a pass over a row of X."""
     n, C = X.shape
-    XT = X.T
+    XT = jax.lax.bitcast_convert_type(X, jnp.int32).T            # (C, n)
+    cols = jnp.arange(C, dtype=jnp.int32)[:, None]
     tab = jnp.stack([col, jax.lax.bitcast_convert_type(thr, jnp.int32),
                      nal.astype(jnp.int32)], axis=-1)       # (T, nodes, 3)
 
@@ -1241,9 +1247,9 @@ def _walk_tail(X, col, thr, nal, val, tw, start, *, levels):
             c = e[:, 0]
             leafish = c < 0
             cc = jnp.maximum(c, 0)
-            x = XT[0]
-            for j in range(1, C):
-                x = jnp.where(cc == j, XT[j], x)
+            bits = jnp.sum(jnp.where(cols == cc[None, :], XT, 0), axis=0,
+                           dtype=jnp.int32)
+            x = jax.lax.bitcast_convert_type(bits, jnp.float32)
             isna = jnp.isnan(x)
             right = x > jax.lax.bitcast_convert_type(e[:, 1], jnp.float32)
             right = jnp.where(isna, e[:, 2] == 0, right)

@@ -599,3 +599,42 @@ def test_deep_walk_compiles_for_v5e_at_frame_size(ntrees, depth, topo, sds,
     assert text.startswith("HloModule jit__ensemble_walk")
     assert "tpu_custom_call" in text and re.search(r" gather\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+    # the tail's level step reads X once: no (1, rows) slice of X's
+    # transpose a column (28 of them in 62 fusions a step when a select a
+    # column picked the feature), ONE gather (the node's packed row)
+    comps, step = _level_step(text)
+    assert _deep_ops(comps, step, rf" = \w+\[1,{SCORE_ROWS}\]\S* slice\(") \
+        == 0
+    assert 0 < sum(" fusion(" in line for line in step) <= 12
+    assert _deep_ops(comps, step, r" gather\(") == 1
+
+
+def _computations(text):
+    """{name: instruction lines} of each computation of an HLO module."""
+    comps, body = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            body = comps[head.group(1)] = []
+        elif body is not None and line.startswith("  "):
+            body.append(line)
+    return comps
+
+
+def _level_step(text):
+    """(computations, the instruction lines of the deep walk's level
+    step): the body of the `walk.tail` while loop that holds no loop."""
+    comps = _computations(text)
+    bodies = [comps[m] for m in re.findall(
+        r" while\(.*body=%([\w.\-]+).*walk\.tail", text)]
+    inner = [b for b in bodies if not any(" while(" in ln for ln in b)]
+    assert len(inner) == 1, len(inner)
+    return comps, inner[0]
+
+
+def _deep_ops(comps, lines, pattern):
+    """How many instructions match `pattern` in `lines` and in the
+    computations they call (a fusion's ops live in its computation)."""
+    return sum(bool(re.search(pattern, line)) + sum(
+        _deep_ops(comps, comps[c], pattern)
+        for c in re.findall(r"calls=%([\w.\-]+)", line)) for line in lines)

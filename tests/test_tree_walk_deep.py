@@ -5,8 +5,10 @@ TPU the kernel `walk_dense_tile_top`, interpreted here), the levels under
 them by gathers (`_walk_tail`) — against `_walk_gather`, bit for bit
 (`==`, never allclose), at depths 12, 16 and 20, on perfect trees and on
 ragged ones (leaves at every depth), with NaN, ±inf and values on the
-thresholds; the rule that sends a shape to it; and that the walks of the
-benchmark's four GBM cells are the programs they were, letter for letter.
+thresholds, and bit pattern for bit pattern where the tail's features and
+thresholds are signed NaNs with payloads, -0.0 and subnormals; the rule
+that sends a shape to it; and that the walks of the benchmark's four GBM
+cells are the programs they were, letter for letter.
 
 That the chip's kernel picks the same nodes is the chip run's (PERF.md).
 """
@@ -73,6 +75,57 @@ def test_the_deep_walk_is_the_gather_walk_bit_for_bit(depth, ntrees, n,
                                                       shape):
     want = _both(depth, ntrees, n, 31 * depth + ntrees + n, shape)
     assert n == 1 or np.unique(want).size > 1      # rows went several ways
+
+
+# f32 bit patterns the tail's one-pass pick must carry whole: a quiet NaN
+# with the sign bit and a payload, a signalling NaN, -0.0, the smallest
+# and the largest subnormals of either sign
+EDGE_BITS = np.array([0xFFC01234, 0x7F800001, 0x80000000, 0x00000001,
+                      0x80000001, 0x007FFFFF, 0x807FFFFF], np.uint32)
+
+
+def _tail_reads(X, col, thr, nal, depth):
+    """The bit patterns of the features rows read at the tail's steps
+    (levels _DEEP_TOP and under), by a NumPy walk."""
+    n, rows = X.shape[0], np.arange(X.shape[0])
+    reads = []
+    for t in range(col.shape[0]):
+        node = np.zeros(n, np.int64)
+        for d in range(depth):
+            c = col[t][node]
+            x = X[rows, np.maximum(c, 0)]
+            if d >= E._DEEP_TOP:
+                reads.append(x[c >= 0].view(np.uint32))
+            with np.errstate(invalid="ignore"):
+                right = np.where(np.isnan(x), ~nal[t][node], x > thr[t][node])
+            node = np.where(c < 0, node, 2 * node + 1 + right)
+    return np.concatenate(reads)
+
+
+@pytest.mark.parametrize("ntrees,n", [(3, 517), (4, 1031)])
+def test_the_deep_walk_keeps_every_bit_of_a_tail_feature(ntrees, n):
+    """Ragged trees at depth 20 whose tail thresholds are often ±0 or
+    subnormal, over rows whose cells are often EDGE_BITS: the sums are
+    `_walk_gather`'s bit pattern for bit pattern, and every such pattern
+    is read where the tail picks a feature."""
+    depth = 20
+    rng = np.random.default_rng(53 * n + ntrees)
+    col, thr, nal, val, tw = _ragged(rng, ntrees, depth)
+    odd = rng.random(thr.shape) < 0.3
+    odd[:, :2 ** E._DEEP_TOP - 1] = False
+    thr.view(np.uint32)[odd] = rng.choice(EDGE_BITS[2:], size=odd.sum())
+    X = _rows(rng, n, col, thr)
+    cells = rng.random(X.shape) < 0.15
+    X.view(np.uint32)[cells] = rng.choice(EDGE_BITS, size=cells.sum())
+    assert set(EDGE_BITS) <= set(_tail_reads(X, col, thr, nal, depth))
+    args = [jnp.asarray(a) for a in (X, col, thr, nal, val, tw)]
+    want = np.asarray(E._walk_gather(*args, *NO_BITS, depth=depth,
+                                     has_cat=False))
+    got = np.asarray(E._walk_deep(*args, depth=depth))
+    assert got.dtype == want.dtype == np.float32 and got.shape == (n,)
+    assert np.array_equal(got.view(np.int32), want.view(np.int32)), \
+        f"{(got.view(np.int32) != want.view(np.int32)).sum()} of {n} differ"
+    assert np.unique(want).size > 1                # rows went several ways
 
 
 @pytest.mark.parametrize("depth,ntrees,n", [(12, 2, 300), (20, 3, 517)])
